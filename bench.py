@@ -1288,7 +1288,7 @@ def main() -> int:
     warm_s, _, results = timed(
         lambda: runs.append(wgl_seg.check_many(model, hists))
         or runs[-1])
-    ks = sorted(r[0]["time_kernel_s"] for r in runs)
+    ks = sorted(r[0]["stages"]["kernel"] for r in runs)
     kernel_s, kernel_med = ks[0], ks[len(ks) // 2]
     bad = [i for i, r in enumerate(results) if r["valid?"] is not True]
     if bad or any(r["valid?"] is not True for r in cold):
@@ -2575,7 +2575,7 @@ def main() -> int:
           f"({cpu_rate:.0f} ops/s)", file=sys.stderr)
     print(f"# single-history: {n1} ops in {single_wall:.3f}s wall "
           f"(median {single_med:.3f}s; kernel "
-          f"{r1['time_kernel_s']:.3f}s; {r1['segments']} "
+          f"{r1['stages']['kernel']:.3f}s; {r1['segments']} "
           f"segments; {cpu_note}; ratio {single_ratio:.1f}x)",
           file=sys.stderr)
     print(f"# hard-regime: {nh} ops ({n_crash} crashed) in "

@@ -19,6 +19,7 @@ from collections import Counter
 from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Optional
 
+from jepsen_tpu import telemetry
 from jepsen_tpu.history import History, Op
 
 UNKNOWN = "unknown"
@@ -265,8 +266,13 @@ class Linearizable(Checker):
         return [self.check(test, h) for h in histories]
 
     def check(self, test, history, opts=None):
+        """The verdict of the configured engine, then the front's own
+        stages — the `final_paths` rebuild and `assemble` — as spans
+        counted into
+        `jepsen_checker_seconds_total{checker="linearizable"}`."""
         from jepsen_tpu.ops import wgl_cpu
 
+        front = telemetry.Stages()
         algo = self.algorithm
         spec = self.model.device_spec()
         if algo == "auto":
@@ -284,62 +290,68 @@ class Linearizable(Checker):
         if (a.get("valid?") is False and "final-paths" not in a
                 and "final-paths-error" not in a
                 and a.get("op_index") is not None):
-            # Analysis-artifact parity (checker.clj:155-158): device
-            # verdicts localize a witness but carry no configs or
-            # final-paths; reconstruct both from the CPU oracle on the
-            # prefix through the witness, under the artifact caps: the
-            # oracle is exponential in crashed calls, and the verdict
-            # is already known invalid.  An engine whose own capped
-            # attempt ran out says so in final-paths-error.
-            try:
-                # The prefix must include the witness's COMPLETION: cut
-                # at its invocation and prepare() treats it as crashed
-                # (linearizable by omission), yielding a bogus valid
-                # analysis (cf. wgl_seg's cutoff at completion.index).
-                hist = History(history)
-                wit = next((o for o in hist
-                            if o.index == a["op_index"]), None)
-                cutoff = a["op_index"]
-                if wit is not None:
-                    for o in hist:
-                        if (o.index is not None
-                                and o.index > a["op_index"]
-                                and o.process == wit.process
-                                and not o.is_invoke):
-                            cutoff = o.index
-                            break
-                prefix = History(
-                    [o for o in hist
-                     if o.index is not None and o.index <= cutoff])
-                oracle = wgl_cpu.check(self.model, prefix,
-                                       **wgl_cpu.ARTIFACT_CAPS)
-                if oracle.get("valid?") is not False:
-                    a["final-paths-error"] = wgl_cpu.artifacts_error(
-                        oracle)
-                else:
-                    for key in ("configs", "final-paths"):
-                        if key in oracle and key not in a:
-                            a[key] = oracle[key]
-            except Exception as e:      # noqa: BLE001
-                a["final-paths-error"] = str(e)
-        # Truncation parity (checker.clj:155-158): writing full configs
-        # "can take *hours*".  The config-explosion verdict sets
-        # 'configs' to a COUNT, not a list — only slice lists.
-        if isinstance(a.get("configs"), list):
-            a["configs"] = a["configs"][:10]
-        if isinstance(a.get("final-paths"), list):
-            a["final-paths"] = a["final-paths"][:10]
-        if a.get("valid?") is False:
-            # checker.clj:147-154: render the failing window as
-            # linear.svg in the store dir.  Rendering must never fail
-            # the check itself.
-            try:
-                from jepsen_tpu.checker import linear_report
-                p = linear_report.write_to_store(test, history, a, opts)
-                if p:
-                    a["linear-svg"] = p
-            except Exception as e:      # noqa: BLE001
-                a["linear-svg-error"] = str(e)
+            with front.span("final_paths"):
+                # Analysis-artifact parity (checker.clj:155-158):
+                # device verdicts localize a witness but carry no
+                # configs or final-paths; reconstruct both from the CPU
+                # oracle on the prefix through the witness, under the
+                # artifact caps: the oracle is exponential in crashed
+                # calls, and the verdict is already known invalid.  An
+                # engine whose own capped attempt ran out says so in
+                # final-paths-error.
+                try:
+                    # The prefix must include the witness's COMPLETION:
+                    # cut at its invocation and prepare() treats it as
+                    # crashed (linearizable by omission), yielding a
+                    # bogus valid analysis (cf. wgl_seg's cutoff at
+                    # completion.index).
+                    hist = History(history)
+                    wit = next((o for o in hist
+                                if o.index == a["op_index"]), None)
+                    cutoff = a["op_index"]
+                    if wit is not None:
+                        for o in hist:
+                            if (o.index is not None
+                                    and o.index > a["op_index"]
+                                    and o.process == wit.process
+                                    and not o.is_invoke):
+                                cutoff = o.index
+                                break
+                    prefix = History(
+                        [o for o in hist
+                         if o.index is not None and o.index <= cutoff])
+                    oracle = wgl_cpu.check(self.model, prefix,
+                                           **wgl_cpu.ARTIFACT_CAPS)
+                    if oracle.get("valid?") is not False:
+                        a["final-paths-error"] = \
+                            wgl_cpu.artifacts_error(oracle)
+                    else:
+                        for key in ("configs", "final-paths"):
+                            if key in oracle and key not in a:
+                                a[key] = oracle[key]
+                except Exception as e:      # noqa: BLE001
+                    a["final-paths-error"] = str(e)
+        with front.span("assemble"):
+            # Truncation parity (checker.clj:155-158): writing full
+            # configs "can take *hours*".  The config-explosion verdict
+            # sets 'configs' to a COUNT, not a list — only slice lists.
+            if isinstance(a.get("configs"), list):
+                a["configs"] = a["configs"][:10]
+            if isinstance(a.get("final-paths"), list):
+                a["final-paths"] = a["final-paths"][:10]
+            if a.get("valid?") is False:
+                # checker.clj:147-154: render the failing window as
+                # linear.svg in the store dir.  Rendering must never
+                # fail the check itself.
+                try:
+                    from jepsen_tpu.checker import linear_report
+                    p = linear_report.write_to_store(test, history, a,
+                                                     opts)
+                    if p:
+                        a["linear-svg"] = p
+                except Exception as e:      # noqa: BLE001
+                    a["linear-svg-error"] = str(e)
+        front.flush(telemetry.CHECKER_SECONDS, checker="linearizable")
         return a
 
 

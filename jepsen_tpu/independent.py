@@ -22,6 +22,7 @@ from typing import Callable, Iterable, Optional
 
 from jepsen_tpu import checker as ck
 from jepsen_tpu import generator as gen
+from jepsen_tpu import telemetry
 from jepsen_tpu.history import History, Op
 from jepsen_tpu.util import bounded_pmap
 
@@ -295,14 +296,27 @@ class BatchedLinearizableChecker(ck.Checker):
         self.max_retries = max_retries
 
     def check(self, test, history, opts=None):
+        """Split, one batched device call, assemble.  The front's own
+        stages (`keys`, `split`, `assemble`) are spans counted into
+        `jepsen_checker_seconds_total{checker="independent"}`; the
+        engine counts its dispatch stages itself."""
+        front = telemetry.Stages()
+        try:
+            return self._check(test, history, opts, front)
+        finally:
+            front.flush(telemetry.CHECKER_SECONDS, checker="independent")
+
+    def _check(self, test, history, opts, front):
         import os as _os
 
         from jepsen_tpu.ops import runner as runner_mod
 
-        ks = sorted(history_keys(history), key=repr)
+        with front.span("keys"):
+            ks = sorted(history_keys(history), key=repr)
         if not ks:
             return {"valid?": True, "results": {}, "failures": []}
-        subs = subhistories(ks, history)
+        with front.span("split"):
+            subs = subhistories(ks, history)
         ckdir = (opts or {}).get("checkpoint_dir")
         per_key = runner_mod.ResilientRunner(
             engine="seg_many",
@@ -315,27 +329,30 @@ class BatchedLinearizableChecker(ck.Checker):
             checkpoint_dir=(_os.path.join(str(ckdir), DIR)
                             if ckdir else None),
         ).check(self.model, subs)
-        results = dict(zip(ks, per_key))
-        failures = [k for k, r in results.items() if r["valid?"] is not True]
-        # Failing-window SVGs under independent/<k>/, matching the
-        # host-parallel IndependentChecker path (checker.clj:147-154).
-        for k, sub in zip(ks, subs):
-            r = results[k]
-            if r.get("valid?") is False and r.get("op_index") is not None:
-                try:
-                    from jepsen_tpu.checker import linear_report
-                    subdir = (list((opts or {}).get("subdirectory")
-                                   or []) + [DIR, str(k)])
-                    p = linear_report.write_to_store(
-                        test, sub, r, {"subdirectory": subdir})
-                    if p:
-                        r["linear-svg"] = p
-                except Exception as e:          # noqa: BLE001
-                    r["linear-svg-error"] = str(e)
-        return {"valid?": ck.merge_valid(r["valid?"]
-                                         for r in results.values()),
-                "results": results,
-                "failures": failures}
+        with front.span("assemble"):
+            results = dict(zip(ks, per_key))
+            failures = [k for k, r in results.items()
+                        if r["valid?"] is not True]
+            # Failing-window SVGs under independent/<k>/, matching the
+            # host-parallel IndependentChecker path (checker.clj:147-154).
+            for k, sub in zip(ks, subs):
+                r = results[k]
+                if r.get("valid?") is False \
+                        and r.get("op_index") is not None:
+                    try:
+                        from jepsen_tpu.checker import linear_report
+                        subdir = (list((opts or {}).get("subdirectory")
+                                       or []) + [DIR, str(k)])
+                        p = linear_report.write_to_store(
+                            test, sub, r, {"subdirectory": subdir})
+                        if p:
+                            r["linear-svg"] = p
+                    except Exception as e:          # noqa: BLE001
+                        r["linear-svg-error"] = str(e)
+            return {"valid?": ck.merge_valid(r["valid?"]
+                                             for r in results.values()),
+                    "results": results,
+                    "failures": failures}
 
 
 def batch_checker(model_or_checker, frontier_size: int = 256, mesh=None):

@@ -70,6 +70,7 @@ from typing import Optional
 
 import numpy as np
 
+from jepsen_tpu import telemetry
 from jepsen_tpu.errors import CheckError
 from jepsen_tpu.history import History
 from jepsen_tpu.models import DeviceSpec
@@ -1677,7 +1678,7 @@ def _segments_from_fk(fk, R: int, seg_ends):
 
 
 def _scan_history(h, ops, spec, seen: dict, rows: list,
-                  max_open_bits: int, want_snaps: bool = True):
+                  max_open_bits: int, want_snaps: bool = True, st=None):
     """The one scan-fallback policy shared by every engine entry point:
     columnar C scan when the history carries packed columns, then the
     object C scan, then the pure-Python twin.  Returns a _FastKey or
@@ -1685,14 +1686,22 @@ def _scan_history(h, ops, spec, seen: dict, rows: list,
     values); all three scanners are differentially pinned to classify
     identically.  want_snaps=False skips candidate-snapshot emission
     for callers that consume only the delta stream (fk.arrays then
-    carries empty cand_slots/cand_uops)."""
+    carries empty cand_slots/cand_uops).  With `st` (a
+    telemetry.Stages), the scanner that gave the answer is tallied
+    under `jepsen_scan_keys_total{path}`: `columnar`, `objects` or
+    `python`."""
+    path = "columnar"
     fk = _native_scan_cols(
         h.packed_columns() if isinstance(h, History) else None,
         spec, seen, rows, max_open_bits, want_snaps)
     if fk is False or fk is None:
+        path = "objects"
         fk = _native_scan(ops, spec, seen, rows, max_open_bits)
     if fk is False:
+        path = "python"
         fk = _fast_scan(h, spec, seen, rows, max_open_bits)
+    if st is not None:
+        st.tally(telemetry.SCAN_KEYS, path=path)
     return fk
 
 

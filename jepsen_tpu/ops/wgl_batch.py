@@ -152,97 +152,98 @@ def check_many(model, histories: Sequence, *,
     (jepsen_tpu.telemetry)."""
     import jax
 
-    from jepsen_tpu.ops.wgl_seg import _stats_clock
+    from jepsen_tpu import telemetry
 
     spec = model.device_spec()
     if spec is None:
         raise BackendUnavailable(f"model {model!r} has no device spec")
-    stats = {} if stats is None else stats
-    _mt, _acc = _stats_clock(stats)
-    t0 = _mt()
+    st = telemetry.Stages(stats)
+    stats = st.seconds
 
-    preps = [h if hasattr(h, "calls") else prepare(h) for h in histories]
-    results: list[Optional[dict]] = [None] * len(preps)
-    lanes = []  # (index, plan)
-    for i, prep in enumerate(preps):
-        if not prep.calls:
-            results[i] = {"valid?": True, "op_count": 0}
-            continue
-        lanes.append((i, plan(prep, spec, model)))
-    t0 = _acc("plan", t0)
+    with st.span("plan"):
+        preps = [h if hasattr(h, "calls") else prepare(h)
+                 for h in histories]
+        results: list[Optional[dict]] = [None] * len(preps)
+        lanes = []  # (index, plan)
+        for i, prep in enumerate(preps):
+            if not prep.calls:
+                results[i] = {"valid?": True, "op_count": 0}
+                continue
+            lanes.append((i, plan(prep, spec, model)))
     if not lanes:
         return [r for r in results]
 
-    R = _bucket(max(pl.ret_call.shape[0] for _, pl in lanes))
-    C = _bucket(max(pl.cand_call.shape[1] for _, pl in lanes), 4)
-    N = _bucket(max(pl.n_calls for _, pl in lanes))
-    S = lanes[0][1].init_state.shape[0]
-    W = max(C, _bucket(max(pl.max_open for _, pl in lanes), 4))
+    with st.span("pack"):
+        R = _bucket(max(pl.ret_call.shape[0] for _, pl in lanes))
+        C = _bucket(max(pl.cand_call.shape[1] for _, pl in lanes), 4)
+        N = _bucket(max(pl.n_calls for _, pl in lanes))
+        S = lanes[0][1].init_state.shape[0]
+        W = max(C, _bucket(max(pl.max_open for _, pl in lanes), 4))
 
-    padded = [_pad_plan(pl, R, C, N) for _, pl in lanes]
-    K = len(padded)
-    # Pad the key axis to a multiple of the mesh size so it shards evenly.
-    K_pad = K
-    if mesh is not None:
-        d = int(np.prod(list(mesh.shape.values())))
-        K_pad = ((K + d - 1) // d) * d
-    while len(padded) < K_pad:
-        padded.append(padded[0])  # duplicate lane; result ignored
+        padded = [_pad_plan(pl, R, C, N) for _, pl in lanes]
+        K = len(padded)
+        # Pad the key axis to a multiple of the mesh size so it shards
+        # evenly.
+        K_pad = K
+        if mesh is not None:
+            d = int(np.prod(list(mesh.shape.values())))
+            K_pad = ((K + d - 1) // d) * d
+        while len(padded) < K_pad:
+            padded.append(padded[0])  # duplicate lane; result ignored
 
-    def stack(attr):
-        return np.stack([getattr(p, attr) for p in padded])
+        def stack(attr):
+            return np.stack([getattr(p, attr) for p in padded])
 
-    args = [stack("ret_call"), stack("ret_slot"), stack("cand_call"),
-            stack("cand_slot"), stack("f"), stack("a"), stack("b"),
-            stack("a_ok"), stack("init_state"),
-            np.asarray([p.n_events for p in padded], np.int32)]
+        args = [stack("ret_call"), stack("ret_slot"),
+                stack("cand_call"), stack("cand_slot"), stack("f"),
+                stack("a"), stack("b"), stack("a_ok"), stack("init_state"),
+                np.asarray([p.n_events for p in padded], np.int32)]
 
-    stats["wire_bytes"] = (stats.get("wire_bytes", 0)
-                           + sum(a.nbytes for a in args))
-    t0 = _acc("pack", t0)
-    if mesh is not None:
-        from jax.sharding import NamedSharding, PartitionSpec
-        axis = mesh.axis_names[0]
-        sharding = NamedSharding(mesh, PartitionSpec(axis))
-        args = [jax.device_put(a, sharding) for a in args]
+        stats["wire_bytes"] = (stats.get("wire_bytes", 0)
+                               + sum(a.nbytes for a in args))
+    with st.span("dispatch"):
+        if mesh is not None:
+            from jax.sharding import NamedSharding, PartitionSpec
+            axis = mesh.axis_names[0]
+            sharding = NamedSharding(mesh, PartitionSpec(axis))
+            args = [jax.device_put(a, sharding) for a in args]
 
-    kern = _build_batch_kernel(spec.step, int(frontier_size), int(C),
-                               int(W), int(S))
-    dev = kern(*args)
-    t0 = _acc("dispatch", t0)
-    out = jax.device_get(dev)
-    t0 = _acc("fetch", t0)
+        kern = _build_batch_kernel(spec.step, int(frontier_size),
+                                   int(C), int(W), int(S))
+        dev = kern(*args)
+    with st.span("fetch"):
+        out = jax.device_get(dev)
 
-    escalated: list = []
-    for lane_idx, (i, pl) in enumerate(lanes):
-        ok = bool(out["ok"][lane_idx])
-        overflow = bool(out["overflow"][lane_idx])
-        if ok or not overflow:
-            r: dict[str, Any] = {"valid?": ok, "op_count": pl.n_calls,
-                                 "frontier_size": frontier_size,
-                                 "final_frontier": int(
-                                     out["frontier"][lane_idx])}
-            if not ok:
-                ev = int(out["failed_event"][lane_idx])
-                cid = int(pl.ret_call[ev]) if ev >= 0 else -1
-                calls = preps[i].calls
-                if 0 <= cid < len(calls):
-                    r["op"] = calls[cid].op.to_dict()
-                    r["op_index"] = calls[cid].op.index
-                r["anomaly"] = "nonlinearizable"
-            results[i] = r
-        elif escalate:
-            from jepsen_tpu.ops import wgl
-            results[i] = wgl.check(model, preps[i])
-            results[i].setdefault("engine", "wgl")
-            escalated.append(i)
-        else:
-            results[i] = {"valid?": "unknown", "cause": "frontier-overflow",
-                          "op_count": pl.n_calls}
-    _acc("assemble", t0)
+    with st.span("assemble"):
+        escalated: list = []
+        for lane_idx, (i, pl) in enumerate(lanes):
+            ok = bool(out["ok"][lane_idx])
+            overflow = bool(out["overflow"][lane_idx])
+            if ok or not overflow:
+                r: dict[str, Any] = {
+                    "valid?": ok, "op_count": pl.n_calls,
+                    "frontier_size": frontier_size,
+                    "final_frontier": int(out["frontier"][lane_idx])}
+                if not ok:
+                    ev = int(out["failed_event"][lane_idx])
+                    cid = int(pl.ret_call[ev]) if ev >= 0 else -1
+                    calls = preps[i].calls
+                    if 0 <= cid < len(calls):
+                        r["op"] = calls[cid].op.to_dict()
+                        r["op_index"] = calls[cid].op.index
+                    r["anomaly"] = "nonlinearizable"
+                results[i] = r
+            elif escalate:
+                from jepsen_tpu.ops import wgl
+                results[i] = wgl.check(model, preps[i])
+                results[i].setdefault("engine", "wgl")
+                escalated.append(i)
+            else:
+                results[i] = {"valid?": "unknown",
+                              "cause": "frontier-overflow",
+                              "op_count": pl.n_calls}
     # dispatch records (telemetry): batched lanes vs escalated lanes,
     # both rendering the planner-emitted plan (ops.planner)
-    from jepsen_tpu import telemetry as telemetry_mod
     from jepsen_tpu.ops import planner
     mesh_desc = (dict(zip(mesh.axis_names, mesh.devices.shape))
                  if mesh is not None else None)
@@ -256,13 +257,13 @@ def check_many(model, histories: Sequence, *,
                 int(W), int(S)))
     batched_rs = [r for i, r in enumerate(results)
                   if isinstance(r, dict) and i not in set(escalated)]
-    telemetry_mod.attach_dispatch(
+    telemetry.attach_dispatch(
         batched_rs,
         batch_plan.record(engine="wgl_batch", batch=len(histories),
                           mesh=mesh_desc),
         stages=stats)
     for i in escalated:
-        telemetry_mod.attach_dispatch(
+        telemetry.attach_dispatch(
             [results[i]],
             batch_plan.refine(
                 why="frontier overflow on an invalid-looking lane; "

@@ -525,14 +525,12 @@ def check_tables(ret_t, islot_t, iuop_t, a1t, a2t, t0t,
     fetch the verdict.  Returns {"valid?": bool, "failed_row":
     int | None, ...}; failed_row indexes ret_t's rows (callers map it
     to the returning op)."""
-    t1 = time.monotonic()
     dev, G = dispatch_tables(ret_t, islot_t, iuop_t, a1t, a2t, t0t,
                              R, Sn)
     out = np.asarray(dev)
     alive = bool(out[0, 0])
     res = {"valid?": alive,
            "failed_row": None if alive else int(out[0, 1]),
-           "time_kernel_s": time.monotonic() - t1,
            "grid": G}
     P = planner.deep_split_planes(R)
     if P > 1:
@@ -604,8 +602,10 @@ def check_pipeline(model, histories, *, max_open_bits=None,
     spec = model.device_spec()
     if spec is None:
         raise BackendUnavailable(f"model {model!r} has no device spec")
-    stats = {} if stats is None else stats   # always collected now
-    _mt, _acc = wgl_seg._stats_clock(stats)
+    from jepsen_tpu import telemetry
+
+    st = telemetry.Stages(stats)      # always collected
+    stats = st.seconds
     backend = jax.default_backend()
     n_mesh = int(np.prod(mesh.devices.shape)) if mesh is not None else 1
     if max_open_bits is None:
@@ -627,53 +627,57 @@ def check_pipeline(model, histories, *, max_open_bits=None,
     init = np.asarray(spec.encode(model), np.int32)
     for i, h in enumerate(histories):
         ops = h.ops
-        t0 = _mt()
-        fk = wgl_seg._scan_history(h, ops, spec, seen, rows,
-                                   max_open_bits, want_snaps=False)
-        t0 = _acc("scan", t0)
+        with st.span("scan"):
+            fk = wgl_seg._scan_history(h, ops, spec, seen, rows,
+                                       max_open_bits, want_snaps=False,
+                                       st=st)
         if not fk:
             strag.append(i)
             continue
         R = int(fk.max_open)
-        if len(rows) != U_at:
-            uops = np.asarray(rows, np.int32).reshape(len(rows), 4)
-            try:
-                states, legal, next_state = wgl_seg._enumerate_states(
-                    spec, init, uops, max_states)
-            except wgl_seg.Unsupported:
-                # the alphabet (and with it the state space) only
-                # grows: everything from here on is a straggler —
-                # already-dispatched in-scope verdicts stay valid
-                from jepsen_tpu import telemetry as telemetry_mod
-                telemetry_mod.count_fallback("wgl_deep_pipeline",
+        with st.span("tables"):
+            grown = False
+            if len(rows) != U_at:
+                uops = np.asarray(rows, np.int32).reshape(len(rows), 4)
+                try:
+                    states, legal, next_state = \
+                        wgl_seg._enumerate_states(spec, init, uops,
+                                                  max_states)
+                except wgl_seg.Unsupported:
+                    # the alphabet (and with it the state space) only
+                    # grows: everything from here on is a straggler —
+                    # already-dispatched in-scope verdicts stay valid
+                    telemetry.count_fallback("wgl_deep_pipeline",
                                              "state-space")
-                strag.extend(range(i, len(histories)))
-                break
-            Sn = states.shape[0]
-            dw, cw, t0c = wgl_seg._decompose(legal, next_state)
-            if dw is None:
-                # undecomposable models only grow less decomposable
-                strag.extend(range(i, len(histories)))
-                break
-            tables = wgl_seg._pack_uop_tables(legal, next_state,
-                                              dw, cw, t0c)
-            U_at = len(rows)
-        t0 = _acc("tables", t0)
+                    grown = True
+                if not grown:
+                    Sn = states.shape[0]
+                    dw, cw, t0c = wgl_seg._decompose(legal, next_state)
+                    # undecomposable models only grow less decomposable
+                    grown = dw is None
+                if not grown:
+                    tables = wgl_seg._pack_uop_tables(legal, next_state,
+                                                      dw, cw, t0c)
+                    U_at = len(rows)
+        if grown:
+            strag.extend(range(i, len(histories)))
+            break
         if not supported(R, Sn, len(rows), True, backend):
             strag.append(i)   # e.g. R past deep_r_max: straggler tiers
             continue
         I = min(2, R) if R else 1
-        if fk.deltas is not None:
-            ret_t, islot_t, iuop_t, _ = wgl_seg._pack_regs_single(
-                fk, [fk.n_rets], R, len(rows), I)
-        else:
-            ret_t, islot_t, iuop_t, _ = wgl_seg._pack_regs(
-                [(0, fk)], 1, R, len(rows), I)
-        a1t, a2t, t0t = tables
-        t0 = _acc("pack", t0)
+        with st.span("pack"):
+            if fk.deltas is not None:
+                ret_t, islot_t, iuop_t, _ = wgl_seg._pack_regs_single(
+                    fk, [fk.n_rets], R, len(rows), I)
+            else:
+                ret_t, islot_t, iuop_t, _ = wgl_seg._pack_regs(
+                    [(0, fk)], 1, R, len(rows), I)
+            a1t, a2t, t0t = tables
         try:
-            dev, G = dispatch_tables(ret_t, islot_t, iuop_t, a1t, a2t,
-                                     t0t, R, Sn, stats=stats)
+            with st.span("dispatch"):
+                dev, G = dispatch_tables(ret_t, islot_t, iuop_t, a1t,
+                                         a2t, t0t, R, Sn, stats=stats)
         except Exception as e:       # noqa: BLE001 - classified below
             if not errors_mod.is_oom(e):
                 raise
@@ -682,34 +686,32 @@ def check_pipeline(model, histories, *, max_open_bits=None,
             # poisoned batch (ISSUE 10: no silent wrong verdict)
             oom_demoted += 1
             strag.append(i)
-            _acc("dispatch", t0)
             continue
-        _acc("dispatch", t0)
         pend.append((dev, i, fk, ret_t, ops, R, Sn, G))
 
     if pend:
-        t0 = _mt()
-        stacked = wgl_seg._build_stack(len(pend))(
-            *[d for d, *_ in pend])
-        outs = np.asarray(stacked)                    # ONE fetch
-        t0 = _acc("fetch", t0)
-        for j, (dev, i, fk, ret_t, ops, R, Sn_i, G) in enumerate(pend):
-            alive = bool(outs[j, 0, 0])
-            res = {"valid?": alive, "op_count": fk.n_calls,
-                   "backend": backend, "engine": "wgl_deep",
-                   "max_open": R, "states": Sn_i, "pipelined": True}
-            P_i = planner.deep_split_planes(R)
-            if P_i > 1:
-                res["deep_variant"] = "word-split"
-                res["shards"] = P_i
-            if not alive:
-                res["anomaly"] = "nonlinearizable"
-                w = map_witness(ret_t, fk, ops, int(outs[j, 0, 1]))
-                if w is not None:
-                    res["op"] = w[0].to_dict()
-                    res["op_index"] = w[1]
-            results[i] = res
-        _acc("assemble", t0)
+        with st.span("fetch"):
+            stacked = wgl_seg._build_stack(len(pend))(
+                *[d for d, *_ in pend])
+            outs = np.asarray(stacked)                # ONE fetch
+        with st.span("assemble"):
+            for j, (dev, i, fk, ret_t, ops, R, Sn_i, G) \
+                    in enumerate(pend):
+                alive = bool(outs[j, 0, 0])
+                res = {"valid?": alive, "op_count": fk.n_calls,
+                       "backend": backend, "engine": "wgl_deep",
+                       "max_open": R, "states": Sn_i, "pipelined": True}
+                P_i = planner.deep_split_planes(R)
+                if P_i > 1:
+                    res["deep_variant"] = "word-split"
+                    res["shards"] = P_i
+                if not alive:
+                    res["anomaly"] = "nonlinearizable"
+                    w = map_witness(ret_t, fk, ops, int(outs[j, 0, 1]))
+                    if w is not None:
+                        res["op"] = w[0].to_dict()
+                        res["op_index"] = w[1]
+                results[i] = res
     # in-scope verdicts carry the deep pipeline's plan + stage
     # decomposition BEFORE the stragglers run, so the serial chain's
     # verdicts keep their own engines' records
@@ -731,6 +733,7 @@ def check_pipeline(model, histories, *, max_open_bits=None,
                          stragglers=len(strag) or None,
                          oom_demoted=oom_demoted or None),
         stages=stats)
+    st.flush()
     if oom_demoted:
         try:
             telemetry_mod.REGISTRY.counter(

@@ -69,11 +69,11 @@ from __future__ import annotations
 import functools
 import os
 import threading
-import time
 from typing import Any, Optional
 
 import numpy as np
 
+from jepsen_tpu import telemetry
 from jepsen_tpu.history import History, PackedHistory
 from jepsen_tpu.ops.prep import PreparedHistory, prepare
 from jepsen_tpu.ops.frontier import (make_plane_ops as _bit_ops,
@@ -139,7 +139,7 @@ def _build_kernel_bits(K: int, L: int, C: int, Wd: int, Sn: int, R: int,
     def popcount(x):
         return jax.lax.population_count(x).astype(jnp.int32).sum()
 
-    def kern(ret_slot, cand_slot, cand_aux1, cand_aux2, cand_t0):
+    def wgl_seg_bits(ret_slot, cand_slot, cand_aux1, cand_aux2, cand_t0):
         # fr[w, s, j, k]; bit i of word w = mask index w*32+i.
         # Decomposed: aux1/aux2 = uint32 per-candidate state-bitmasks of
         # the diag/const weights (bit s set iff weight[s]); no device
@@ -250,7 +250,7 @@ def _build_kernel_bits(K: int, L: int, C: int, Wd: int, Sn: int, R: int,
         # mask 0 = bit 0 of word 0
         return (fr[0] & 1).transpose(2, 1, 0)          # [K, J, Sn]
 
-    return jax.jit(kern)
+    return jax.jit(wgl_seg_bits)
 
 
 @functools.lru_cache(maxsize=32)
@@ -319,8 +319,8 @@ def _build_kernel_regs(K: int, L: int, I: int, Wd: int, Sn: int, R: int,
     lacking, set_slot, retire_slot, sel32 = _bit_ops(Wd, R)
     b_iota = np.arange(R, dtype=np.int32)[:, None]          # [R, 1]
 
-    def kern(ret_slot, inv_slot, inv_uop, aux1_tab, aux2_tab, t0_tab,
-             *closure_args):
+    def wgl_seg_regs_scan(ret_slot, inv_slot, inv_uop, aux1_tab, aux2_tab,
+                          t0_tab, *closure_args):
         # ret_slot [L, K] i8; inv_slot/inv_uop [L, K, I] i8/i16;
         # aux1_tab/aux2_tab [U] u32, t0_tab [U] i32.  With
         # crash_closure: closure_args = (crow i32 [L, K] row index,
@@ -514,7 +514,7 @@ def _build_kernel_regs(K: int, L: int, I: int, Wd: int, Sn: int, R: int,
             [valid.astype(jnp.int32), dead]
             + [jax.lax.bitcast_convert_type(w, jnp.int32) for w in em])
 
-    return jax.jit(kern)
+    return jax.jit(wgl_seg_regs_scan)
 
 
 def _unpack_transfer_bufs(buf8, buf32, B: int, L: int, K: int, I: int,
@@ -581,7 +581,7 @@ def _build_kernel_regs_relaxed(K: int, L: int, I: int, Wd: int,
     n_crow = L * K * 2               # i16
     W = sn_words
 
-    def fn(buf8, buf32):
+    def wgl_seg_regs_relaxed(buf8, buf32):
         base = len(buf8) - n_crow
         if W == 1:
             tabs = _unpack_transfer_bufs(buf8[:base], buf32[:3 * U],
@@ -606,7 +606,7 @@ def _build_kernel_regs_relaxed(K: int, L: int, I: int, Wd: int,
         ctab = buf32[aux_n:].reshape(nC, Sn, W)
         return kern(*tabs, crow, ctab)
 
-    return jax.jit(fn)
+    return jax.jit(wgl_seg_regs_relaxed)
 
 
 @functools.lru_cache(maxsize=32)
@@ -626,11 +626,11 @@ def _build_kernel_regs_packed(K: int, L: int, I: int, Wd: int, Sn: int,
                               rounds=rounds, unroll=unroll, J=J, nc=nc,
                               rn=rn, compose=True)
 
-    def fn(buf8, buf32):
+    def wgl_seg_regs(buf8, buf32):
         return kern(*_unpack_transfer_bufs(buf8, buf32, 1, L, K, I, U,
                                            wide_uop))
 
-    return jax.jit(fn)
+    return jax.jit(wgl_seg_regs)
 
 
 
@@ -662,7 +662,7 @@ def _build_kernel_regs_group_c(B: int, K: int, L: int, Wd: int,
                               nc=0, rn=0, compose=False)
     l_iota = np.arange(L, dtype=np.int32)[:, None]      # [L, 1]
 
-    def fn(buf8, buf32):
+    def wgl_seg_pipeline_regs(buf8, buf32):
         blocks = buf8.reshape(B, per)
         cum = jax.lax.bitcast_convert_type(
             blocks[:, Rp * (1 + ub):].reshape(B, K + 1, 4),
@@ -716,7 +716,7 @@ def _build_kernel_regs_group_c(B: int, K: int, L: int, Wd: int,
             [valid.astype(jnp.int32)[:, None], dead[:, None],
              jax.lax.bitcast_convert_type(em, jnp.int32)], axis=1)
 
-    return jax.jit(fn)
+    return jax.jit(wgl_seg_pipeline_regs)
 
 
 @functools.lru_cache(maxsize=32)
@@ -747,7 +747,7 @@ def _build_kernel_regs_many_c(K: int, L: int, Wd: int, Sn: int, R: int,
     ub = 1 if U <= 255 else 2
     l_iota = np.arange(L, dtype=np.int32)[:, None]      # [L, 1]
 
-    def fn(buf8, buf32):
+    def wgl_seg_batch_regs(buf8, buf32):
         cum = jax.lax.bitcast_convert_type(
             buf8[Rp * (1 + ub):].reshape(K + 1, 4), jnp.int32)
         start = cum[:K]
@@ -771,7 +771,8 @@ def _build_kernel_regs_many_c(K: int, L: int, Wd: int, Sn: int, R: int,
                                           jnp.int32)
         return kern(ret, islot, iuop, a1, a2, t0)
 
-    return jax.jit(fn, donate_argnums=(0,)) if donate else jax.jit(fn)
+    return (jax.jit(wgl_seg_batch_regs, donate_argnums=(0,)) if donate
+            else jax.jit(wgl_seg_batch_regs))
 
 
 
@@ -822,8 +823,8 @@ def _build_kernel(K: int, L: int, C: int, M: int, Sn: int, R: int,
 
     bf16 = jnp.bfloat16  # 0/1 indicator tensors and small-int sums only
 
-    def kern(ret_slot, cand_slot, cand_uop, legal, next_state,
-             diag_w, const_w, const_t0):
+    def wgl_seg_dense(ret_slot, cand_slot, cand_uop, legal, next_state,
+                      diag_w, const_w, const_t0):
         # ret_slot [L, K]; cand_slot/cand_uop [L, K, C];
         # legal [U, Sn] bool; next_state [U, Sn] i32;
         # diag_w/const_w f32 [U, Sn], const_t0 i32 [U] (decomposed only)
@@ -934,7 +935,7 @@ def _build_kernel(K: int, L: int, C: int, M: int, Sn: int, R: int,
         # At a quiescent cut every slot is retired: only mask 0 is live.
         return fr[0].transpose(2, 1, 0)                      # [K, J, Sn]
 
-    return jax.jit(kern)
+    return jax.jit(wgl_seg_dense)
 
 
 def _dispatch_kernel(K, L, C, M, Sn, R, J, ret_t, cslot_t, cuop_t,
@@ -1003,14 +1004,45 @@ def _shard_args(mesh, mesh_axis: str, args: list, n_sharded: int):
 # Public API
 # ---------------------------------------------------------------------------
 
+def _device_call(st: telemetry.Stages, engine: str, fn, args: list, *,
+                 lanes: int, padded: int, put=None) -> np.ndarray:
+    """Call `fn` on the host arrays `args` and fetch its result: the
+    trace-only parts of the caller's open `kernel` span.  With `put`
+    (e.g. a mesh sharding) the arrays go to the device first, as
+    `kernel.put`; without it the call transfers them itself, which
+    measured ~0.2 ms a request cheaper than an explicit put on a v5e
+    host.  Tallies the call, its used and padded lanes and its wire
+    bytes on `st`."""
+    if put is not None:
+        with telemetry.trace("kernel.put"):
+            args_in = put(args)
+    else:
+        args_in = args
+    with telemetry.trace("kernel.call"):
+        out = fn(*args_in)
+    with telemetry.trace("kernel.fetch"):
+        out = np.asarray(out)
+    st.tally(telemetry.DEVICE_CALLS, engine=engine)
+    st.tally(telemetry.LANES, lanes, engine=engine, kind="used")
+    st.tally(telemetry.LANES, padded, engine=engine, kind="padded")
+    st.tally(telemetry.WIRE_BYTES, sum(a.nbytes for a in args),
+             engine=engine, direction="h2d")
+    st.tally(telemetry.WIRE_BYTES, out.nbytes, engine=engine,
+             direction="d2h")
+    return out
+
+
 def _run_seg_regs(seg_fk: list, K: int, R: int, U: int, Sn: int, M: int,
                   legal, next_state, diag_w, const_w, const_t0,
                   mesh, mesh_axis, nc: int = 0, rn: int = 0,
-                  compose: bool = True, tables=None):
+                  compose: bool = True, tables=None,
+                  st: Optional[telemetry.Stages] = None):
     """Run the register-delta kernel over per-segment lanes with
     J = Sn * 2^nc entry configurations (nc = crashed-call count).
-    Returns (T, t_kernel, sharded, dead_segment) — shared by the
-    plan()-based and fast-scan single-history paths.
+    Returns (T, lanes, sharded, dead_segment) — shared by the
+    plan()-based and fast-scan single-history paths.  Stage seconds go
+    to `st`: `tables` (uop tables), `pack` (lane tables and the wire
+    buffers), `kernel` (the device call and its fetch).
 
     Unsharded with compose=True (the default), the per-segment transfer
     matrices are composed ON DEVICE and only (valid, first-dead) come
@@ -1020,6 +1052,7 @@ def _run_seg_regs(seg_fk: list, K: int, R: int, U: int, Sn: int, M: int,
     matrices cross the ICI/host boundary.  (Multi-history pipelining
     does not come through here — check_pipeline has its own grouped
     dispatch.)"""
+    st = telemetry.Stages() if st is None else st
     sharded = False
     K_run = K
     if mesh is not None and mesh_axis is not None:
@@ -1030,60 +1063,58 @@ def _run_seg_regs(seg_fk: list, K: int, R: int, U: int, Sn: int, M: int,
         sharded = True
     I = min(2, R) if R else 1
     decomposed = diag_w is not None
-    # timer covers host packing too, matching the candidate-table path
-    # (whose _dispatch_kernel packing sits inside the timed window) so
-    # the two flavours report comparable time_kernel_s
-    t1 = time.monotonic()
-    if tables is not None and not sharded and K_run == K:
-        ret_t, islot_t, iuop_t, Lp = tables
-    else:
-        ret_t, islot_t, iuop_t, Lp = _pack_regs(
-            [(k, fk) for k, fk in enumerate(seg_fk)], K_run, R, int(U), I)
-    a1t, a2t, t0t = _pack_uop_tables(
-        legal, next_state, diag_w, const_w, const_t0)
     unroll = int(os.environ.get("JEPSEN_TPU_SCAN_UNROLL", "4"))
-    if not sharded and compose:
-        out = _dispatch_regs_packed(ret_t, islot_t, iuop_t, a1t, a2t,
-                                    t0t, M, Sn, R, decomposed, nc, rn,
-                                    unroll)
-        vd = np.asarray(out)         # [6]: valid, dead, entry mask x4
-        return None, time.monotonic() - t1, False, vd
-    kern = _build_kernel_regs(K_run, int(Lp), I, max(1, M // 32),
-                              int(Sn), R, decomposed,
-                              rounds=R, unroll=unroll,
-                              J=int(Sn) << nc, nc=nc, rn=rn)
-    args = [ret_t, islot_t, iuop_t, a1t, a2t, t0t]
-    if sharded:
-        args = _shard_args(mesh, mesh_axis, args, 3)
-    T = np.asarray(kern(*args))[:K] > 0.5                    # [K, J, J]
-    return T, time.monotonic() - t1, sharded, None
-
-
-def _dispatch_regs_packed(ret_t, islot_t, iuop_t, a1t, a2t, t0t,
-                          M: int, Sn: int, R: int, decomposed: bool,
-                          nc: int, rn: int, unroll: int):
-    """Pack the six host tables into two transfer buffers and dispatch
-    the composed register kernel asynchronously; returns the un-fetched
-    int32[6] (valid, first-dead-segment, 128-bit entry-config mask)
-    device value."""
-    Lp, K_run = ret_t.shape
-    I = islot_t.shape[2]
-    wide = iuop_t.dtype == np.int16
-    buf8 = np.concatenate([ret_t.view(np.uint8).ravel(),
-                           islot_t.view(np.uint8).ravel(),
-                           iuop_t.view(np.uint8).ravel()])
-    buf32 = np.concatenate([a1t, a2t, t0t.view(np.uint32)])
-    fn = _build_kernel_regs_packed(
-        int(K_run), int(Lp), I, max(1, M // 32), int(Sn), R, decomposed,
-        R, unroll, int(Sn) << nc, nc, rn, int(a1t.shape[0]), wide)
-    return fn(buf8, buf32)
+    with st.span("tables"):
+        a1t, a2t, t0t = _pack_uop_tables(
+            legal, next_state, diag_w, const_w, const_t0)
+    with st.span("pack"):
+        if tables is not None and not sharded and K_run == K:
+            ret_t, islot_t, iuop_t, Lp = tables
+        else:
+            ret_t, islot_t, iuop_t, Lp = _pack_regs(
+                [(k, fk) for k, fk in enumerate(seg_fk)], K_run, R,
+                int(U), I)
+        if not sharded and compose:
+            # two transfer buffers instead of six tables (one fixed
+            # put latency each): u8 event tables ++ u32 uop tables
+            args = [np.concatenate([ret_t.view(np.uint8).ravel(),
+                                    islot_t.view(np.uint8).ravel(),
+                                    iuop_t.view(np.uint8).ravel()]),
+                    np.concatenate([a1t, a2t, t0t.view(np.uint32)])]
+        else:
+            args = [ret_t, islot_t, iuop_t, a1t, a2t, t0t]
+    with st.span("kernel"):
+        if not sharded and compose:
+            fn = _build_kernel_regs_packed(
+                int(K_run), int(Lp), I, max(1, M // 32), int(Sn), R,
+                decomposed, R, unroll, int(Sn) << nc, nc, rn,
+                int(a1t.shape[0]), iuop_t.dtype == np.int16)
+            # [6]: valid, dead, entry mask x4
+            vd = _device_call(st, "wgl_seg", fn, args, lanes=K,
+                              padded=0)
+            return None, K_run, False, vd
+        kern = _build_kernel_regs(K_run, int(Lp), I, max(1, M // 32),
+                                  int(Sn), R, decomposed,
+                                  rounds=R, unroll=unroll,
+                                  J=int(Sn) << nc, nc=nc, rn=rn)
+        put = None
+        if sharded:
+            def put(a):
+                return _shard_args(mesh, mesh_axis, a, 3)
+        T = _device_call(st, "wgl_seg", kern, args, lanes=K,
+                         padded=K_run - K, put=put)[:K] > 0.5  # [K, J, J]
+    return T, K_run, sharded, None
 
 
 @functools.lru_cache(maxsize=8)
 def _build_stack(n: int):
     import jax
     import jax.numpy as jnp
-    return jax.jit(lambda *xs: jnp.stack(xs))
+
+    def wgl_seg_stack(*xs):
+        return jnp.stack(xs)
+
+    return jax.jit(wgl_seg_stack)
 
 
 def _localize_segment(model, spec, ops, fk, seg_ends, dead: int,
@@ -1151,7 +1182,7 @@ def _relaxed_refute(model, spec, history, ops, drop, crashed,
                     crash_uop, inert, seen, rows, states, legal,
                     next_state, *, max_open_bits,
                     target_returns_per_segment, backend_name,
-                    localize, t0):
+                    localize, st):
     """Tier 4 — SOUND REFUTATION under relaxed crash semantics.
 
     Over-approximate every crashed call as an unlimited-use epsilon
@@ -1281,11 +1312,13 @@ def _relaxed_refute(model, spec, history, ops, drop, crashed,
                            crow_t.view(np.uint8).ravel()])
     buf32 = np.concatenate([a1t.ravel(), a2t.ravel(),
                             t0t.view(np.uint32), ctab.ravel()])
-    fn = _build_kernel_regs_relaxed(
-        K, int(Lp), I, max(1, (1 << R) // 32), int(Sn), R,
-        diag_w is not None, R, unroll, U0, wide, int(nC_pad),
-        sn_words=W)
-    vd = np.asarray(fn(buf8, buf32))
+    with st.span("kernel"):
+        fn = _build_kernel_regs_relaxed(
+            K, int(Lp), I, max(1, (1 << R) // 32), int(Sn), R,
+            diag_w is not None, R, unroll, U0, wide, int(nC_pad),
+            sn_words=W)
+        vd = _device_call(st, "wgl_seg_relaxed", fn, [buf8, buf32],
+                          lanes=K, padded=0)
     if int(vd[0]) == 1:
         return None                  # relaxed-valid: proves nothing
     dead = int(vd[1])
@@ -1311,10 +1344,14 @@ def _relaxed_refute(model, spec, history, ops, drop, crashed,
             & 0xFFFFFFFF).astype(np.uint32)[:W] \
         if W > 1 else np.asarray(
             [np.int64(vd[2]) & 0xFFFFFFFF], np.uint32)
-    drow = int(np.asarray(loc_kern(
-        ret_t[:, dead:dead + 1], islot_t[:, dead:dead + 1],
-        iuop_t[:, dead:dead + 1], a1t, a2t, t0t,
-        crow_t[:, dead:dead + 1], ctab, seed)))
+    with st.span("kernel"):
+        drow = int(_device_call(
+            st, "wgl_seg_relaxed", loc_kern,
+            [np.ascontiguousarray(ret_t[:, dead:dead + 1]),
+             np.ascontiguousarray(islot_t[:, dead:dead + 1]),
+             np.ascontiguousarray(iuop_t[:, dead:dead + 1]), a1t, a2t,
+             t0t, np.ascontiguousarray(crow_t[:, dead:dead + 1]), ctab,
+             seed], lanes=1, padded=0))
     if drow >= 0:
         local = int((ret_t[:drow + 1, dead] >= 0).sum()) - 1
         g = seg_lo + local
@@ -1347,7 +1384,9 @@ def _relaxed_refute(model, spec, history, ops, drop, crashed,
         # configs) and, when it finishes, the true minimal witness —
         # the exact relaxed-death op above is always reportable
         from jepsen_tpu.ops import wgl_cpu
-        oracle = wgl_cpu.check(model, history, **wgl_cpu.ARTIFACT_CAPS)
+        with telemetry.checker_stage("wgl_seg", "witness"):
+            oracle = wgl_cpu.check(model, history,
+                                   **wgl_cpu.ARTIFACT_CAPS)
         if oracle.get("valid?") is False:
             for key in ("op", "op_index", "final-paths", "configs"):
                 if key in oracle:
@@ -1359,7 +1398,7 @@ def _relaxed_refute(model, spec, history, ops, drop, crashed,
 
 def _check_crashed_fast(model, spec, history, *, max_states,
                         max_open_bits, target_returns_per_segment,
-                        localize, mesh, mesh_axis, backend_name, t0):
+                        localize, mesh, mesh_axis, backend_name, st):
     """Crash-bearing histories on the segment-parallel engine, in three
     exact tiers (a crashed call may be linearized at any point after
     its invoke, or never — `doc/tutorial/06-refining.md:12-19`):
@@ -1396,9 +1435,13 @@ def _check_crashed_fast(model, spec, history, *, max_states,
     stripped = [o for pos, o in enumerate(ops) if not drop[pos]]
     seen: dict = {}
     rows: list = []
-    fk = _native_scan(stripped, spec, seen, rows, max_open_bits)
-    if fk is False:
-        fk = _fast_scan(stripped, spec, seen, rows, max_open_bits)
+    with st.span("scan"):
+        fk = _native_scan(stripped, spec, seen, rows, max_open_bits)
+        path = "objects"
+        if fk is False:
+            path = "python"
+            fk = _fast_scan(stripped, spec, seen, rows, max_open_bits)
+    st.tally(telemetry.SCAN_KEYS, path=path)
     if fk is None:
         return None              # stripped key still out of scope
 
@@ -1420,12 +1463,11 @@ def _check_crashed_fast(model, spec, history, *, max_states,
     uops = np.asarray(rows, np.int32).reshape(len(rows), 4)
     init = np.asarray(spec.encode(model), np.int32)
     try:
-        states, legal, next_state = _enumerate_states(
-            spec, init, uops, max_states)
+        with st.span("tables"):
+            states, legal, next_state = _enumerate_states(
+                spec, init, uops, max_states)
     except Unsupported:
-        from jepsen_tpu import telemetry as telemetry_mod
-        telemetry_mod.count_fallback("wgl_seg_crash_fast",
-                                     "state-space")
+        telemetry.count_fallback("wgl_seg_crash_fast", "state-space")
         return None
     eye = np.arange(legal.shape[1])
     inert = [u >= 0 and bool(legal[u].all())
@@ -1452,7 +1494,7 @@ def _check_crashed_fast(model, spec, history, *, max_states,
             max_open_bits=max_open_bits,
             target_returns_per_segment=target_returns_per_segment,
             localize=localize, mesh=mesh, mesh_axis=mesh_axis,
-            backend_name=backend_name, t0=t0,
+            backend_name=backend_name, st=st,
             max_crashed=_MAX_CRASHED, escalate=False)
         if res is not None:
             if n_inert:
@@ -1468,7 +1510,7 @@ def _check_crashed_fast(model, spec, history, *, max_states,
         max_open_bits=max_open_bits,
         target_returns_per_segment=target_returns_per_segment,
         localize=False, mesh=mesh, mesh_axis=mesh_axis,
-        backend_name=backend_name, t0=t0, escalate=False)
+        backend_name=backend_name, st=st, escalate=False)
     if res is None:
         # outside the register-delta gate (e.g. concurrency > 8): the
         # stripped twin has no crashes, so the full check() chain (the
@@ -1481,9 +1523,8 @@ def _check_crashed_fast(model, spec, history, *, max_states,
                         target_returns_per_segment,
                         localize=False, mesh=mesh, mesh_axis=mesh_axis)
         except Unsupported:
-            from jepsen_tpu import telemetry as telemetry_mod
-            telemetry_mod.count_fallback("wgl_seg_crash_fast",
-                                         "stripped-chain")
+            telemetry.count_fallback("wgl_seg_crash_fast",
+                                     "stripped-chain")
             res = None
     if res is not None and res.get("valid?") is True:
         res["crashed_ignored"] = len(crashed)
@@ -1498,12 +1539,12 @@ def _check_crashed_fast(model, spec, history, *, max_states,
         seen, rows, states, legal, next_state,
         max_open_bits=max_open_bits,
         target_returns_per_segment=target_returns_per_segment,
-        backend_name=backend_name, localize=localize, t0=t0)
+        backend_name=backend_name, localize=localize, st=st)
 
 
 def _check_deep(model, ops, fk, legal, next_state,
                 diag_w, const_w, const_t0, *, R, Sn, nc, localize,
-                backend_name, t0):
+                backend_name, st):
     """Deep-overlap single history on the ops.wgl_deep Pallas
     megakernel (R > the register-delta gate, up to the word-split
     boundary planner.deep_r_max(backend, 1);
@@ -1516,19 +1557,23 @@ def _check_deep(model, ops, fk, legal, next_state,
             R, Sn, legal.shape[0], True, backend_name):
         return None
     I = min(2, R) if R else 1
-    if fk.deltas is not None:
-        # columnar scan: the delta stream feeds the layout directly
-        ret_t, islot_t, iuop_t, Lp = _pack_regs_single(
-            fk, [fk.n_rets], R, int(legal.shape[0]), I)
-    else:
-        # crash-tolerant Python scan: snapshot-diff packer
-        ret_t, islot_t, iuop_t, Lp = _pack_regs(
-            [(0, fk)], 1, R, int(legal.shape[0]), I)
-    a1t, a2t, t0t = _pack_uop_tables(
-        legal, next_state, diag_w, const_w, const_t0)
-    t_plan = time.monotonic() - t0
-    res = wgl_deep.check_tables(ret_t, islot_t, iuop_t, a1t, a2t, t0t,
-                                R, Sn)
+    with st.span("pack"):
+        if fk.deltas is not None:
+            # columnar scan: the delta stream feeds the layout directly
+            ret_t, islot_t, iuop_t, Lp = _pack_regs_single(
+                fk, [fk.n_rets], R, int(legal.shape[0]), I)
+        else:
+            # crash-tolerant Python scan: snapshot-diff packer
+            ret_t, islot_t, iuop_t, Lp = _pack_regs(
+                [(0, fk)], 1, R, int(legal.shape[0]), I)
+    with st.span("tables"):
+        a1t, a2t, t0t = _pack_uop_tables(
+            legal, next_state, diag_w, const_w, const_t0)
+    with st.span("kernel"):
+        # the deep program's own compact wire packing rides inside
+        res = wgl_deep.check_tables(ret_t, islot_t, iuop_t, a1t, a2t,
+                                    t0t, R, Sn)
+    st.tally(telemetry.DEVICE_CALLS, engine="wgl_deep")
     result: dict[str, Any] = {
         "valid?": res["valid?"],
         "op_count": fk.n_calls,
@@ -1536,8 +1581,6 @@ def _check_deep(model, ops, fk, legal, next_state,
         "engine": "wgl_deep",
         "max_open": R,
         "states": Sn,
-        "time_plan_s": t_plan,
-        "time_kernel_s": res["time_kernel_s"],
     }
     for key in ("deep_variant", "shards"):   # word-split provenance
         if key in res:
@@ -1563,8 +1606,9 @@ def _check_deep(model, ops, fk, legal, next_state,
         # are already exact without it
         from jepsen_tpu.ops import wgl_cpu
         prefix = ops if pos is None else ops[:pos + 1]
-        oracle = wgl_cpu.check(model, History(list(prefix)),
-                               **wgl_cpu.ARTIFACT_CAPS)
+        with telemetry.checker_stage("wgl_seg", "witness"):
+            oracle = wgl_cpu.check(model, History(list(prefix)),
+                                   **wgl_cpu.ARTIFACT_CAPS)
         if oracle.get("valid?") is False:
             for key in ("final-paths", "configs"):
                 if key in oracle:
@@ -1580,24 +1624,27 @@ def _check_deep(model, ops, fk, legal, next_state,
 
 def _check_fast(model, spec, history, *, max_states, max_open_bits,
                 target_returns_per_segment, localize, mesh, mesh_axis,
-                backend_name, t0, max_crashed: int = 0,
+                backend_name, st, max_crashed: int = 0,
                 escalate: bool = True):
     """Single-history fast path: one fused host scan (the native C
     scanner when available) straight into per-segment register-delta
     lanes — no per-op Python objects.  Crash-bearing histories escalate
     to _check_crashed_fast (inert dropping / bounded kernel / stripped
     validity proof).  Returns None when out of scope so check() takes
-    the plan() route, which raises the descriptive Unsupported."""
+    the plan() route, which raises the descriptive Unsupported.  Stage
+    seconds go to `st`: scan, tables, pack, kernel."""
     seen: dict = {}
     rows: list = []
-    ops = history.ops if isinstance(history, History) else \
-        History(history).ops
-    fk = _scan_history(history, ops, spec, seen, rows, max_open_bits,
-                       want_snaps=(mesh is not None))
-    if fk is None and max_crashed:
-        # crash-tolerant scan (Python twin; permanent high slots)
-        fk = _fast_scan(history, spec, seen, rows, max_open_bits,
-                        max_crashed=max_crashed)
+    with st.span("scan"):
+        ops = history.ops if isinstance(history, History) else \
+            History(history).ops
+        fk = _scan_history(history, ops, spec, seen, rows, max_open_bits,
+                           want_snaps=(mesh is not None), st=st)
+        if fk is None and max_crashed:
+            # crash-tolerant scan (Python twin; permanent high slots)
+            fk = _fast_scan(history, spec, seen, rows, max_open_bits,
+                            max_crashed=max_crashed)
+            st.tally(telemetry.SCAN_KEYS, path="python")
     if fk is None:
         if escalate:
             return _check_crashed_fast(
@@ -1605,75 +1652,79 @@ def _check_fast(model, spec, history, *, max_states, max_open_bits,
                 max_open_bits=max_open_bits,
                 target_returns_per_segment=target_returns_per_segment,
                 localize=localize, mesh=mesh, mesh_axis=mesh_axis,
-                backend_name=backend_name, t0=t0)
+                backend_name=backend_name, st=st)
         return None
     if fk.n_calls == 0:
         return {"valid?": True, "op_count": 0, "backend": backend_name,
                 "engine": "wgl_seg"}
     nc = int(fk.nc)
     rn = int(fk.rn) if fk.rn is not None else int(fk.max_open)
-    uops = np.asarray(rows, np.int32).reshape(len(rows), 4)
-    init = np.asarray(spec.encode(model), np.int32)
     try:
-        states, legal, next_state = _enumerate_states(
-            spec, init, uops, max_states)
+        with st.span("tables"):
+            uops = np.asarray(rows, np.int32).reshape(len(rows), 4)
+            init = np.asarray(spec.encode(model), np.int32)
+            states, legal, next_state = _enumerate_states(
+                spec, init, uops, max_states)
+            diag_w, const_w, const_t0 = _decompose(legal, next_state)
+            Sn = states.shape[0]
+            R = rn + nc if nc else int(fk.max_open)
+            # THE routing decision (ops.planner), over the tables:
+            # register-delta segment kernel vs deep-overlap Pallas
+            # megakernel (crashed calls are permanent slots there; a
+            # crash set too wide for the J = Sn * 2^nc entry axis
+            # diverts the same way) vs the candidate-table plan() route
+            # (None).  The JEPSEN_TPU_NO_REGS / JEPSEN_TPU_DYN_ROUNDS
+            # escape hatches keep their documented meaning (the
+            # candidate-table path) via the planner's prune table.
+            route = planner.plan_engines(
+                planner.Shape(kind="linear",
+                              R=rn if nc else int(fk.max_open),
+                              crashes=nc, Sn=int(Sn),
+                              U=int(legal.shape[0]),
+                              decomposed=diag_w is not None,
+                              n_ops=int(fk.n_calls),
+                              mesh=None if mesh is None else int(
+                                  np.prod(mesh.devices.shape)),
+                              max_states=max_states,
+                              max_open_bits=max_open_bits),
+                backend=backend_name)
     except Unsupported:
-        from jepsen_tpu import telemetry as telemetry_mod
-        telemetry_mod.count_fallback("wgl_seg_regs", "state-space")
+        telemetry.count_fallback("wgl_seg_regs", "state-space")
         return None
-    Sn = states.shape[0]
-    R = rn + nc if nc else int(fk.max_open)
-    diag_w, const_w, const_t0 = _decompose(legal, next_state)
-    # THE routing decision (ops.planner): register-delta segment kernel
-    # vs deep-overlap Pallas megakernel (crashed calls are permanent
-    # slots there; a crash set too wide for the J = Sn * 2^nc entry
-    # axis diverts the same way) vs the candidate-table plan() route
-    # (None).  The JEPSEN_TPU_NO_REGS / JEPSEN_TPU_DYN_ROUNDS escape
-    # hatches keep their documented meaning (the candidate-table path)
-    # via the planner's prune table.
-    route = planner.plan_engines(
-        planner.Shape(kind="linear", R=rn if nc else int(fk.max_open),
-                      crashes=nc, Sn=int(Sn), U=int(legal.shape[0]),
-                      decomposed=diag_w is not None,
-                      n_ops=int(fk.n_calls),
-                      mesh=None if mesh is None else int(
-                          np.prod(mesh.devices.shape)),
-                      max_states=max_states,
-                      max_open_bits=max_open_bits),
-        backend=backend_name)
     if route.engine != "wgl_seg_regs":
         if route.engine in ("wgl_deep", "wgl_deep_split") \
                 and mesh is None:
             r = _check_deep(
                 model, ops, fk, legal, next_state,
                 diag_w, const_w, const_t0, R=R, Sn=Sn, nc=nc,
-                localize=localize, backend_name=backend_name, t0=t0)
+                localize=localize, backend_name=backend_name, st=st)
             if isinstance(r, dict):
                 r["_plan"] = route
             return r
         return None
 
-    # segment at quiescent cuts, >= target returns per segment
-    cuts = np.asarray(fk.cuts, np.int32)
-    if len(cuts) != fk.n_rets or not fk.n_rets or cuts[-1] != 1:
-        return None                  # defensive: malformed cut stream
-    seg_ends = _segment_ends(cuts, target_returns_per_segment)
-    K = len(seg_ends)
-    tables = seg_fk = None
-    if fk.deltas is not None and nc == 0 and mesh is None:
-        # columnar scan supplied the invoke-delta stream: pack tables
-        # directly, skipping the per-segment slicing round trip
-        tables = _pack_regs_single(fk, seg_ends, R,
-                                   int(legal.shape[0]),
-                                   min(2, R) if R else 1)
-    else:
-        seg_fk = _segments_from_fk(fk, R, seg_ends)
-    t_plan = time.monotonic() - t0
+    with st.span("pack"):
+        # segment at quiescent cuts, >= target returns per segment
+        cuts = np.asarray(fk.cuts, np.int32)
+        if len(cuts) != fk.n_rets or not fk.n_rets or cuts[-1] != 1:
+            return None              # defensive: malformed cut stream
+        seg_ends = _segment_ends(cuts, target_returns_per_segment)
+        K = len(seg_ends)
+        tables = seg_fk = None
+        if fk.deltas is not None and nc == 0 and mesh is None:
+            # columnar scan supplied the invoke-delta stream: pack
+            # tables directly, skipping the per-segment slicing round
+            # trip
+            tables = _pack_regs_single(fk, seg_ends, R,
+                                       int(legal.shape[0]),
+                                       min(2, R) if R else 1)
+        else:
+            seg_fk = _segments_from_fk(fk, R, seg_ends)
 
-    T, t_kernel, sharded, verdict = _run_seg_regs(
+    T, _, sharded, verdict = _run_seg_regs(
         seg_fk, K, R, legal.shape[0], Sn, 1 << R, legal, next_state,
         diag_w, const_w, const_t0, mesh, mesh_axis, nc=nc, rn=rn,
-        tables=tables)
+        tables=tables, st=st)
     entry_mask = None
     if verdict is None:
         dead_segment = _compose_transfer(T, Sn << nc)
@@ -1689,8 +1740,6 @@ def _check_fast(model, spec, history, *, max_states, max_open_bits,
         "segments": K,
         "states": Sn,
         "sharded": sharded,
-        "time_plan_s": t_plan,
-        "time_kernel_s": t_kernel,
         "_plan": route.refine(
             bucket=("wgl_seg_regs", R, int(Sn), int(legal.shape[0]),
                     K)),
@@ -1702,17 +1751,19 @@ def _check_fast(model, spec, history, *, max_states, max_open_bits,
         result["dead_segment"] = dead_segment
         if localize:
             oracle = None
-            if entry_mask is not None and nc == 0:
-                # segment-local replay from the device's entry mask —
-                # O(segment) instead of O(prefix-through-witness)
-                oracle = _localize_segment(model, spec, ops, fk,
-                                           seg_ends, dead_segment,
-                                           entry_mask, states)
-            if oracle is None:
-                # fallback: whole-history oracle (terminates at the
-                # first non-linearizable op)
-                from jepsen_tpu.ops import wgl_cpu
-                oracle = wgl_cpu.check(model, history)
+            with telemetry.checker_stage("wgl_seg", "witness"):
+                if entry_mask is not None and nc == 0:
+                    # segment-local replay from the device's entry
+                    # mask — O(segment) instead of
+                    # O(prefix-through-witness)
+                    oracle = _localize_segment(model, spec, ops, fk,
+                                               seg_ends, dead_segment,
+                                               entry_mask, states)
+                if oracle is None:
+                    # fallback: whole-history oracle (terminates at the
+                    # first non-linearizable op)
+                    from jepsen_tpu.ops import wgl_cpu
+                    oracle = wgl_cpu.check(model, history)
             for key in ("op", "op_index", "final-paths", "configs"):
                 if key in oracle:
                     result[key] = oracle[key]
@@ -1727,12 +1778,19 @@ def check(model, history, *, max_states: int = 64, max_open_bits: int = 10,
     carries (jepsen_tpu.telemetry): which engine produced it, why, the
     fallback chain below it, and the env knobs in effect — so
     `results.json` explains its own dispatch instead of requiring the
-    reader to re-derive eight modules' worth of gating."""
-    from jepsen_tpu import telemetry as telemetry_mod
+    reader to re-derive eight modules' worth of gating.
+
+    The call's stage seconds (scan, tables, pack, kernel on the fast
+    path; plan, pack, kernel on the plan() route) ride the verdict's
+    `stages` and `jepsen_stage_seconds_total`; its work counts reach
+    the registry once, here."""
+    st = telemetry.Stages()
     r = _check_impl(model, history, max_states=max_states,
                     max_open_bits=max_open_bits,
                     target_returns_per_segment=target_returns_per_segment,
-                    localize=localize, mesh=mesh, mesh_axis=mesh_axis)
+                    localize=localize, mesh=mesh, mesh_axis=mesh_axis,
+                    st=st)
+    st.flush()
     if isinstance(r, dict):
         # the fast path stashed the planner-emitted Plan; the crash
         # tiers and the plan() route synthesize one so EVERY verdict
@@ -1757,7 +1815,7 @@ def check(model, history, *, max_states: int = 64, max_open_bits: int = 10,
             if tier_why:
                 pl = pl.refine(why=str(tier_why))
         if "dispatch" not in r:
-            telemetry_mod.attach_dispatch(
+            telemetry.attach_dispatch(
                 [r],
                 pl.record(
                     engine=r.get("engine", "wgl_seg"),
@@ -1766,8 +1824,7 @@ def check(model, history, *, max_states: int = 64, max_open_bits: int = 10,
                     batch=1,
                     mesh=(getattr(mesh, "shape", None)
                           if mesh is not None else None)),
-                stages={"plan": r.get("time_plan_s"),
-                        "kernel": r.get("time_kernel_s")})
+                stages=st.seconds)
     return r
 
 
@@ -1775,7 +1832,8 @@ def _check_impl(model, history, *, max_states: int = 64,
                 max_open_bits: int = 10,
                 target_returns_per_segment: int = 256,
                 localize: bool = True, mesh=None,
-                mesh_axis: Optional[str] = None) -> dict[str, Any]:
+                mesh_axis: Optional[str] = None,
+                st: Optional[telemetry.Stages] = None) -> dict[str, Any]:
     """Segment-parallel linearizability check.  Returns a knossos-shaped
     analysis map (same keys as ops.wgl.check).  Crashed (:info) calls
     are handled exactly (inert dropping / bounded crash kernel /
@@ -1795,7 +1853,7 @@ def _check_impl(model, history, *, max_states: int = 64,
     if spec is None:
         raise Unsupported(f"model {model!r} has no device spec")
 
-    t0 = time.monotonic()
+    st = telemetry.Stages() if st is None else st
     backend_name = jax.default_backend()
     if (not isinstance(history, PreparedHistory)
             and getattr(spec, "encode_op", None) is None):
@@ -1804,22 +1862,23 @@ def _check_impl(model, history, *, max_states: int = 64,
             max_open_bits=max_open_bits,
             target_returns_per_segment=target_returns_per_segment,
             localize=localize, mesh=mesh, mesh_axis=mesh_axis,
-            backend_name=backend_name, t0=t0)
+            backend_name=backend_name, st=st)
         if fast is not None:
             return fast
-    prep = history if isinstance(history, PreparedHistory) else prepare(history)
+    with st.span("plan"):
+        prep = history if isinstance(history, PreparedHistory) \
+            else prepare(history)
+        if prep.calls:
+            pl = plan(prep, spec, model, max_states=max_states,
+                      max_open_bits=max_open_bits,
+                      target_returns_per_segment=target_returns_per_segment)
     if not prep.calls:
         return {"valid?": True, "op_count": 0, "backend": backend_name,
                 "engine": "wgl_seg"}
-
-    pl = plan(prep, spec, model, max_states=max_states,
-              max_open_bits=max_open_bits,
-              target_returns_per_segment=target_returns_per_segment)
     K, L = pl.ret_slot.shape
     C = pl.cand_slot.shape[2]
     Sn = pl.states.shape[0]
     M = 1 << pl.max_open
-    t_plan = time.monotonic() - t0
 
     # Register-delta kernel for segments (one lane per segment, J=Sn
     # entry states) under the same gate as the batch path; the
@@ -1829,9 +1888,9 @@ def _check_impl(model, history, *, max_states: int = 64,
     U = pl.legal.shape[0]
     dead_segment = None
     if pl.seg_fk is not None and _regs_eligible(R, U, Sn, decomposed):
-        T, t_kernel, sharded, verdict = _run_seg_regs(
+        T, _, sharded, verdict = _run_seg_regs(
             pl.seg_fk, K, R, U, Sn, M, pl.legal, pl.next_state,
-            pl.diag_w, pl.const_w, pl.const_t0, mesh, mesh_axis)
+            pl.diag_w, pl.const_w, pl.const_t0, mesh, mesh_axis, st=st)
         if verdict is not None:
             dead_segment = int(verdict[1])
     else:
@@ -1844,27 +1903,30 @@ def _check_impl(model, history, *, max_states: int = 64,
             m = int(mesh.shape[mesh_axis])
             K_run = ((K + m - 1) // m) * m
             sharded = True
-        ret_slot, cand_slot, cand_uop = \
-            pl.ret_slot, pl.cand_slot, pl.cand_uop
-        if K_run != K:
-            ret_slot = np.concatenate(
-                [ret_slot, np.full((K_run - K, L), -1, np.int32)])
-            cand_slot = np.concatenate(
-                [cand_slot, np.zeros((K_run - K, L, C), np.int32)])
-            cand_uop = np.concatenate(
-                [cand_uop, np.full((K_run - K, L, C), -1, np.int32)])
-        ret_t = np.ascontiguousarray(ret_slot.T)             # [L, K]
-        cslot_t = np.ascontiguousarray(cand_slot.transpose(1, 0, 2))
-        cuop_t = np.ascontiguousarray(cand_uop.transpose(1, 0, 2))
-        t1 = time.monotonic()
-        kern, args, n_sharded = _dispatch_kernel(
-            K_run, int(L), int(C), int(M), int(Sn), R,
-            int(Sn), ret_t, cslot_t, cuop_t, pl.legal, pl.next_state,
-            pl.diag_w, pl.const_w, pl.const_t0)
+        with st.span("pack"):
+            ret_slot, cand_slot, cand_uop = \
+                pl.ret_slot, pl.cand_slot, pl.cand_uop
+            if K_run != K:
+                ret_slot = np.concatenate(
+                    [ret_slot, np.full((K_run - K, L), -1, np.int32)])
+                cand_slot = np.concatenate(
+                    [cand_slot, np.zeros((K_run - K, L, C), np.int32)])
+                cand_uop = np.concatenate(
+                    [cand_uop, np.full((K_run - K, L, C), -1, np.int32)])
+            ret_t = np.ascontiguousarray(ret_slot.T)         # [L, K]
+            cslot_t = np.ascontiguousarray(cand_slot.transpose(1, 0, 2))
+            cuop_t = np.ascontiguousarray(cand_uop.transpose(1, 0, 2))
+            kern, args, n_sharded = _dispatch_kernel(
+                K_run, int(L), int(C), int(M), int(Sn), R,
+                int(Sn), ret_t, cslot_t, cuop_t, pl.legal, pl.next_state,
+                pl.diag_w, pl.const_w, pl.const_t0)
+        put = None
         if sharded:
-            args = _shard_args(mesh, mesh_axis, args, n_sharded)
-        T = np.asarray(kern(*args))[:K] > 0.5                # [K, Sn, Sn]
-        t_kernel = time.monotonic() - t1
+            def put(a):
+                return _shard_args(mesh, mesh_axis, a, n_sharded)
+        with st.span("kernel"):
+            T = _device_call(st, "wgl_seg", kern, args, lanes=K,
+                             padded=K_run - K, put=put)[:K] > 0.5
 
     if dead_segment is None:
         dead_segment = _compose_transfer(T, Sn)
@@ -1877,8 +1939,6 @@ def _check_impl(model, history, *, max_states: int = 64,
         "segments": K,
         "states": Sn,
         "sharded": sharded,
-        "time_plan_s": t_plan,
-        "time_kernel_s": t_kernel,
     }
     if dead_segment >= 0:
         result["anomaly"] = "nonlinearizable"
@@ -1893,28 +1953,14 @@ def _check_impl(model, history, *, max_states: int = 64,
                 last = prep.calls[end_call]
                 cutoff = (last.completion.index
                           if last.completion is not None else last.op.index)
-                prefix = History(
-                    [o for o in history if o.index <= cutoff])
-                oracle = wgl_cpu.check(model, prefix)
+                with telemetry.checker_stage("wgl_seg", "witness"):
+                    prefix = History(
+                        [o for o in history if o.index <= cutoff])
+                    oracle = wgl_cpu.check(model, prefix)
                 for key in ("op", "op_index", "final-paths", "configs"):
                     if key in oracle:
                         result[key] = oracle[key]
     return result
-
-
-def _stats_clock(stats: Optional[dict]):
-    """(now_fn, acc_fn) pair for the pipelines' per-stage host-time
-    decomposition — ONE definition so wgl_seg's and wgl_deep's stage
-    protocols cannot drift.  acc(key, t0) adds now-t0 to stats[key]
-    (no-op when stats is None) and returns the new t0."""
-    mt = time.monotonic
-
-    def acc(key, t0):
-        if stats is not None:
-            stats[key] = stats.get(key, 0.0) + (mt() - t0)
-        return mt()
-
-    return mt, acc
 
 
 _fill_pool_lock = threading.Lock()
@@ -1977,11 +2023,11 @@ def check_pipeline(model, histories, *, max_states: int = 64,
     spec = model.device_spec()
     if spec is None:
         raise Unsupported(f"model {model!r} has no device spec")
-    # stage timings are ALWAYS collected now (the dict costs a handful
-    # of monotonic() reads per group): every pipelined verdict carries
-    # its stage decomposition + dispatch record (telemetry, ISSUE 4)
-    stats = {} if stats is None else stats
-    _mt, _acc = _stats_clock(stats)
+    # stage timings are ALWAYS collected (a handful of spans per
+    # group): every pipelined verdict carries its stage decomposition +
+    # dispatch record
+    st = telemetry.Stages(stats)
+    stats = st.seconds
     backend_name = jax.default_backend()
     n = len(histories)
     results: list = [None] * n
@@ -2036,15 +2082,22 @@ def check_pipeline(model, histories, *, max_states: int = 64,
             if isinstance(h, PreparedHistory):
                 strag.append(i)
                 continue
-            t0 = _mt()
-            # fast path: ONE C pass from packed columns to the wire
-            # layout (scan + segmentation + row streams fused)
-            sk = _native_scan_streams(
-                h.packed_columns() if isinstance(h, History) else None,
-                spec, seen, rows, max_open_bits,
-                target_returns_per_segment)
-            if sk is not None and sk is not False:
-                t0 = _acc("scan", t0)
+            with st.span("scan"):
+                # fast path: ONE C pass from packed columns to the wire
+                # layout (scan + segmentation + row streams fused)
+                sk = _native_scan_streams(
+                    h.packed_columns() if isinstance(h, History)
+                    else None, spec, seen, rows, max_open_bits,
+                    target_returns_per_segment)
+                streamed = sk is not None and sk is not False
+                if not streamed:
+                    ops = h.ops if isinstance(h, History) \
+                        else History(h).ops
+                    fk = _scan_history(h, ops, spec, seen, rows,
+                                       max_open_bits, want_snaps=False,
+                                       st=st)
+            if streamed:
+                st.tally(telemetry.SCAN_KEYS, path="columnar-stream")
                 if sk.n_calls == 0:
                     results[i] = {"valid?": True, "op_count": 0,
                                   "backend": backend_name,
@@ -2052,10 +2105,6 @@ def check_pipeline(model, histories, *, max_states: int = 64,
                     continue
                 grp.append((i, sk, sk.seg_ends, sk))
                 continue
-            ops = h.ops if isinstance(h, History) else History(h).ops
-            fk = _scan_history(h, ops, spec, seen, rows,
-                               max_open_bits, want_snaps=False)
-            t0 = _acc("scan", t0)
             if fk is None:
                 strag.append(i)
                 continue
@@ -2064,73 +2113,77 @@ def check_pipeline(model, histories, *, max_states: int = 64,
                               "backend": backend_name,
                               "engine": "wgl_seg"}
                 continue
-            cuts = np.asarray(fk.cuts, np.int32)
-            if len(cuts) != fk.n_rets or not fk.n_rets \
-                    or cuts[-1] != 1 or fk.deltas is None:
+            with st.span("segment"):
+                cuts = np.asarray(fk.cuts, np.int32)
+                cut_ok = (len(cuts) == fk.n_rets and fk.n_rets
+                          and cuts[-1] == 1 and fk.deltas is not None)
+                if cut_ok:
+                    seg_ends = _segment_ends(cuts,
+                                             target_returns_per_segment)
+            if not cut_ok:
                 strag.append(i)
                 continue
-            seg_ends = _segment_ends(cuts, target_returns_per_segment)
-            t0 = _acc("segment", t0)
-            lay = _RegsLayout(fk, seg_ends, 1)
-            _acc("layout", t0)
+            with st.span("layout"):
+                lay = _RegsLayout(fk, seg_ends, 1)
             grp.append((i, fk, seg_ends, lay))
         if not grp:
             continue
 
         # (re)build tables/kernel if this group grew anything
-        t0 = _mt()
-        if len(rows) != U_at:
-            try:
-                Sn = refresh_tables()
-            except Unsupported:
-                # state space outgrew max_states: this group (and any
-                # later one — the alphabet only grows) goes through
-                # check()'s own fallback chain
-                from jepsen_tpu import telemetry as telemetry_mod
-                telemetry_mod.count_fallback("wgl_seg_pipeline",
+        with st.span("tables"):
+            off = False
+            if len(rows) != U_at:
+                try:
+                    Sn = refresh_tables()
+                except Unsupported:
+                    # state space outgrew max_states: this group (and
+                    # any later one — the alphabet only grows) goes
+                    # through check()'s own fallback chain
+                    telemetry.count_fallback("wgl_seg_pipeline",
                                              "state-space")
-                strag.extend(i for i, *_ in grp)
-                continue
-        R_g = max(fk.max_open for _, fk, _, _ in grp)
-        U = int(legal.shape[0])
-        if not _regs_eligible(max(R_g, R_cur), U, Sn,
-                              diag_w is not None):
-            # this group falls off the batched engine (deep overlap /
-            # undecomposable growth): send it through check(), which
-            # owns the full fallback chain, and keep streaming
+                    off = True
+            if not off:
+                R_g = max(fk.max_open for _, fk, _, _ in grp)
+                U = int(legal.shape[0])
+                # a group off the batched engine (deep overlap /
+                # undecomposable growth) goes through check(), which
+                # owns the full fallback chain, and streaming goes on
+                off = not _regs_eligible(max(R_g, R_cur), U, Sn,
+                                         diag_w is not None)
+            if not off:
+                grow = False
+                for _, fk, seg_ends, filler in grp:
+                    if isinstance(filler, _StreamKey):
+                        lp, k, rp = filler.lp_min, filler.k, filler.rtot
+                    else:
+                        lp, k = filler.lp_min, filler.k
+                        rp = int(filler.rows_per_key.sum()) if k else 0
+                    if lp > Lp_c or k > K_c or rp > Rp_c:
+                        grow = True
+                        Lp_c = max(Lp_c, lp)
+                        K_c = max(K_c, k)
+                        Rp_c = max(Rp_c, rp)
+                if R_g > R_cur:
+                    R_cur = R_g
+                    fn = None
+                if grow:
+                    Lp_c = _pad_len(Lp_c)
+                    K_c = ((K_c + 63) // 64) * 64
+                    Rp_c = ((Rp_c + 8191) // 8192) * 8192
+                    fn = None
+                if fn is None:
+                    spec_rounds = min(R_cur, spec_rounds_env)
+                    fn = planner.compiled(
+                        "wgl_seg_pipeline",
+                        (G, K_c, Lp_c, R_cur, int(Sn), U, Rp_c,
+                         spec_rounds, unroll, diag_w is not None),
+                        _build_kernel_regs_group_c,
+                        G, K_c, Lp_c, max(1, (1 << R_cur) // 32),
+                        int(Sn), R_cur, diag_w is not None, spec_rounds,
+                        unroll, U, Rp_c)
+        if off:
             strag.extend(i for i, *_ in grp)
             continue
-        grow = False
-        for _, fk, seg_ends, filler in grp:
-            if isinstance(filler, _StreamKey):
-                lp, k, rp = filler.lp_min, filler.k, filler.rtot
-            else:
-                lp, k = filler.lp_min, filler.k
-                rp = int(filler.rows_per_key.sum()) if k else 0
-            if lp > Lp_c or k > K_c or rp > Rp_c:
-                grow = True
-                Lp_c = max(Lp_c, lp)
-                K_c = max(K_c, k)
-                Rp_c = max(Rp_c, rp)
-        if R_g > R_cur:
-            R_cur = R_g
-            fn = None
-        if grow:
-            Lp_c = _pad_len(Lp_c)
-            K_c = ((K_c + 63) // 64) * 64
-            Rp_c = ((Rp_c + 8191) // 8192) * 8192
-            fn = None
-        if fn is None:
-            spec_rounds = min(R_cur, spec_rounds_env)
-            fn = planner.compiled(
-                "wgl_seg_pipeline",
-                (G, K_c, Lp_c, R_cur, int(Sn), U, Rp_c, spec_rounds,
-                 unroll, diag_w is not None),
-                _build_kernel_regs_group_c,
-                G, K_c, Lp_c, max(1, (1 << R_cur) // 32), int(Sn),
-                R_cur, diag_w is not None, spec_rounds, unroll, U,
-                Rp_c)
-        t0 = _acc("tables", t0)
 
         def _layout_fill(args):
             i, fk, seg_ends, filler = args
@@ -2143,82 +2196,81 @@ def check_pipeline(model, histories, *, max_states: int = 64,
         # layout+fill are numpy-bound (GIL-releasing): a small pool
         # packs the group's histories in parallel while the device
         # executes the previous group
-        if len(grp) > 1:
-            filled = list(_fill_pool().map(_layout_fill, grp))
-        else:
-            filled = [_layout_fill(grp[0])]
-        blocks = []
-        for (i, fk, seg_ends, lay), (i2, k_segs, buf) in zip(grp, filled):
-            assert i == i2
-            metas[i] = (fk, seg_ends, k_segs)
-            blocks.append(buf)
-        while len(blocks) < G:        # short tail group: padding lane
-            blocks.append(blocks[0])  # (extra verdicts discarded)
-        t0 = _acc("fill", t0)
-        payload = np.concatenate(blocks)
-        # measured wire traffic: the compact event blocks + the uop
-        # tables shipped with every group (bench.py reports MB/s over
-        # the dispatch+fetch window from this)
-        stats["wire_bytes"] = (stats.get("wire_bytes", 0)
-                               + payload.nbytes + buf32.nbytes)
-        dispatched.append(
-            (fn(payload, buf32),
-             [i for i, *_ in grp], spec_rounds, R_cur, Sn, states))
-        _acc("dispatch", t0)
+        with st.span("fill"):
+            if len(grp) > 1:
+                filled = list(_fill_pool().map(_layout_fill, grp))
+            else:
+                filled = [_layout_fill(grp[0])]
+            blocks = []
+            for (i, fk, seg_ends, lay), (i2, k_segs, buf) \
+                    in zip(grp, filled):
+                assert i == i2
+                metas[i] = (fk, seg_ends, k_segs)
+                blocks.append(buf)
+            while len(blocks) < G:        # short tail group: padding
+                blocks.append(blocks[0])  # lane (verdicts discarded)
+        with st.span("dispatch"):
+            payload = np.concatenate(blocks)
+            # measured wire traffic: the compact event blocks + the uop
+            # tables shipped with every group (bench.py reports MB/s
+            # over the dispatch+fetch window from this)
+            stats["wire_bytes"] = (stats.get("wire_bytes", 0)
+                                   + payload.nbytes + buf32.nbytes)
+            dispatched.append(
+                (fn(payload, buf32),
+                 [i for i, *_ in grp], spec_rounds, R_cur, Sn, states))
 
     if dispatched:
-        t0 = _mt()
-        stacked = _build_stack(len(dispatched))(
-            *[d for d, *_ in dispatched])
-        vds = np.asarray(stacked)                 # ONE fetch
-        t0 = _acc("fetch", t0)
-        for g, (_, idxs, sr, R_g_disp, Sn_g, states_g) \
-                in enumerate(dispatched):
-            vd = vds[g].reshape(-1, 6)
-            for j, i in enumerate(idxs):
-                valid = bool(vd[j, 0])
-                fk, seg_ends_i, k_segs = metas[i]
-                if not valid and sr < R_g_disp:
-                    # speculative death is inconclusive: exact re-run
-                    # (rare on valid workloads; carries the witness)
-                    res = check(model, histories[i],
-                                max_states=max_states,
-                                max_open_bits=max_open_bits,
-                                target_returns_per_segment=
-                                target_returns_per_segment,
-                                localize=localize)
-                    res["pipelined"] = True
-                    res["speculation"] = "exact-rerun"
+        with st.span("fetch"):
+            stacked = _build_stack(len(dispatched))(
+                *[d for d, *_ in dispatched])
+            vds = np.asarray(stacked)                 # ONE fetch
+        with st.span("assemble"):
+            for g, (_, idxs, sr, R_g_disp, Sn_g, states_g) \
+                    in enumerate(dispatched):
+                vd = vds[g].reshape(-1, 6)
+                for j, i in enumerate(idxs):
+                    valid = bool(vd[j, 0])
+                    fk, seg_ends_i, k_segs = metas[i]
+                    if not valid and sr < R_g_disp:
+                        # speculative death is inconclusive: exact re-run
+                        # (rare on valid workloads; carries the witness)
+                        res = check(model, histories[i],
+                                    max_states=max_states,
+                                    max_open_bits=max_open_bits,
+                                    target_returns_per_segment=
+                                    target_returns_per_segment,
+                                    localize=localize)
+                        res["pipelined"] = True
+                        res["speculation"] = "exact-rerun"
+                        results[i] = res
+                        continue
+                    res = {"valid?": valid, "op_count": fk.n_calls,
+                           "backend": backend_name, "engine": "wgl_seg",
+                           "segments": k_segs, "states": int(Sn_g),
+                           "pipelined": True}
+                    if not valid:
+                        res["anomaly"] = "nonlinearizable"
+                        res["dead_segment"] = int(vd[j, 1])
+                        if localize:
+                            hi = histories[i]
+                            h_ops = hi.ops if isinstance(hi, History) \
+                                else History(hi).ops
+                            oracle = _localize_segment(
+                                model, spec, h_ops, fk, seg_ends_i,
+                                int(vd[j, 1]), vd[j, 2:6], states_g)
+                            if oracle is None:
+                                from jepsen_tpu.ops import wgl_cpu
+                                oracle = wgl_cpu.check(model, histories[i])
+                            for key in ("op", "op_index", "final-paths",
+                                        "configs"):
+                                if key in oracle:
+                                    res[key] = oracle[key]
                     results[i] = res
-                    continue
-                res = {"valid?": valid, "op_count": fk.n_calls,
-                       "backend": backend_name, "engine": "wgl_seg",
-                       "segments": k_segs, "states": int(Sn_g),
-                       "pipelined": True}
-                if not valid:
-                    res["anomaly"] = "nonlinearizable"
-                    res["dead_segment"] = int(vd[j, 1])
-                    if localize:
-                        hi = histories[i]
-                        h_ops = hi.ops if isinstance(hi, History) \
-                            else History(hi).ops
-                        oracle = _localize_segment(
-                            model, spec, h_ops, fk, seg_ends_i,
-                            int(vd[j, 1]), vd[j, 2:6], states_g)
-                        if oracle is None:
-                            from jepsen_tpu.ops import wgl_cpu
-                            oracle = wgl_cpu.check(model, histories[i])
-                        for key in ("op", "op_index", "final-paths",
-                                    "configs"):
-                            if key in oracle:
-                                res[key] = oracle[key]
-                results[i] = res
-        _acc("assemble", t0)
     # pipelined verdicts carry the pipeline's plan + stage
     # decomposition; stragglers (checked below through check()'s own
     # chain) carry the plan check() attaches for the engine that
     # actually produced them
-    from jepsen_tpu import telemetry as telemetry_mod
     pipe_plan = planner.plan_engines(
         planner.Shape(kind="linear-pipeline", R=R_cur, Sn=Sn or None,
                       U=len(rows) or None, decomposed=True, batch=n,
@@ -2227,12 +2279,13 @@ def check_pipeline(model, histories, *, max_states: int = 64,
         backend=backend_name).refine(
         bucket=("wgl_seg_pipeline", R_cur, int(Sn), G, Lp_c, K_c,
                 Rp_c))
-    telemetry_mod.attach_dispatch(
+    telemetry.attach_dispatch(
         results,
         pipe_plan.record(engine="wgl_seg",
                          R=R_cur or None, batch=n,
                          stragglers=len(strag) or None),
         stages=stats)
+    st.flush()
     for i in strag:
         results[i] = check(model, histories[i], max_states=max_states,
                            max_open_bits=max_open_bits,
@@ -2243,7 +2296,7 @@ def check_pipeline(model, histories, *, max_states: int = 64,
 
 
 def _run_segmented(batch, legal, next_state, diag_w, const_w, const_t0,
-                   Sn: int, R: int, M: int, C: int):
+                   Sn: int, R: int, M: int, C: int, st: telemetry.Stages):
     """The segmented batch engine: each key's event stream is cut at
     its quiescent points (the single-history engine's trick, applied
     across the whole batch), segments become kernel lanes bucketed by
@@ -2255,7 +2308,8 @@ def _run_segmented(batch, legal, next_state, diag_w, const_w, const_t0,
     accordingly — the same wall-clock trade the module docstring
     describes for one history, at batch scale.
 
-    Returns (ok_by_batch_index bool[Kk], t_kernel_s)."""
+    Returns ok_by_batch_index bool[Kk]; stage seconds go to `st`
+    (`kernel` for each bucket's device call)."""
     Kk = len(batch)
 
     # --- flatten all keys' returns with global segment ids ------------
@@ -2308,7 +2362,6 @@ def _run_segmented(batch, legal, next_state, diag_w, const_w, const_t0,
     # --- bucket segments by size (pow2 floors at 8) --------------------
     Lb_of_seg = np.maximum(
         8, 1 << np.ceil(np.log2(np.maximum(seg_sizes, 1))).astype(int))
-    t_kernel = 0.0
     S_max = int(key_nseg.max()) if Kk else 0
     # Ragged storage: one [Sn, Sn] matrix per segment — memory bounded
     # by TOTAL segments, not Kk x the single deepest key.  Segments
@@ -2350,10 +2403,10 @@ def _run_segmented(batch, legal, next_state, diag_w, const_w, const_t0,
             Kp, int(Lb), int(C), int(M), int(Sn), int(R), int(Sn),
             ret_t, cslot_t, cuop_t, legal, next_state,
             diag_w, const_w, const_t0)
-        t1 = time.monotonic()
-        T = np.asarray(kern(*args)) > 0.5              # [Kp, Sn, Sn]
-        t_kernel += time.monotonic() - t1
-        T_all[seg_ids] = T[:lanes]
+        with st.span("kernel"):
+            T = _device_call(st, "wgl_seg_batch", kern, args,
+                             lanes=lanes, padded=Kp - lanes) > 0.5
+        T_all[seg_ids] = T[:lanes]                     # [Kp, Sn, Sn]
 
     # --- compose each key's chain (entry state = enumeration index 0) -
     v = np.zeros((Kk, Sn), bool)
@@ -2362,25 +2415,37 @@ def _run_segmented(batch, legal, next_state, diag_w, const_w, const_t0,
         act = np.nonzero(key_nseg > s)[0]
         Ts = T_all[key_off[act] + s]                   # [A, Sn, Sn]
         v[act] = (v[act][:, :, None] & Ts).any(axis=1)
-    return v.any(axis=1), t_kernel
+    return v.any(axis=1)
 
 
 def _emit_batch_result(results, i, fk, ok: bool, backend_name: str,
-                       engine: str, t_kernel: float, model,
-                       histories, localize: bool) -> None:
-    """Per-key result dict + invalid-key localization via the CPU
-    oracle — shared by the segmented and single-lane batch paths."""
+                       engine: str, witness: list, localize: bool) -> None:
+    """Per-key result dict — shared by the segmented and single-lane
+    batch paths.  An invalid key to localize joins `witness`, for
+    _localize_keys."""
     results[i] = {
         "valid?": ok,
         "op_count": fk.n_calls,
         "backend": backend_name,
         "engine": engine,
-        "time_kernel_s": t_kernel,
     }
     if not ok:
         results[i]["anomaly"] = "nonlinearizable"
-        if localize and not isinstance(histories[i], PreparedHistory):
-            from jepsen_tpu.ops import wgl_cpu
+        if localize:
+            witness.append(i)
+
+
+def _localize_keys(results, keys: list, model, histories) -> None:
+    """The witness of each invalid batched key, from the CPU oracle on
+    its history: one `witness` checker span for the whole batch."""
+    if not keys:
+        return
+    from jepsen_tpu.ops import wgl_cpu
+
+    with telemetry.checker_stage("wgl_seg", "witness"):
+        for i in keys:
+            if isinstance(histories[i], PreparedHistory):
+                continue
             oracle = wgl_cpu.check(model, histories[i])
             for key in ("op", "op_index", "final-paths", "configs"):
                 if key in oracle:
@@ -2399,7 +2464,7 @@ def _overlap_chunk() -> int:
 
 def _run_many_overlapped(batch, R: int, U: int, Sn: int, M: int,
                          decomposed: bool, unroll: int,
-                         buf32: np.ndarray, stats: dict, _acc_s,
+                         buf32: np.ndarray, st: telemetry.Stages,
                          backend_name: str):
     """check_many's compact register-delta path through the async
     double-buffered executor (ops.runner.overlap): the key batch is cut
@@ -2416,9 +2481,15 @@ def _run_many_overlapped(batch, R: int, U: int, Sn: int, M: int,
     independent and chunking only partitions the lane axis
     (differentially pinned in tests/test_planner.py).
 
-    Returns (ok bool[len(batch)], kernel+fetch seconds)."""
+    Returns ok bool[len(batch)].  Stage seconds go to `st`: `pack` for
+    each chunk's packing, `kernel` for the rest of the overlapped
+    window (puts, calls, the fetch) — the packing runs inside that
+    window, so it is a child span, never kernel time."""
+    import jax
+
     from jepsen_tpu.ops import runner as runner_mod
 
+    stats = st.seconds
     chunk = _overlap_chunk()
     if chunk <= 0 or len(batch) <= chunk:
         chunks = [batch]
@@ -2430,38 +2501,35 @@ def _run_many_overlapped(batch, R: int, U: int, Sn: int, M: int,
     donate = backend_name not in ("cpu", "unknown") \
         and os.environ.get("JEPSEN_TPU_NO_DONATE") != "1"
     n_native = [0]
+    h2d = [buf32.nbytes * len(chunks)]
 
     def pack(ch):
-        import jax
-
-        t0 = time.monotonic()
-        # Native parallel ingest (ISSUE 9): GIL-released work-stealing
-        # snapshot-delta pack straight into one arena, bit-identical
-        # to the numpy packers below (the permanent differential twin
-        # and total fallback — any native error degrades here, never a
-        # silent wrong pack; planner counts both outcomes).
-        nat = planner._native_pack_compact(ch, Kp, int(R), int(U))
-        if nat is not None:
-            buf8, Rp, Lp = nat
-            n_native[0] += 1
-        else:
-            ret_t, islot_t, iuop_t, Lp = _pack_regs(ch, Kp, R, U, 1)
-            buf8, Rp = _compact_many_block(ret_t, islot_t, iuop_t,
-                                           Kp, U)
-        _acc_s("pack", t0)
-        stats["wire_bytes"] = (stats.get("wire_bytes", 0)
-                               + buf8.nbytes + buf32.nbytes)
+        with st.span("pack"):
+            # Native parallel ingest: GIL-released
+            # work-stealing snapshot-delta pack straight into one arena,
+            # bit-identical to the numpy packers below (the permanent
+            # differential twin and total fallback — any native error
+            # degrades here, never a silent wrong pack; planner counts
+            # both outcomes).
+            nat = planner._native_pack_compact(ch, Kp, int(R), int(U))
+            if nat is not None:
+                buf8, Rp, Lp = nat
+                n_native[0] += 1
+            else:
+                ret_t, islot_t, iuop_t, Lp = _pack_regs(ch, Kp, R, U, 1)
+                buf8, Rp = _compact_many_block(ret_t, islot_t, iuop_t,
+                                               Kp, U)
+        h2d[0] += buf8.nbytes
         if donate:
             # start the H2D transfer of the arena now, while the NEXT
             # chunk packs — the executable then consumes (and donates)
             # an already-on-device buffer instead of paying transfer
             # inside its own dispatch
-            buf8 = jax.device_put(buf8)
+            with telemetry.trace("kernel.put"):
+                buf8 = jax.device_put(buf8)
         return buf8, int(Lp), Rp
 
     def dispatch(payload):
-        import jax
-
         buf8, Lp, Rp = payload
         # AOT: jit(...).lower(...).compile() inside planner.compiled,
         # so the XLA compile is timed and charged to
@@ -2476,21 +2544,33 @@ def _run_many_overlapped(batch, R: int, U: int, Sn: int, M: int,
             lower_args=(jax.ShapeDtypeStruct(buf8.shape, buf8.dtype),
                         jax.ShapeDtypeStruct(buf32.shape,
                                              buf32.dtype)))
-        return kern(buf8, buf32)        # async device call
+        with telemetry.trace("kernel.call"):
+            return kern(buf8, buf32)        # async device call
 
-    t1 = time.monotonic()
-    outs = runner_mod.overlap(chunks, pack, dispatch, depth=2)
+    with st.span("kernel"):
+        outs = runner_mod.overlap(chunks, pack, dispatch, depth=2)
+        with telemetry.trace("kernel.fetch"):
+            if len(outs) == 1:
+                T = np.asarray(outs[0])                  # [Kp, 1, Sn]
+            else:
+                # ONE fetch
+                T = np.asarray(_build_stack(len(outs))(*outs))
     if len(outs) == 1:
-        T = np.asarray(outs[0])                      # [Kp, 1, Sn]
         ok = (T[:, 0, :] > 0.5).any(axis=1)[:len(batch)]
     else:
-        stacked = _build_stack(len(outs))(*outs)     # ONE fetch
-        T = np.asarray(stacked)                      # [G, Kp, 1, Sn]
-        ok_all = (T[:, :, 0, :] > 0.5).any(axis=2)   # [G, Kp]
+        ok_all = (T[:, :, 0, :] > 0.5).any(axis=2)       # [G, Kp]
         ok = np.concatenate(
             [ok_all[g][:len(ch)] for g, ch in enumerate(chunks)])
-    t_kernel = time.monotonic() - t1
-    stats["kernel"] = stats.get("kernel", 0.0) + t_kernel
+    engine = "wgl_seg_batch_regs"
+    st.tally(telemetry.DEVICE_CALLS, len(chunks), engine=engine)
+    st.tally(telemetry.LANES, len(batch), engine=engine, kind="used")
+    st.tally(telemetry.LANES, Kp * len(chunks) - len(batch),
+             engine=engine, kind="padded")
+    st.tally(telemetry.WIRE_BYTES, h2d[0], engine=engine,
+             direction="h2d")
+    st.tally(telemetry.WIRE_BYTES, T.nbytes, engine=engine,
+             direction="d2h")
+    stats["wire_bytes"] = stats.get("wire_bytes", 0) + h2d[0]
     stats["overlap_chunks"] = len(chunks)
     # which ingest backend actually packed (vs the plan's intent):
     # popped into the dispatch RECORD by check_many — "mixed" means a
@@ -2499,7 +2579,7 @@ def _run_many_overlapped(batch, R: int, U: int, Sn: int, M: int,
         "native" if n_native[0] == len(chunks)
         else "mixed" if n_native[0] else "python")
     stats["pack_threads"] = planner.pack_threads_effective()
-    return ok, t_kernel
+    return ok
 
 
 def check_many(model, histories, *, max_states: int = 64,
@@ -2526,12 +2606,14 @@ def check_many(model, histories, *, max_states: int = 64,
     if spec is None:
         raise Unsupported(f"model {model!r} has no device spec")
 
-    t0 = time.monotonic()
     backend_name = jax.default_backend()
     results: list = [None] * len(histories)
-    stats: dict = {}            # per-stage host seconds (telemetry)
-    _mt_s, _acc_s = _stats_clock(stats)
-    ts = _mt_s()
+    # per-stage host seconds (telemetry): scan, tables, pack, kernel on
+    # the register-delta path; counted once per call, whatever mix of
+    # engines produced the verdicts
+    st = telemetry.Stages()
+    stats = st.seconds
+    witness: list = []          # invalid batched keys to localize
 
     # Partition keys: batchable vs fallback — one fused host pass per
     # key (no per-op objects).  With the native ingest layer and >= 2
@@ -2545,112 +2627,120 @@ def check_many(model, histories, *, max_states: int = 64,
     fall: list = []
     stripped_note: dict = {}  # key idx -> crash count (stripped twin batched)
     native_ok = getattr(spec, "encode_op", None) is None
-    pre = planner._scan_cols_many(histories, spec, seen, rows,
-                                  max_open_bits)
-    for i, h in enumerate(histories):
-        if isinstance(h, PreparedHistory):
-            fall.append(i)  # pre-prepped callers take the slow path
-            continue
-        ops = h.ops if isinstance(h, History) else History(h).ops
-        if pre is not None and pre.get(i) is not None:
-            fk = pre[i]
-        else:
-            # includes keys the batch scan judged out of scope: the
-            # serial ladder's object-scan retry can still recover
-            # regimes outside the COLUMNAR scope (e.g. out-of-int32
-            # client ids), exactly as before
-            fk = _scan_history(h, ops, spec, seen, rows, max_open_bits)
-        if fk is None:
-            # Crashed keys ride the batch as their crash-stripped twin:
-            # stripped-valid => valid (a crashed call carries no
-            # obligation, so a linearization that never linearizes one
-            # is a linearization of the full key).  Keys the stripped
-            # pass cannot prove valid are re-checked exactly afterwards
-            # (bounded crash kernel via check(), then serial fallback).
-            split = _split_crashed(ops)
-            if split is not None and split[1]:
-                drop, crashed = split
-                stripped = [o for pos, o in enumerate(ops)
-                            if not drop[pos]]
-                sfk = _native_scan(stripped, spec, seen, rows,
-                                   max_open_bits) if native_ok else False
-                if sfk is False:
-                    sfk = _fast_scan(stripped, spec, seen, rows,
-                                     max_open_bits)
-                if sfk is not None and sfk.n_calls:
-                    stripped_note[i] = len(crashed)
-                    batch.append((i, sfk))
-                    continue
-                if sfk is not None:
-                    # every client call crashed: trivially linearizable
-                    # (linearize none of them)
-                    results[i] = {"valid?": True, "op_count": 0,
-                                  "backend": backend_name,
-                                  "engine": "wgl_seg_batch",
-                                  "crashed_ignored": len(crashed)}
-                    continue
-            fall.append(i)
-        elif fk.n_calls == 0:
-            results[i] = {"valid?": True, "op_count": 0,
-                          "backend": backend_name,
-                          "engine": "wgl_seg_batch"}
-        else:
-            batch.append((i, fk))
-    ts = _acc_s("scan", ts)
-
-    if batch:
-        uops = np.asarray(rows, np.int32).reshape(len(rows), 4)
-        init = np.asarray(spec.encode(model), np.int32)
-        try:
-            states, legal, next_state = _enumerate_states(
-                spec, init, uops, max_states)
-        except Unsupported:
-            from jepsen_tpu import telemetry as telemetry_mod
-            telemetry_mod.count_fallback("wgl_seg_batch",
-                                         "state-space")
-            fall.extend(i for i, _ in batch)
-            batch = []
-        ts = _acc_s("tables", ts)
+    with st.span("scan"):
+        pre = planner._scan_cols_many(histories, spec, seen, rows,
+                                      max_open_bits)
+        if pre:
+            st.tally(telemetry.SCAN_KEYS, len(pre), path="columnar-batch")
+        for i, h in enumerate(histories):
+            if isinstance(h, PreparedHistory):
+                fall.append(i)  # pre-prepped callers take the slow path
+                continue
+            ops = h.ops if isinstance(h, History) else History(h).ops
+            if pre is not None and pre.get(i) is not None:
+                fk = pre[i]
+            else:
+                # includes keys the batch scan judged out of scope: the
+                # serial ladder's object-scan retry can still recover
+                # regimes outside the COLUMNAR scope (e.g. out-of-int32
+                # client ids), exactly as before
+                fk = _scan_history(h, ops, spec, seen, rows,
+                                   max_open_bits, st=st)
+            if fk is None:
+                # Crashed keys ride the batch as their crash-stripped
+                # twin: stripped-valid => valid (a crashed call carries
+                # no obligation, so a linearization that never
+                # linearizes one is a linearization of the full key).
+                # Keys the stripped pass cannot prove valid are
+                # re-checked exactly afterwards (bounded crash kernel
+                # via check(), then serial fallback).
+                split = _split_crashed(ops)
+                if split is not None and split[1]:
+                    drop, crashed = split
+                    stripped = [o for pos, o in enumerate(ops)
+                                if not drop[pos]]
+                    sfk = _native_scan(stripped, spec, seen, rows,
+                                       max_open_bits) if native_ok \
+                        else False
+                    path = "objects"
+                    if sfk is False:
+                        path = "python"
+                        sfk = _fast_scan(stripped, spec, seen, rows,
+                                         max_open_bits)
+                    st.tally(telemetry.SCAN_KEYS, path=path)
+                    if sfk is not None and sfk.n_calls:
+                        stripped_note[i] = len(crashed)
+                        batch.append((i, sfk))
+                        continue
+                    if sfk is not None:
+                        # every client call crashed: trivially
+                        # linearizable (linearize none of them)
+                        results[i] = {"valid?": True, "op_count": 0,
+                                      "backend": backend_name,
+                                      "engine": "wgl_seg_batch",
+                                      "crashed_ignored": len(crashed)}
+                        continue
+                fall.append(i)
+            elif fk.n_calls == 0:
+                results[i] = {"valid?": True, "op_count": 0,
+                              "backend": backend_name,
+                              "engine": "wgl_seg_batch"}
+            else:
+                batch.append((i, fk))
 
     R_batch = None
     route = None
     if batch:
-        Sn = states.shape[0]
-        R = max(fk.max_open for _, fk in batch)
+        try:
+            with st.span("tables"):
+                uops = np.asarray(rows, np.int32).reshape(len(rows), 4)
+                init = np.asarray(spec.encode(model), np.int32)
+                states, legal, next_state = _enumerate_states(
+                    spec, init, uops, max_states)
+                diag_w, const_w, const_t0 = _decompose(legal, next_state)
+                Sn = states.shape[0]
+                R = max(fk.max_open for _, fk in batch)
+                # THE routing decision (ops.planner), over the tables:
+                # register-delta compact lanes vs candidate-table lanes
+                # vs the opt-in segmented engine (JEPSEN_TPU_SEGMENT=1
+                # prunes the single-lane layouts so the segmented tier
+                # surfaces — measured on a v5e-1 it LOSES to them at
+                # both bench shapes, 2.0s vs 0.83s kernel at 300-op
+                # keys, because the J=Sn entry-state axis multiplies
+                # total work ~Sn x; kept verdict-identical as the
+                # scaling path for workloads whose per-key depth
+                # actually binds).
+                route = planner.plan_engines(
+                    planner.Shape(kind="linear-many", R=int(R),
+                                  Sn=int(Sn), U=len(rows),
+                                  decomposed=diag_w is not None,
+                                  batch=len(batch),
+                                  mesh=None if mesh is None else int(
+                                      np.prod(mesh.devices.shape)),
+                                  max_states=max_states,
+                                  max_open_bits=max_open_bits),
+                    backend=backend_name)
+        except Unsupported:
+            telemetry.count_fallback("wgl_seg_batch", "state-space")
+            fall.extend(i for i, _ in batch)
+            batch = []
+
+    if batch:
         R_batch = int(R)
         M = 1 << R
         # C needs no pow2 pad — a return's candidate set is the open
         # calls, <= R.
         L = _pad_len(max(fk.n_rets for _, fk in batch))
         C = int(R)
-        diag_w, const_w, const_t0 = _decompose(legal, next_state)
-
-        # THE routing decision (ops.planner): register-delta compact
-        # lanes vs candidate-table lanes vs the opt-in segmented
-        # engine (JEPSEN_TPU_SEGMENT=1 prunes the single-lane layouts
-        # so the segmented tier surfaces — measured on a v5e-1 it
-        # LOSES to them at both bench shapes, 2.0s vs 0.83s kernel at
-        # 300-op keys, because the J=Sn entry-state axis multiplies
-        # total work ~Sn x; kept verdict-identical as the scaling path
-        # for workloads whose per-key depth actually binds).
-        route = planner.plan_engines(
-            planner.Shape(kind="linear-many", R=int(R), Sn=int(Sn),
-                          U=len(rows), decomposed=diag_w is not None,
-                          batch=len(batch),
-                          mesh=None if mesh is None else int(
-                              np.prod(mesh.devices.shape)),
-                          max_states=max_states,
-                          max_open_bits=max_open_bits),
-            backend=backend_name)
 
         if route.engine == "wgl_seg_batch_seg":
-            ok_b, t_kernel = _run_segmented(
+            ok_b = _run_segmented(
                 batch, legal, next_state, diag_w, const_w, const_t0,
-                int(Sn), int(R), int(M), int(C))
+                int(Sn), int(R), int(M), int(C), st)
             for bi, (i, fk) in enumerate(batch):
                 _emit_batch_result(results, i, fk, bool(ok_b[bi]),
                                    backend_name, "wgl_seg_batch",
-                                   t_kernel, model, histories,
+                                   witness,
                                    localize and i not in stripped_note)
             batch = []
 
@@ -2670,9 +2760,11 @@ def check_many(model, histories, *, max_states: int = 64,
         # _build_kernel_regs and the shared _regs_eligible gate
         # (planner._linear_candidates routes on exactly that gate).
         if route.engine == "wgl_seg_batch_regs":
+            engine_name = "wgl_seg_batch_regs"
             unroll = int(os.environ.get("JEPSEN_TPU_SCAN_UNROLL", "4"))
-            a1t, a2t, t0t = _pack_uop_tables(
-                legal, next_state, diag_w, const_w, const_t0)
+            with st.span("tables"):
+                a1t, a2t, t0t = _pack_uop_tables(
+                    legal, next_state, diag_w, const_w, const_t0)
             if mesh is None:
                 # compact wire (I = 1): key-major row streams, tables
                 # rebuilt on device — ~3x fewer bytes than the padded
@@ -2682,97 +2774,91 @@ def check_many(model, histories, *, max_states: int = 64,
                 # chunk k, all verdicts fetched ONCE at the end.
                 buf32 = np.concatenate(
                     [a1t, a2t, t0t.view(np.uint32)])
-                ok_k, t_kernel = _run_many_overlapped(
+                ok_k = _run_many_overlapped(
                     batch, int(R), int(U), int(Sn), int(M),
-                    decomposed, unroll, buf32, stats, _acc_s,
-                    backend_name)
-                ts = _mt_s()
+                    decomposed, unroll, buf32, st, backend_name)
             else:
                 I = min(2, int(R))
-                ret_t, islot_t, iuop_t, Lp = _pack_regs(
-                    batch, Kp, int(R), int(U), I)
-                kern = planner.compiled(
-                    "wgl_seg_batch_regs",
-                    (Kp, int(Lp), I, int(R), int(Sn), int(U),
-                     unroll, decomposed, "mesh"),
-                    _build_kernel_regs,
-                    Kp, int(Lp), I, max(1, M // 32),
-                    int(Sn), int(R), decomposed,
-                    rounds=int(R), unroll=unroll)
-                args = _shard_args(
-                    mesh, mesh_axis,
-                    [ret_t, islot_t, iuop_t, a1t, a2t, t0t], 3)
-                ts = _acc_s("fill", ts)
+                with st.span("pack"):
+                    ret_t, islot_t, iuop_t, Lp = _pack_regs(
+                        batch, Kp, int(R), int(U), I)
+                args = [ret_t, islot_t, iuop_t, a1t, a2t, t0t]
+                with st.span("kernel"):
+                    kern = planner.compiled(
+                        "wgl_seg_batch_regs",
+                        (Kp, int(Lp), I, int(R), int(Sn), int(U),
+                         unroll, decomposed, "mesh"),
+                        _build_kernel_regs,
+                        Kp, int(Lp), I, max(1, M // 32),
+                        int(Sn), int(R), decomposed,
+                        rounds=int(R), unroll=unroll)
+                    T = _device_call(
+                        st, engine_name, kern, args, lanes=Kk,
+                        padded=Kp - Kk,
+                        put=lambda a: _shard_args(mesh, mesh_axis, a,
+                                                  3))  # [Kp, 1, Sn]
                 stats["wire_bytes"] = (stats.get("wire_bytes", 0)
-                                       + sum(a.nbytes for a in args
-                                             if hasattr(a, "nbytes")))
-                t1 = time.monotonic()
-                T = np.asarray(kern(*args))              # [Kp, 1, Sn]
-                t_kernel = time.monotonic() - t1
-                stats["kernel"] = stats.get("kernel", 0.0) + t_kernel
-                ts = _mt_s()
+                                       + sum(a.nbytes for a in args))
                 ok_k = (T[:, 0, :] > 0.5).any(axis=1)
-            engine_name = "wgl_seg_batch_regs"
             for kk, (i, fk) in enumerate(batch):
                 _emit_batch_result(results, i, fk, bool(ok_k[kk]),
-                                   backend_name, engine_name, t_kernel,
-                                   model, histories,
+                                   backend_name, engine_name, witness,
                                    localize and i not in stripped_note)
             batch = []
 
     if batch:
-        ret_slot = np.full((Kp, L), -1, np.int32)
-        cand_slot = np.zeros((Kp, L, C), np.int32)
-        cand_uop = np.full((Kp, L, C), -1, np.int32)
-        for kk, (_, fk) in enumerate(batch):
-            if fk.arrays is not None:
-                # native form: vectorized scatter from the flat arrays
-                rs, counts, cs, cu = fk.arrays
-                nr = len(rs)
-                ret_slot[kk, :nr] = rs
-                if len(cs):
-                    ends = np.cumsum(counts)
-                    r_idx = np.repeat(np.arange(nr), counts)
-                    j_idx = (np.arange(ends[-1])
-                             - np.repeat(ends - counts, counts))
-                    cand_slot[kk, r_idx, j_idx] = cs
-                    cand_uop[kk, r_idx, j_idx] = cu
-                continue
-            for r, (slot, cands) in enumerate(fk.rets):
-                ret_slot[kk, r] = slot
-                for j, (s2, u2) in enumerate(cands):
-                    cand_slot[kk, r, j] = s2
-                    cand_uop[kk, r, j] = u2
+        with st.span("pack"):
+            ret_slot = np.full((Kp, L), -1, np.int32)
+            cand_slot = np.zeros((Kp, L, C), np.int32)
+            cand_uop = np.full((Kp, L, C), -1, np.int32)
+            for kk, (_, fk) in enumerate(batch):
+                if fk.arrays is not None:
+                    # native form: vectorized scatter from the flat
+                    # arrays
+                    rs, counts, cs, cu = fk.arrays
+                    nr = len(rs)
+                    ret_slot[kk, :nr] = rs
+                    if len(cs):
+                        ends = np.cumsum(counts)
+                        r_idx = np.repeat(np.arange(nr), counts)
+                        j_idx = (np.arange(ends[-1])
+                                 - np.repeat(ends - counts, counts))
+                        cand_slot[kk, r_idx, j_idx] = cs
+                        cand_uop[kk, r_idx, j_idx] = cu
+                    continue
+                for r, (slot, cands) in enumerate(fk.rets):
+                    ret_slot[kk, r] = slot
+                    for j, (s2, u2) in enumerate(cands):
+                        cand_slot[kk, r, j] = s2
+                        cand_uop[kk, r, j] = u2
 
-        ret_t = np.ascontiguousarray(ret_slot.T)             # [L, K]
-        cslot_t = np.ascontiguousarray(cand_slot.transpose(1, 0, 2))
-        cuop_t = np.ascontiguousarray(cand_uop.transpose(1, 0, 2))
+            ret_t = np.ascontiguousarray(ret_slot.T)         # [L, K]
+            cslot_t = np.ascontiguousarray(cand_slot.transpose(1, 0, 2))
+            cuop_t = np.ascontiguousarray(cand_uop.transpose(1, 0, 2))
 
-        # (A Pallas megakernel variant of this scan was carried through
-        # round 2 behind JEPSEN_TPU_PALLAS=1; it never beat XLA's
-        # fusion of the same bitmap algebra on any measured shape
-        # (~25% slower at its best) and was removed in round 3 —
-        # hand-scheduling what the compiler already fuses well bought
-        # nothing but maintenance surface.)
-        engine_name = "wgl_seg_batch"
-        kern, args, kc_shaped = _dispatch_kernel(
-            Kp, int(L), int(C), int(M), int(Sn), int(R), 1,
-            ret_t, cslot_t, cuop_t, legal, next_state,
-            diag_w, const_w, const_t0)
+            # (No Pallas megakernel here: one never beat XLA's fusion of
+            # the same bitmap algebra on any measured shape, ~25% slower
+            # at its best — hand-scheduling what the compiler already
+            # fuses well buys nothing but maintenance surface.)
+            engine_name = "wgl_seg_batch"
+            kern, args, kc_shaped = _dispatch_kernel(
+                Kp, int(L), int(C), int(M), int(Sn), int(R), 1,
+                ret_t, cslot_t, cuop_t, legal, next_state,
+                diag_w, const_w, const_t0)
+        put = None
         if mesh is not None and mesh_axis is not None:
-            args = _shard_args(mesh, mesh_axis, args, kc_shaped)
-
-        ts = _acc_s("fill", ts)
-        t1 = time.monotonic()
-        T = np.asarray(kern(*args))                      # [Kp, 1, Sn]
-        t_kernel = time.monotonic() - t1
-        stats["kernel"] = stats.get("kernel", 0.0) + t_kernel
+            def put(a):
+                return _shard_args(mesh, mesh_axis, a, kc_shaped)
+        with st.span("kernel"):
+            T = _device_call(st, engine_name, kern, args,
+                             lanes=len(batch), padded=Kp - len(batch),
+                             put=put)                    # [Kp, 1, Sn]
         ok_k = (T[:, 0, :] > 0.5).any(axis=1)
         for kk, (i, fk) in enumerate(batch):
             _emit_batch_result(results, i, fk, bool(ok_k[kk]),
-                               backend_name, engine_name, t_kernel,
-                               model, histories,
+                               backend_name, engine_name, witness,
                                localize and i not in stripped_note)
+    _localize_keys(results, witness, model, histories)
 
     if stripped_note:
         # Crash-bearing keys: a valid verdict on the stripped twin IS
@@ -2794,9 +2880,7 @@ def check_many(model, histories, *, max_states: int = 64,
                                    max_open_bits=max_open_bits,
                                    localize=localize)
             except Unsupported:
-                from jepsen_tpu import telemetry as telemetry_mod
-                telemetry_mod.count_fallback("wgl_seg_batch",
-                                             "per-key-chain")
+                telemetry.count_fallback("wgl_seg_batch", "per-key-chain")
                 results[i] = None
                 fall.append(i)
 
@@ -2818,16 +2902,12 @@ def check_many(model, histories, *, max_states: int = 64,
             results[i] = fallback(model, p)
             results[i].setdefault("engine", "fallback")
 
-    t_total = time.monotonic() - t0
-    for r in results:
-        if r is not None and "time_total_s" not in r:
-            r["time_total_s"] = t_total
     # Dispatch records, grouped by the engine that actually produced
     # each verdict (batched kernel lanes, exact single-key crash
     # chains, serial fallbacks): one shared plan-rendered record per
     # engine, so the attribution costs dict references, not
-    # per-verdict env scans.
-    from jepsen_tpu import telemetry as telemetry_mod
+    # per-verdict env scans.  The call's stage seconds are counted
+    # once, under the routed engine when it produced verdicts.
     by_engine: dict = {}
     for r in results:
         if isinstance(r, dict) and "dispatch" not in r:
@@ -2846,14 +2926,16 @@ def check_many(model, histories, *, max_states: int = 64,
     # strings ride the record, not the numeric stage decomposition
     pack_used = stats.pop("pack_backend", None)
     pack_nt = stats.pop("pack_threads", None)
-    for eng, rs in by_engine.items():
-        telemetry_mod.attach_dispatch(
-            rs,
+    order = sorted(by_engine, key=lambda e: e != route.engine)
+    for n, eng in enumerate(order):
+        telemetry.attach_dispatch(
+            by_engine[eng],
             route.record(
                 engine=eng,
                 R=R_batch, crashes=n_crash, batch=len(histories),
                 mesh=(getattr(mesh, "shape", None)
                       if mesh is not None else None),
                 pack_backend=pack_used, pack_threads=pack_nt),
-            stages=stats)
+            stages=stats, count_stages=(n == 0))
+    st.flush()
     return results

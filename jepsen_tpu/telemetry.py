@@ -29,6 +29,13 @@ named runs) and Prometheus-style pull metrics, it provides:
     overrides in effect), attached to every verdict by the engine
     entry points (ops/wgl_seg, ops/wgl_deep, ops/wgl_batch,
     ops/runner) and emitted into the active run's event log.
+  * **Stages** — one call's stage spans: `with stages.span("scan"):`
+    adds the block's self seconds (its duration less the counted spans
+    inside it) to the call's stage dict and writes a
+    `jax.profiler.TraceAnnotation("jepsen.scan")` over the same
+    interval, so stage counters and profiler spans cannot disagree and
+    no interval is counted under two labels.  Work counts tallied on
+    it reach the registry once per call (`flush`).
 
 Event schema (telemetry.jsonl `ev` payloads; the envelope adds `i`
 sequence, `t` wall-clock seconds, `crc`):
@@ -61,8 +68,10 @@ is the Prometheus exposition for scraping.
 
 from __future__ import annotations
 
+import contextlib
 import json
 import os
+import sys
 import threading
 import time
 import zlib
@@ -182,6 +191,7 @@ class MetricsRegistry:
     def __init__(self):
         self._lock = threading.Lock()
         self._metrics: dict = {}      # name -> (kind, {labelkey: metric})
+        self._by_items: dict = {}     # (name, label items) -> Counter
 
     def _get(self, kind, name: str, labels: dict, ctor):
         key = _label_key(labels)
@@ -197,6 +207,16 @@ class MetricsRegistry:
 
     def counter(self, name: str, **labels) -> Counter:
         return self._get("counter", name, labels, Counter)
+
+    def counter_for(self, name: str, items: tuple) -> Counter:
+        """`counter(name, **dict(items))` for a sorted tuple of label
+        items, memoised: a per-call flush skips the label normalisation
+        and the registry lock."""
+        c = self._by_items.get((name, items))
+        if c is None:
+            c = self._by_items[(name, items)] = self.counter(
+                name, **dict(items))
+        return c
 
     def gauge(self, name: str, **labels) -> Gauge:
         return self._get("gauge", name, labels, Gauge)
@@ -720,11 +740,15 @@ def dispatch_record(engine: str, *, why: Optional[str] = None,
 
 
 def attach_dispatch(results, record: dict,
-                    stages: Optional[dict] = None) -> dict:
+                    stages: Optional[dict] = None, *,
+                    count_stages: bool = True) -> dict:
     """Attach one dispatch record (and optional per-stage host-second
     decomposition) to every verdict dict in `results` that lacks one,
     record the engine mix + stage seconds in the registry, and emit a
-    `dispatch` event into the active run's log.  Returns the record."""
+    `dispatch` event into the active run's log.  A call that attaches
+    one stage dict to several engines' verdicts counts its seconds on
+    the first attach only (`count_stages=False` on the rest).  Returns
+    the record."""
     st = None
     _counts = ("wire_bytes", "overlap_chunks")   # ints, not seconds
     if stages:
@@ -742,14 +766,128 @@ def attach_dispatch(results, record: dict,
             n += 1
     REGISTRY.counter("jepsen_engine_dispatch_total",
                      engine=record["engine"]).inc(max(n, 1))
-    if st:
+    if st and count_stages:
         for k, v in st.items():
             if k not in _counts:
-                REGISTRY.counter("jepsen_stage_seconds_total",
+                REGISTRY.counter(STAGE_SECONDS,
                                  engine=record["engine"], stage=k).inc(v)
     if _active is not None:
-        emit("dispatch", record=record, stages=st, verdicts=n)
+        # the log's stage sums (summarize) count a call once too
+        emit("dispatch", record=record,
+             stages=st if count_stages else None, verdicts=n)
     return record
+
+
+# ---------------------------------------------------------------------------
+# Stage spans: one primitive for stage seconds and profiler annotations
+# ---------------------------------------------------------------------------
+
+STAGE_SECONDS = "jepsen_stage_seconds_total"      # {engine, stage}
+CHECKER_SECONDS = "jepsen_checker_seconds_total"  # {checker, stage}
+SCAN_KEYS = "jepsen_scan_keys_total"              # {path}
+DEVICE_CALLS = "jepsen_device_calls_total"        # {engine}
+LANES = "jepsen_lanes_total"                      # {engine, kind}
+WIRE_BYTES = "jepsen_wire_bytes_total"            # {engine, direction}
+
+# the innermost open counted span of this thread
+_open = threading.local()
+
+
+def _annotation(name: str):
+    """`jax.profiler.TraceAnnotation("jepsen.<name>")`, or None when JAX
+    is not loaded (the run side imports this module without it)."""
+    prof = sys.modules.get("jax.profiler")
+    return None if prof is None else prof.TraceAnnotation("jepsen." + name)
+
+
+class _Span:
+    __slots__ = ("_seconds", "_name", "_ann", "_t0", "_child", "_parent")
+
+    def __init__(self, seconds: dict, name: str):
+        self._seconds = seconds
+        self._name = name
+
+    def __enter__(self):
+        self._ann = _annotation(self._name)
+        if self._ann is not None:
+            self._ann.__enter__()
+        self._parent = getattr(_open, "span", None)
+        _open.span = self
+        self._child = 0.0
+        self._t0 = time.monotonic()
+        return self
+
+    def __exit__(self, *exc):
+        dur = time.monotonic() - self._t0
+        _open.span = self._parent
+        if self._parent is not None:
+            self._parent._child += dur
+        self._seconds[self._name] = (self._seconds.get(self._name, 0.0)
+                                     + dur - self._child)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        return False
+
+
+class Stages:
+    """One call's stage seconds and work counts.
+
+    `span(name)` times a block once on the monotonic clock and adds its
+    SELF seconds to `seconds[name]`: the duration less every counted
+    span that opened inside it on this thread, whichever Stages that
+    one belongs to.  So no interval lands under two labels, and the
+    labels of a call add up to at most its wall time.  The same
+    interval is a `jepsen.<name>` annotation on the profiler's host
+    plane, on the clock the device planes use.  `seconds` may be a
+    dict the caller already holds (an engine's `stats`).
+
+    `tally(metric, n, **labels)` adds to a local count; `flush` moves
+    the tallies (and, given a metric name, the stage seconds) into the
+    registry — once per call, never from a per-key or per-op loop."""
+
+    __slots__ = ("seconds", "_counts")
+
+    def __init__(self, seconds: Optional[dict] = None):
+        self.seconds = {} if seconds is None else seconds
+        self._counts: dict = {}
+
+    def span(self, name: str) -> _Span:
+        return _Span(self.seconds, name)
+
+    def tally(self, metric: str, n: float = 1, **labels) -> None:
+        key = (metric, tuple(sorted(labels.items())))
+        self._counts[key] = self._counts.get(key, 0) + n
+
+    def flush(self, metric: Optional[str] = None, **labels) -> None:
+        for (name, key), n in self._counts.items():
+            if n:
+                REGISTRY.counter_for(name, key).inc(n)
+        self._counts.clear()
+        if metric is not None:
+            for k, v in self.seconds.items():
+                if isinstance(v, float):
+                    REGISTRY.counter_for(metric, tuple(sorted(
+                        {**labels, "stage": k}.items()))).inc(v)
+
+
+def trace(name: str):
+    """An annotation only (`jepsen.<name>`, no counter): a part of a
+    counted span that the trace should show on its own, such as the
+    put, call and fetch inside `kernel`.  Its time stays in the
+    enclosing span's self seconds."""
+    ann = _annotation(name)
+    return contextlib.nullcontext() if ann is None else ann
+
+
+@contextlib.contextmanager
+def checker_stage(checker: str, stage: str):
+    """One checker-front span counted on its own into
+    `jepsen_checker_seconds_total{checker, stage}` as it closes (a
+    witness replay inside an engine)."""
+    st = Stages()
+    with st.span(stage):
+        yield
+    st.flush(CHECKER_SECONDS, checker=checker)
 
 
 # ---------------------------------------------------------------------------
