@@ -45,6 +45,27 @@ TYPE_CODE = {t: i for i, t in enumerate(TYPES)}
 CODE_TYPE = {i: t for t, i in TYPE_CODE.items()}
 
 
+class KV(tuple):
+    """A key/value tuple marking an op value as belonging to an
+    independent key (independent.clj tuple :21-29).  Public as
+    `independent.KV`; defined here so the column journal can recognise
+    keyed records."""
+
+    def __new__(cls, k, v):
+        return super().__new__(cls, (k, v))
+
+    @property
+    def key(self):
+        return self[0]
+
+    @property
+    def value(self):
+        return self[1]
+
+    def __repr__(self):
+        return f"[{self[0]!r} {self[1]!r}]"
+
+
 @dataclasses.dataclass
 class Op:
     """One operation record.  Mutable by design: the worker loop assigns
@@ -115,7 +136,7 @@ class Op:
 
     def to_dict(self) -> dict:
         v = self.value
-        if type(v).__name__ == "KV" and isinstance(v, tuple):
+        if isinstance(v, KV):
             # Tag independent-key tuples so they survive the JSON
             # round-trip — the reference registers a custom Fressian
             # handler for MapEntry for exactly this (store.clj:28-123);
@@ -137,7 +158,6 @@ class Op:
               if k in d}
         v = kw.get("value")
         if isinstance(v, dict) and set(v) == {"__kv__"}:
-            from jepsen_tpu.independent import KV
             kw["value"] = KV(*v["__kv__"])
         return cls(extra=d, **kw)
 
@@ -356,6 +376,15 @@ class PackedHistory:
     attached instance) or the cached casts go stale while the Python
     oracle sees the new values — a verdict-divergence footgun.  A
     length change invalidates on its own.
+
+    Keyed histories (values `KV(k, v)`, the independent layer's) carry
+    a `key` column: the record's code into `key_table`, KEY_NONE for an
+    un-keyed record, KEY_UNHELD for a key no table can hold
+    (unhashable).  A held keyed record's value columns encode the inner
+    `v`, so a key's rows are its subhistory's columns as they stand
+    (`independent.subhistories`).  The whole history's value columns
+    are then no single register's: the scanners decline columns that
+    carry a key column.  `key` is None where no record is keyed.
     """
 
     index: np.ndarray       # int32 [n]
@@ -375,9 +404,31 @@ class PackedHistory:
     # Mutation counter guarding derived-cast caches (see class
     # docstring): bump after any in-place column edit.
     version: int = 0
+    key: Optional[np.ndarray] = None    # int32 [n] key code
+    key_table: Optional[list] = None    # key code -> key
 
     def __len__(self):
         return len(self.index)
+
+    def take(self, rows) -> "PackedHistory":
+        """The records at `rows` as a new PackedHistory: positions (an
+        int array, gathered in the order given) or a slice (views).
+        Every per-record column is taken, the code tables copied, no
+        derived casts carried over."""
+        if isinstance(rows, slice):
+            def get(a):
+                return a[rows]
+        else:
+            def get(a):         # np.take: ~10x a[rows] on the 2-D columns
+                return np.take(a, rows, axis=0)
+        keyed = self.key is not None
+        return PackedHistory(
+            get(self.index), get(self.process), get(self.type),
+            get(self.f), get(self.value), get(self.value_ok),
+            get(self.time), dict(self.f_codes),
+            vkind=None if self.vkind is None else get(self.vkind),
+            key=get(self.key) if keyed else None,
+            key_table=list(self.key_table) if keyed else None)
 
     def unpack_op(self, i: int) -> Op:
         codes_f = {v: k for k, v in self.f_codes.items()}
@@ -439,7 +490,10 @@ def default_value_encoder(o: Op) -> tuple[list[int], list[bool]]:
     Ints beyond int64 are marked not-ok instead of overflowing the
     column store — the run loop journals every op through here
     (ColumnJournal), so this must never raise."""
-    v = o.value
+    return _encode_value(o.value)
+
+
+def _encode_value(v) -> tuple[list[int], list[bool]]:
     if isinstance(v, bool):  # bool is an int subclass; keep it encodable
         return [int(v), 0], [True, False]
     if isinstance(v, int):
@@ -472,10 +526,37 @@ def _value_kind(v) -> int:
     return 3
 
 
+# Key column codes (see PackedHistory): a record whose value is no KV,
+# and a KV whose key no table can hold.
+KEY_NONE = -1
+KEY_UNHELD = -2
+
+# PackedHistory's per-record columns.
+_RECORD_COLUMNS = ("index", "process", "type", "f", "value", "value_ok",
+                   "time", "vkind", "key")
+
+
+def _key_code(k, codes: dict, table: list) -> int:
+    """`k`'s code in (codes, table), entered when new.  Keys group by
+    dict equality, as the independent split groups them; a key no dict
+    can hold (unhashable) is KEY_UNHELD, since the journal must never
+    raise."""
+    try:
+        c = codes.get(k)
+        if c is None:
+            c = codes[k] = len(table)
+            table.append(k)
+        return c
+    except TypeError:
+        return KEY_UNHELD
+
+
 def pack_history(h: History, f_codes: Optional[dict] = None,
                  value_encoder=None) -> PackedHistory:
+    """The columns of `h` (see PackedHistory).  With a custom
+    `value_encoder` the value columns are the encoder's, and neither
+    `vkind` nor a key column is kept."""
     custom_encoder = value_encoder is not None
-    value_encoder = value_encoder or default_value_encoder
     if f_codes is None:
         f_codes = {}
         for o in h:
@@ -490,6 +571,9 @@ def pack_history(h: History, f_codes: Optional[dict] = None,
     value_ok = np.zeros((n, 2), bool)
     time = np.zeros(n, np.int64)
     vkind = None if custom_encoder else np.zeros(n, np.uint8)
+    key = np.full(n, KEY_NONE, np.int32)
+    key_codes: dict = {}
+    key_table: list = []
     for i, o in enumerate(h):
         index[i] = _i32_index(o.index, i)
         p = o.process
@@ -501,13 +585,24 @@ def pack_history(h: History, f_codes: Optional[dict] = None,
         process[i] = _i32_process(p)
         typ[i] = TYPE_CODE[o.type]
         f[i] = f_codes.get(o.f, -1)
-        (value[i, 0], value[i, 1]), (value_ok[i, 0], value_ok[i, 1]) = \
-            value_encoder(o)
         time[i] = o.time if o.time is not None else 0
-        if vkind is not None:
-            vkind[i] = _value_kind(o.value)
+        if custom_encoder:
+            (value[i, 0], value[i, 1]), \
+                (value_ok[i, 0], value_ok[i, 1]) = value_encoder(o)
+            continue
+        v = o.value
+        if isinstance(v, KV):
+            key[i] = c = _key_code(v[0], key_codes, key_table)
+            if c >= 0:
+                v = v[1]
+        (value[i, 0], value[i, 1]), (value_ok[i, 0], value_ok[i, 1]) = \
+            _encode_value(v)
+        vkind[i] = _value_kind(v)
+    keyed = not custom_encoder and bool((key != KEY_NONE).any())
     return PackedHistory(index, process, typ, f, value, value_ok, time,
-                         dict(f_codes), vkind=vkind)
+                         dict(f_codes), vkind=vkind,
+                         key=key if keyed else None,
+                         key_table=key_table if keyed else None)
 
 
 class ColumnJournal:
@@ -522,6 +617,11 @@ class ColumnJournal:
         self._n = 0
         self._cap = cap
         self.f_codes: dict = {}
+        # the key column appears with the first keyed record; a history
+        # with none pays one isinstance check per record
+        self.key: Optional[np.ndarray] = None
+        self._key_codes: dict = {}
+        self.key_table: list = []
         self._alloc(cap)
 
     def _alloc(self, cap):
@@ -535,13 +635,14 @@ class ColumnJournal:
         self.vkind = np.zeros(cap, np.uint8)
 
     def _grow(self):
-        old = (self.index, self.process, self.type, self.f, self.value,
-               self.value_ok, self.time, self.vkind)
+        old = {name: getattr(self, name) for name in _RECORD_COLUMNS}
         self._cap *= 2
         self._alloc(self._cap)
-        for o, name in zip(old, ("index", "process", "type", "f",
-                                 "value", "value_ok", "time", "vkind")):
-            getattr(self, name)[:len(o)] = o
+        if old["key"] is not None:
+            self.key = np.full(self._cap, KEY_NONE, np.int32)
+        for name, o in old.items():
+            if o is not None:
+                getattr(self, name)[:len(o)] = o
 
     def append(self, o: Op) -> None:
         i = self._n
@@ -557,19 +658,30 @@ class ColumnJournal:
         if fc is None:
             fc = self.f_codes[o.f] = len(self.f_codes)
         self.f[i] = fc
+        v = o.value
+        if isinstance(v, KV):
+            if self.key is None:
+                self.key = np.full(self._cap, KEY_NONE, np.int32)
+            self.key[i] = c = _key_code(v[0], self._key_codes,
+                                        self.key_table)
+            if c >= 0:
+                v = v[1]
         (self.value[i, 0], self.value[i, 1]), \
-            (self.value_ok[i, 0], self.value_ok[i, 1]) = \
-            default_value_encoder(o)
+            (self.value_ok[i, 0], self.value_ok[i, 1]) = _encode_value(v)
         self.time[i] = o.time if o.time is not None else 0
-        self.vkind[i] = _value_kind(o.value)
+        self.vkind[i] = _value_kind(v)
         self._n = i + 1
 
     def packed(self) -> PackedHistory:
         n = self._n
+        keyed = self.key is not None
         return PackedHistory(self.index[:n], self.process[:n],
                              self.type[:n], self.f[:n], self.value[:n],
                              self.value_ok[:n], self.time[:n],
-                             dict(self.f_codes), vkind=self.vkind[:n])
+                             dict(self.f_codes), vkind=self.vkind[:n],
+                             key=self.key[:n] if keyed else None,
+                             key_table=(list(self.key_table) if keyed
+                                        else None))
 
 
 # ---------------------------------------------------------------------------

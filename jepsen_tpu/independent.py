@@ -20,34 +20,17 @@ import json
 import logging
 from typing import Callable, Iterable, Optional
 
+import numpy as np
+
 from jepsen_tpu import checker as ck
 from jepsen_tpu import generator as gen
 from jepsen_tpu import telemetry
-from jepsen_tpu.history import History, Op
+from jepsen_tpu.history import KEY_NONE, KEY_UNHELD, KV, History, Op
 from jepsen_tpu.util import bounded_pmap
 
 log = logging.getLogger("jepsen")
 
 DIR = "independent"
-
-
-class KV(tuple):
-    """A key/value tuple marking an op value as belonging to an
-    independent key (independent.clj tuple :21-29)."""
-
-    def __new__(cls, k, v):
-        return super().__new__(cls, (k, v))
-
-    @property
-    def key(self):
-        return self[0]
-
-    @property
-    def value(self):
-        return self[1]
-
-    def __repr__(self):
-        return f"[{self[0]!r} {self[1]!r}]"
 
 
 def tuple_(k, v) -> KV:
@@ -191,8 +174,29 @@ def concurrent_generator(n, keys, fgen):
 # History splitting (independent.clj:222-245)
 # ---------------------------------------------------------------------------
 
+def _split_columns(history):
+    """The packed columns the split reads in place of the ops, or None:
+    the history's own columns, with value kinds (so a key column where
+    any record is keyed) and a code for every keyed record's key."""
+    if not isinstance(history, History):
+        return None
+    cols = history.packed_columns()
+    if cols is None or cols.vkind is None or len(cols) != len(history):
+        return None
+    if cols.key is not None and (cols.key == KEY_UNHELD).any():
+        return None
+    return cols
+
+
 def history_keys(history) -> set:
-    return {o.value.key for o in History(history) if is_tuple(o.value)}
+    cols = _split_columns(history)
+    if cols is None:
+        return {o.value.key for o in History(history) if is_tuple(o.value)}
+    if cols.key is None:
+        return set()
+    present = np.bincount(cols.key[cols.key >= 0],
+                          minlength=len(cols.key_table))
+    return {cols.key_table[c] for c in np.flatnonzero(present)}
 
 
 def subhistory(k, history) -> History:
@@ -211,7 +215,12 @@ def subhistory(k, history) -> History:
 def subhistories(ks, history) -> list:
     """[subhistory(k, history) for k in ks] in ONE pass over the
     history: splitting a many-key run key by key rescans every op once
-    per key."""
+    per key.  A history with packed columns splits as columns
+    (_KeyHistory: each key's rows, its ops built only when read); one
+    without (a history loaded from the store) splits its ops."""
+    cols = _split_columns(history)
+    if cols is not None:
+        return _split_cols(ks, history, cols)
     by_key: dict = {k: [] for k in ks}
     for o in History(history):
         v = o.value
@@ -221,6 +230,79 @@ def subhistories(ks, history) -> list:
         elif v.key in by_key:
             by_key[v.key].append(o.assoc(value=v.value))
     return [History(by_key[k]) for k in ks]
+
+
+class _KeyHistory(History):
+    """One key's subhistory as rows of its parent: the rows' packed
+    columns are attached (their values already the inner ones), and
+    `ops` is built from the parent's ops on first access, exactly as
+    `subhistory` builds them: keyed ops unwrapped through `assoc`,
+    un-keyed ops shared."""
+
+    def __init__(self, parent_ops: list, rows: np.ndarray,
+                 keyed: np.ndarray, packed):
+        self._parent_ops = parent_ops
+        self._rows = rows
+        self._keyed = keyed
+        self._ops: Optional[list] = None
+        self._packed = packed
+        self._journal = None
+        self.wal = None
+
+    @property
+    def ops(self) -> list:
+        if self._ops is None:
+            parent = self._parent_ops
+            self._ops = [parent[r].assoc(value=parent[r].value.value)
+                         if kd else parent[r]
+                         for r, kd in zip(self._rows.tolist(),
+                                          self._keyed.tolist())]
+        return self._ops
+
+    @ops.setter
+    def ops(self, ops: list) -> None:
+        self._ops = ops
+
+    @property
+    def ops_built(self) -> bool:
+        return self._ops is not None
+
+    def __len__(self):
+        return len(self._rows) if self._ops is None else len(self._ops)
+
+
+def _split_cols(ks, history, cols) -> list:
+    """subhistories over packed columns: each key's rows are its own
+    merged in order with the un-keyed ones; every column is gathered
+    once in key order and each key takes a slice, the original `index`
+    column included, so witnesses name the parent's op indices."""
+    n = len(cols)
+    key = cols.key if cols.key is not None \
+        else np.full(n, KEY_NONE, np.int32)
+    table = cols.key_table or []
+    codes = {k: c for c, k in enumerate(table)}
+    # rows grouped by code (KEY_NONE first), each group in history order
+    order = np.argsort(key, kind="stable")
+    starts = np.searchsorted(key[order], np.arange(KEY_NONE, len(table) + 1))
+    unkeyed = order[:starts[1]]
+    parts = []
+    for k in ks:
+        c = codes.get(k)
+        own = order[starts[c + 1]:starts[c + 2]] if c is not None \
+            else order[:0]
+        parts.append(np.sort(np.concatenate([own, unkeyed]))
+                     if len(unkeyed) else own)
+    rows = np.concatenate(parts) if parts else order[:0]
+    whole = cols.take(rows)
+    whole.key = whole.key_table = None       # the values are the inner ones
+    keyed = key[rows] >= 0
+    out, lo = [], 0
+    for part in parts:
+        hi = lo + len(part)
+        out.append(_KeyHistory(history.ops, part, keyed[lo:hi],
+                               whole.take(slice(lo, hi))))
+        lo = hi
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -299,7 +381,9 @@ class BatchedLinearizableChecker(ck.Checker):
         """Split, one batched device call, assemble.  The front's own
         stages (`keys`, `split`, `assemble`) are spans counted into
         `jepsen_checker_seconds_total{checker="independent"}`; the
-        engine counts its dispatch stages itself."""
+        engine counts its dispatch stages itself.  The split's keys
+        count into `jepsen_split_keys_total{path}`, and the keys whose
+        ops exist by the end into `jepsen_split_ops_built_total`."""
         front = telemetry.Stages()
         try:
             return self._check(test, history, opts, front)
@@ -307,16 +391,28 @@ class BatchedLinearizableChecker(ck.Checker):
             front.flush(telemetry.CHECKER_SECONDS, checker="independent")
 
     def _check(self, test, history, opts, front):
-        import os as _os
-
-        from jepsen_tpu.ops import runner as runner_mod
-
         with front.span("keys"):
             ks = sorted(history_keys(history), key=repr)
         if not ks:
             return {"valid?": True, "results": {}, "failures": []}
         with front.span("split"):
             subs = subhistories(ks, history)
+        columnar = sum(isinstance(s, _KeyHistory) for s in subs)
+        front.tally(telemetry.SPLIT_KEYS, columnar, path="columnar")
+        front.tally(telemetry.SPLIT_KEYS, len(subs) - columnar,
+                    path="objects")
+        try:
+            return self._verdicts(test, opts, ks, subs, front)
+        finally:
+            front.tally(telemetry.SPLIT_OPS_BUILT,
+                        sum(not isinstance(s, _KeyHistory) or s.ops_built
+                            for s in subs))
+
+    def _verdicts(self, test, opts, ks, subs, front):
+        import os as _os
+
+        from jepsen_tpu.ops import runner as runner_mod
+
         ckdir = (opts or {}).get("checkpoint_dir")
         per_key = runner_mod.ResilientRunner(
             engine="seg_many",

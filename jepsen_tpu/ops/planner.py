@@ -72,7 +72,8 @@ import numpy as np
 
 from jepsen_tpu import telemetry
 from jepsen_tpu.errors import CheckError
-from jepsen_tpu.history import History
+from jepsen_tpu.history import (INFO, INVOKE, P_OUT_OF_RANGE, TYPE_CODE,
+                                History)
 from jepsen_tpu.models import DeviceSpec
 from jepsen_tpu.ops.prep import PreparedHistory
 
@@ -1317,6 +1318,11 @@ def _cols_args(packed, spec):
         return None
     if packed is None or getattr(packed, "vkind", None) is None:
         return None
+    if getattr(packed, "key", None) is not None:
+        # a keyed history's value columns hold each record's inner
+        # value, no single register's: the whole history takes the
+        # object walk, which sees the KV values, as it always did
+        return None
     nf = len(packed.f_codes)
     fcol = packed.f
     if nf == 0:
@@ -1357,13 +1363,16 @@ def _cols_args(packed, spec):
 
 
 def _native_scan_cols(packed, spec, seen: dict, rows: list,
-                      max_open_bits: int, want_snaps: bool = True):
+                      max_open_bits: int, want_snaps: bool = True,
+                      keep=None):
     """Columnar twin of _native_scan: runs the fused C scan over the
     history's native struct-of-arrays representation (built
     incrementally by history.ColumnJournal at journal time, SURVEY.md
     §7) — no per-op Python objects at all, ~25x the object walk.
-    Returns False when unavailable (no packed columns / no extension),
-    None when out of scope, else a _FastKey."""
+    `keep` (record positions) scans those records alone, as a
+    crash-stripped twin.  Returns False when unavailable (no packed
+    columns / no extension), None when out of scope, else a
+    _FastKey."""
     from jepsen_tpu import native
 
     if getattr(spec, "encode_op", None) is not None:
@@ -1374,6 +1383,8 @@ def _native_scan_cols(packed, spec, seen: dict, rows: list,
     cols = _cols_args(packed, spec)
     if cols is None:
         return False
+    if keep is not None:
+        cols = [np.take(c, keep) for c in cols]
     out = mod.fast_scan_cols(*cols, seen, rows, max_open_bits,
                              1 if want_snaps else 0)
     return _fastkey_from_native(out)
@@ -1684,18 +1695,22 @@ def _scan_history(h, ops, spec, seen: dict, rows: list,
     object C scan, then the pure-Python twin.  Returns a _FastKey or
     None (out of scope — crashed calls, deep concurrency, unencodable
     values); all three scanners are differentially pinned to classify
-    identically.  want_snaps=False skips candidate-snapshot emission
-    for callers that consume only the delta stream (fk.arrays then
-    carries empty cand_slots/cand_uops).  With `st` (a
+    identically, so the object scan retries only a key whose columns
+    are not complete (_cols_complete).  `ops` may be None: the object
+    scan then reads `h.ops`.  want_snaps=False skips candidate-snapshot
+    emission for callers that consume only the delta stream (fk.arrays
+    then carries empty cand_slots/cand_uops).  With `st` (a
     telemetry.Stages), the scanner that gave the answer is tallied
     under `jepsen_scan_keys_total{path}`: `columnar`, `objects` or
     `python`."""
     path = "columnar"
-    fk = _native_scan_cols(
-        h.packed_columns() if isinstance(h, History) else None,
-        spec, seen, rows, max_open_bits, want_snaps)
-    if fk is False or fk is None:
+    packed = h.packed_columns() if isinstance(h, History) else None
+    fk = _native_scan_cols(packed, spec, seen, rows, max_open_bits,
+                           want_snaps)
+    if fk is False or (fk is None and not _cols_complete(packed)):
         path = "objects"
+        if ops is None:
+            ops = h.ops if isinstance(h, History) else History(h).ops
         fk = _native_scan(ops, spec, seen, rows, max_open_bits)
     if fk is False:
         path = "python"
@@ -1703,6 +1718,18 @@ def _scan_history(h, ops, spec, seen: dict, rows: list,
     if st is not None:
         st.tally(telemetry.SCAN_KEYS, path=path)
     return fk
+
+
+def _cols_complete(packed) -> bool:
+    """Whether the C columnar scan sees all that the object scan sees:
+    value kinds present, no key column, and no client id outside int32
+    (P_OUT_OF_RANGE, whose real id only the ops hold).  Over complete
+    columns a key the columnar scan judges out of scope is out of the
+    object scan's scope too, so no op need be read to learn it."""
+    return (packed is not None
+            and getattr(packed, "vkind", None) is not None
+            and getattr(packed, "key", None) is None
+            and not (packed.process == P_OUT_OF_RANGE).any())
 
 
 def _fk_arrays(fk: "_FastKey"):
@@ -2167,3 +2194,29 @@ def _split_crashed(ops):
             drop[cp] = True
         crashed.append((ip, cp, ops[ip]))
     return drop, crashed
+
+
+def _split_crashed_cols(packed):
+    """Column twin of _split_crashed over complete columns
+    (_cols_complete): (the same drop mask, the number of crashed
+    calls), or None for a double invoke.  Each client invoke pairs
+    with the next record of its process: the records are grouped by
+    process, in history order within each group, with no per-record
+    Python."""
+    proc = packed.process
+    order = np.argsort(proc, kind="stable")
+    p = proc[order]
+    lo = int(np.searchsorted(p, 0))         # clients: process >= 0
+    order, p = order[lo:], p[lo:]
+    t = packed.type[order]
+    inv = t == TYPE_CODE[INVOKE]
+    follows = inv[:-1] & (p[1:] == p[:-1])  # record j+1 answers invoke j
+    if (follows & (t[1:] == TYPE_CODE[INVOKE])).any():
+        return None
+    info = follows & (t[1:] == TYPE_CODE[INFO])
+    crashed = inv.copy()
+    crashed[:-1] &= ~follows | info
+    drop = np.zeros(len(proc), bool)
+    drop[order[crashed]] = True
+    drop[order[1:][info]] = True
+    return drop, int(np.count_nonzero(crashed))

@@ -59,6 +59,7 @@ def check(model, history, *,
     packed = (history.packed_columns()
               if hasattr(history, "packed_columns") else None)
     if packed is not None and getattr(packed, "vkind", None) is not None \
+            and getattr(packed, "key", None) is None \
             and hasattr(mod, "prep_cols"):
         fmap = _spec_fmap(packed, spec)
         out = mod.prep_cols(

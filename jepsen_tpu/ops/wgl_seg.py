@@ -74,7 +74,7 @@ from typing import Any, Optional
 import numpy as np
 
 from jepsen_tpu import telemetry
-from jepsen_tpu.history import History, PackedHistory
+from jepsen_tpu.history import History
 from jepsen_tpu.ops.prep import PreparedHistory, prepare
 from jepsen_tpu.ops.frontier import (make_plane_ops as _bit_ops,
                                      reshape_shift as _reshape_shift)
@@ -1225,11 +1225,7 @@ def _relaxed_refute(model, spec, history, ops, drop, crashed,
         else None
     keep = np.nonzero(~drop)[0]
     if packed is not None and packed.vkind is not None:
-        stripped_pk = PackedHistory(
-            packed.index[keep], packed.process[keep],
-            packed.type[keep], packed.f[keep], packed.value[keep],
-            packed.value_ok[keep], packed.time[keep],
-            dict(packed.f_codes), vkind=packed.vkind[keep])
+        stripped_pk = packed.take(keep)
     else:
         from jepsen_tpu.history import pack_history
         stripped_pk = pack_history(
@@ -2435,6 +2431,39 @@ def _emit_batch_result(results, i, fk, ok: bool, backend_name: str,
             witness.append(i)
 
 
+def _scan_stripped(h, packed, spec, seen: dict, rows: list,
+                   max_open_bits: int, native_ok: bool, st):
+    """A crashed key's crash-stripped twin, scanned: (_FastKey, or None
+    while still out of scope; crash count), or None where the key holds
+    no crashed call or a malformed one.  Complete columns
+    (planner._cols_complete) split and scan as columns; the key's ops
+    are read only where its columns cannot stand for them."""
+    if native_ok and planner._cols_complete(packed):
+        split = planner._split_crashed_cols(packed)
+        if split is None or not split[1]:
+            return None
+        drop, n_crashed = split
+        sfk = _native_scan_cols(packed, spec, seen, rows, max_open_bits,
+                                keep=np.flatnonzero(~drop))
+        if sfk is not False:
+            st.tally(telemetry.SCAN_KEYS, path="columnar")
+            return sfk, n_crashed
+    ops = h.ops if isinstance(h, History) else History(h).ops
+    split = _split_crashed(ops)
+    if split is None or not split[1]:
+        return None
+    drop, crashed = split
+    stripped = [o for pos, o in enumerate(ops) if not drop[pos]]
+    sfk = _native_scan(stripped, spec, seen, rows, max_open_bits) \
+        if native_ok else False
+    path = "objects"
+    if sfk is False:
+        path = "python"
+        sfk = _fast_scan(stripped, spec, seen, rows, max_open_bits)
+    st.tally(telemetry.SCAN_KEYS, path=path)
+    return sfk, len(crashed)
+
+
 def _localize_keys(results, keys: list, model, histories) -> None:
     """The witness of each invalid batched key, from the CPU oracle on
     its history: one `witness` checker span for the whole batch."""
@@ -2636,15 +2665,17 @@ def check_many(model, histories, *, max_states: int = 64,
             if isinstance(h, PreparedHistory):
                 fall.append(i)  # pre-prepped callers take the slow path
                 continue
-            ops = h.ops if isinstance(h, History) else History(h).ops
-            if pre is not None and pre.get(i) is not None:
+            packed = h.packed_columns() if isinstance(h, History) \
+                else None
+            if pre is not None and i in pre and (
+                    pre[i] is not None or planner._cols_complete(packed)):
                 fk = pre[i]
             else:
-                # includes keys the batch scan judged out of scope: the
-                # serial ladder's object-scan retry can still recover
-                # regimes outside the COLUMNAR scope (e.g. out-of-int32
-                # client ids), exactly as before
-                fk = _scan_history(h, ops, spec, seen, rows,
+                # keys the batch scan did not take, and out-of-scope
+                # keys whose columns are not complete: the serial
+                # ladder's object-scan retry recovers what the columns
+                # cannot represent (an out-of-int32 client id)
+                fk = _scan_history(h, None, spec, seen, rows,
                                    max_open_bits, st=st)
             if fk is None:
                 # Crashed keys ride the batch as their crash-stripped
@@ -2654,22 +2685,12 @@ def check_many(model, histories, *, max_states: int = 64,
                 # Keys the stripped pass cannot prove valid are
                 # re-checked exactly afterwards (bounded crash kernel
                 # via check(), then serial fallback).
-                split = _split_crashed(ops)
-                if split is not None and split[1]:
-                    drop, crashed = split
-                    stripped = [o for pos, o in enumerate(ops)
-                                if not drop[pos]]
-                    sfk = _native_scan(stripped, spec, seen, rows,
-                                       max_open_bits) if native_ok \
-                        else False
-                    path = "objects"
-                    if sfk is False:
-                        path = "python"
-                        sfk = _fast_scan(stripped, spec, seen, rows,
-                                         max_open_bits)
-                    st.tally(telemetry.SCAN_KEYS, path=path)
+                stripped = _scan_stripped(h, packed, spec, seen, rows,
+                                          max_open_bits, native_ok, st)
+                if stripped is not None:
+                    sfk, n_crashed = stripped
                     if sfk is not None and sfk.n_calls:
-                        stripped_note[i] = len(crashed)
+                        stripped_note[i] = n_crashed
                         batch.append((i, sfk))
                         continue
                     if sfk is not None:
@@ -2678,7 +2699,7 @@ def check_many(model, histories, *, max_states: int = 64,
                         results[i] = {"valid?": True, "op_count": 0,
                                       "backend": backend_name,
                                       "engine": "wgl_seg_batch",
-                                      "crashed_ignored": len(crashed)}
+                                      "crashed_ignored": n_crashed}
                         continue
                 fall.append(i)
             elif fk.n_calls == 0:

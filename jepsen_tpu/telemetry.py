@@ -785,6 +785,8 @@ def attach_dispatch(results, record: dict,
 STAGE_SECONDS = "jepsen_stage_seconds_total"      # {engine, stage}
 CHECKER_SECONDS = "jepsen_checker_seconds_total"  # {checker, stage}
 SCAN_KEYS = "jepsen_scan_keys_total"              # {path}
+SPLIT_KEYS = "jepsen_split_keys_total"            # {path}
+SPLIT_OPS_BUILT = "jepsen_split_ops_built_total"
 DEVICE_CALLS = "jepsen_device_calls_total"        # {engine}
 LANES = "jepsen_lanes_total"                      # {engine, kind}
 WIRE_BYTES = "jepsen_wire_bytes_total"            # {engine, direction}

@@ -238,3 +238,239 @@ def test_batched_escalation_on_overflow():
              for k in range(3)]
     out = wgl_batch.check_many(models.CASRegister(), hists, frontier_size=1)
     assert [r["valid?"] for r in out] == [True, False, True]
+
+
+# ---------------------------------------------------------------------------
+# The columnar split: keyed histories stay columns through the split
+# ---------------------------------------------------------------------------
+
+MIXED_KEYS = [0, 1, "a", (2, "b"), None, 2 ** 40]
+
+
+def keyed_history(seed, double_invoke=False):
+    """A seeded keyed history with what the split and the crash split
+    must carry through: nemesis ops, `:info` crashes, invokes with no
+    completion, client ops with no KV value, unencodable and
+    out-of-int32 inner values, an out-of-int32 process and keys of
+    mixed types."""
+    rng = random.Random(seed)
+    procs = list(range(6)) + [2 ** 33]
+    key_of = {p: rng.choice(MIXED_KEYS) for p in procs}
+    open_: dict = {}
+    ops_ = []
+    for _ in range(240):
+        r = rng.random()
+        if r < 0.04:
+            ops_.append(info_op("nemesis", rng.choice(["start", "stop"]),
+                                None))
+            continue
+        live = [p for p in procs if p not in open_]
+        if open_ and (r < 0.5 or not live):
+            p = rng.choice(sorted(open_))
+            f, v = open_.pop(p)
+            kind = rng.random()
+            if kind < 0.08:
+                ops_.append(info_op(p, f, v))
+            elif kind < 0.12:
+                procs.remove(p)             # crashed, never completes
+                continue
+            elif kind < 0.2:
+                ops_.append(fail_op(p, f, v))
+            else:
+                if f == "read":
+                    inner = rng.choice([0, 1, 2, None])
+                    v = v if not isinstance(v, ind.KV) \
+                        else ind.KV(v.key, inner)
+                ops_.append(ok_op(p, f, v))
+            continue
+        if not live:
+            break
+        p = rng.choice(live)
+        f = rng.choice(["read", "write", "cas"])
+        inner = {"read": None, "write": rng.choice([0, 1, 2, 2 ** 35]),
+                 "cas": rng.choice([[0, 1], [1, 2], [0, 1, 2]])}[f]
+        if rng.random() < 0.05:
+            inner = "unencodable"
+        # the out-of-int32 process keeps to its key, so the other keys'
+        # columns stay complete
+        bare = rng.random() < 0.06 and p < 2 ** 31
+        v = inner if bare else ind.KV(key_of[p], inner)
+        ops_.append(invoke_op(p, f, v))
+        open_[p] = (f, v)
+        if double_invoke and len(ops_) == 60:
+            ops_.append(invoke_op(p, f, v))
+    return ops_
+
+
+def with_columns(ops_, how):
+    if how == "journal":
+        return History(ops_, journal=True).index()
+    from jepsen_tpu.history import pack_history
+    h = History(ops_).index()
+    return h.attach_packed(pack_history(h))
+
+
+def tagged_columns(packed):
+    """The columns as comparable lists, f by tag rather than code."""
+    tag = {c: t for t, c in packed.f_codes.items()}
+    return {"index": packed.index.tolist(),
+            "process": packed.process.tolist(),
+            "type": packed.type.tolist(),
+            "f": [tag.get(c) for c in packed.f.tolist()],
+            "value": packed.value.tolist(),
+            "value_ok": packed.value_ok.tolist(),
+            "time": packed.time.tolist(),
+            "vkind": packed.vkind.tolist(),
+            "key": packed.key}
+
+
+@pytest.mark.parametrize("how", ["journal", "pack_history"])
+@pytest.mark.parametrize("seed", range(6))
+def test_columnar_split_matches_object_split(seed, how):
+    from jepsen_tpu.history import pack_history
+
+    h = with_columns(keyed_history(seed), how)
+    assert h.packed_columns().key is not None
+    ks = sorted(ind.history_keys(h), key=repr)
+    assert len(ks) >= 3
+    subs = ind.subhistories(ks, h)
+    assert all(isinstance(s, ind._KeyHistory) for s in subs)
+    for k, sub in zip(ks, subs):
+        ref = ind.subhistory(k, h)
+        assert len(sub) == len(ref)
+        # the columns stand for the ops before any op is built
+        assert tagged_columns(sub.packed_columns()) \
+            == tagged_columns(pack_history(ref))
+        assert not sub.ops_built
+        assert sub.ops == ref.ops
+        assert sub.ops_built
+        # un-keyed ops are shared, keyed ones are new unwrapped copies
+        for o, r, parent in zip(sub.ops, ref.ops, (h.ops[i] for i in
+                                                   sub._rows.tolist())):
+            assert (o is parent) == (r is parent)
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_history_keys_with_and_without_columns(seed):
+    ops_ = keyed_history(seed)
+    with_cols = with_columns(ops_, "journal")
+    plain = History(ops_)
+    assert plain.packed_columns() is None
+    assert ind.history_keys(with_cols) == ind.history_keys(plain)
+    # a key the history lacks holds the un-keyed ops alone, either way
+    absent_cols, absent = (ind.subhistories(["absent"], x)[0]
+                           for x in (with_cols, plain))
+    assert absent_cols.ops == absent.ops
+    # and a history with columns but no keyed record has no keys
+    assert ind.history_keys(
+        History([invoke_op(0, "read", None), ok_op(0, "read", 1)],
+                journal=True)) == set()
+
+
+def test_unhashable_key_takes_the_object_split():
+    from jepsen_tpu.history import KEY_UNHELD
+
+    h = History([invoke_op(0, "read", ind.KV([1], None)),
+                 ok_op(0, "read", ind.KV([1], 2))], journal=True)
+    assert (h.packed_columns().key == KEY_UNHELD).all()
+    with pytest.raises(TypeError):
+        ind.history_keys(h)          # as the object walk always did
+
+
+@pytest.mark.parametrize("double_invoke", [False, True])
+@pytest.mark.parametrize("seed", range(6))
+def test_split_crashed_cols_matches_objects(seed, double_invoke):
+    from jepsen_tpu.ops import planner
+
+    h = with_columns(keyed_history(seed, double_invoke), "journal")
+    ks = sorted(ind.history_keys(h), key=repr)
+    compared = 0
+    for sub in ind.subhistories(ks, h):
+        packed = sub.packed_columns()
+        if not planner._cols_complete(packed):
+            assert (packed.process == -2).any()   # the out-of-int32 id
+            continue
+        want = planner._split_crashed(sub.ops)
+        got = planner._split_crashed_cols(packed)
+        if want is None:
+            assert got is None
+            continue
+        compared += 1
+        assert got[0].tolist() == want[0].tolist()
+        assert got[1] == len(want[1])
+    assert compared >= 1 or double_invoke
+
+
+def _counts(name):
+    from jepsen_tpu import telemetry
+    _, by_label = telemetry.REGISTRY.collect().get(name, ("counter", {}))
+    return {dict(k).get("path"): float(m.value)
+            for k, m in by_label.items()}
+
+
+def _delta(after, before):
+    return {k: v - before.get(k, 0.0) for k, v in after.items()
+            if v - before.get(k, 0.0)}
+
+
+def journaled_campaign_history():
+    """Three keys through the run loop's journal: one crash-free, one
+    with a crashed write, one with a planted stale read."""
+    ops_ = []
+    for k, crash, bad in ((0, False, False), (1, True, False),
+                          (2, False, True)):
+        rng = random.Random(40 + k)
+        value, sub = None, []
+        for i in range(24):
+            p = 10 * k + i % 2
+            if rng.random() < 0.5:
+                sub += [invoke_op(p, "read", None), ok_op(p, "read", value)]
+            else:
+                value = rng.randint(0, 4)
+                sub += [invoke_op(p, "write", value),
+                        ok_op(p, "write", value)]
+        if crash:
+            sub[4:4] = [invoke_op(10 * k + 3, "write", 9)]
+            sub += [info_op(10 * k + 3, "write", 9)]
+        if bad:
+            sub += [invoke_op(10 * k + 5, "read", None),
+                    ok_op(10 * k + 5, "read", 7)]
+        ops_ += [o.assoc(value=ind.KV(k, o.value)) for o in sub]
+    ops_.insert(7, info_op("nemesis", "start", None))
+    h = History(journal=True)
+    for o in ops_:
+        h.append(o)
+    return h.index()
+
+
+def test_journaled_keyed_run_checks_as_its_object_twin():
+    from jepsen_tpu import telemetry
+
+    h = journaled_campaign_history()
+    plain = History(list(h.ops))
+    chk = ind.batch_checker(models.CASRegister())
+    names = ("jepsen_scan_keys_total", "jepsen_split_keys_total",
+             telemetry.SPLIT_OPS_BUILT)
+    runs = {}
+    for label, hist in (("columns", h), ("objects", plain)):
+        before = {n: _counts(n) for n in names}
+        r = chk.check({}, hist, {})
+        runs[label] = (r, {n: _delta(_counts(n), before[n])
+                           for n in names})
+    (rc, nc), (ro, no) = runs["columns"], runs["objects"]
+    assert rc["failures"] == ro["failures"] == [2]
+    assert {k: (v["valid?"], v.get("op_index"))
+            for k, v in rc["results"].items()} \
+        == {k: (v["valid?"], v.get("op_index"))
+            for k, v in ro["results"].items()}
+    assert rc["results"][1].get("crashed_ignored") == 1
+    assert rc["results"][2]["op_index"] is not None
+    # with columns: no op-object scan, ops built for the invalid key only
+    assert "objects" not in nc["jepsen_scan_keys_total"]
+    assert nc["jepsen_split_keys_total"] == {"columnar": 3.0}
+    assert nc[telemetry.SPLIT_OPS_BUILT] == {None: 1.0}
+    # without (cli analyze): the object split and scans, as before —
+    # three keys and the crashed key's stripped twin
+    assert no["jepsen_scan_keys_total"] == {"objects": 4.0}
+    assert no["jepsen_split_keys_total"] == {"objects": 3.0}
+    assert no[telemetry.SPLIT_OPS_BUILT] == {None: 3.0}
