@@ -41,6 +41,7 @@ from typing import Optional
 
 import numpy as np
 
+from jepsen_tpu import telemetry
 from jepsen_tpu import txn as mop
 from jepsen_tpu.history import History
 
@@ -61,11 +62,11 @@ class Inference:
     The dependency and process edges are kept as per-plane edge lists
     (`lists`: ww, wr, rw, po) and the realtime order as the txns'
     invoke and completion indices (`order_idx`), from which
-    `packed_stacked` builds the bit-packed planes directly: rt a block
-    of rows at a time, the rest by sparse word insertion.  The dense
-    planes and their [P, n, n] stack are built on first access only,
-    for the dense tiers and the callers that read them; the mesh tier
-    never touches them."""
+    `packed_stacked` builds the bit-packed planes directly: rt as
+    nested rows (`_rt_pack_into`), the rest by sparse word insertion.
+    The dense planes and their [P, n, n] stack are built on first
+    access only, for the dense tiers and the callers that read them;
+    the mesh tier never touches them."""
 
     txns: list                    # (invoke, ok) Op pairs, completion order
     direct: dict                  # anomaly name -> [witness dicts]
@@ -108,7 +109,8 @@ class Inference:
         """Planes as one bit-packed uint32 [len(PLANES), n_pad, W]
         stack, with no dense plane on the way: list planes by sparse
         word insertion (ops.elle_mesh.set_bits, on the native ingest
-        layer), rt packed a block of rows at a time.  Equal to
+        layer), rt as nested rows (`_rt_pack_into`, under the
+        trace-only `jepsen.pack.rt`).  Equal to
         elle_mesh.pack_planes(self.stacked())."""
         from jepsen_tpu.ops import elle_mesh
         if n_pad is None:
@@ -116,7 +118,8 @@ class Inference:
         out = np.zeros((len(PLANES), n_pad, n_pad // 32), np.uint32)
         for pi, p in enumerate(PLANES):
             if p == "rt":
-                _rt_pack_into(out[pi], *self.order_idx)
+                with telemetry.trace("pack.rt"):
+                    _rt_pack_into(out[pi], *self.order_idx)
             else:
                 elle_mesh.set_bits(out[pi], *self.lists[p])
         return out
@@ -124,7 +127,6 @@ class Inference:
 
 # planes held as edge lists; rt is held as the order indices
 LIST_PLANES = ("ww", "wr", "rw", "po")
-_RT_BLOCK = 1024                  # rows per rt block: ~n KiB of bools
 
 
 def _rt_rows(inv: np.ndarray, ok: np.ndarray, r0: int,
@@ -142,17 +144,37 @@ def _rt_rows(inv: np.ndarray, ok: np.ndarray, r0: int,
 def _rt_pack_into(plane: np.ndarray, inv: np.ndarray,
                   ok: np.ndarray) -> None:
     """The realtime plane packed into `plane` [n_pad, W] (bit b of word
-    w is column 32w + b), one block of rows at a time."""
+    w is column 32w + b), rows 0..n written whole.
+
+    a -> b iff ok[a] < inv[b], an interval order, so its rows nest:
+    taken in descending ok, each known row is the one before it plus the
+    columns whose invoke lies between the two completions.  Column b
+    first appears at position k_b, the number of known rows with
+    ok >= inv[b] (a searchsorted on the sorted ok; counting ties there
+    is what keeps the < strict), and stays in every row after.  So one
+    bit per column goes into a delta, a prefix OR down the delta gives
+    every row, and the rows go back to txn order: a reversed slice
+    where txns are in completion order (`collect_txns`), a scatter
+    otherwise.  Unknown rows stay zero, unknown columns are never set,
+    and the diagonal (a txn invoked after its own completion) is
+    cleared, as in `_rt_rows`."""
+    from jepsen_tpu.ops import elle_mesh
     n = len(inv)
-    if not n:
-        return
     w = -(-n // 32)
-    for r0 in range(0, n, _RT_BLOCK):
-        r1 = min(n, r0 + _RT_BLOCK)
-        rows = np.zeros((r1 - r0, w * 32), bool)
-        rows[:, :n] = _rt_rows(inv, ok, r0, r1)
-        plane[r0:r1, :w] = np.packbits(
-            rows, axis=1, bitorder="little").view("<u4")
+    known = np.flatnonzero((inv >= 0) & (ok >= 0))
+    rows = known[np.argsort(-ok[known], kind="stable")]
+    k = len(rows) - np.searchsorted(ok[rows[::-1]], inv[known], "left")
+    enter = k < len(rows)
+    delta = np.zeros((len(rows), w), np.uint32)
+    elle_mesh.set_bits(delta, k[enter], known[enter])
+    np.bitwise_or.accumulate(delta, axis=0, out=delta)
+    if len(rows) == n and (rows == np.arange(n - 1, -1, -1)).all():
+        plane[:n, :w] = delta[::-1]
+    else:
+        plane[:n, :w] = 0
+        plane[rows, :w] = delta
+    own = known[ok[known] < inv[known]]
+    elle_mesh.clear_bits(plane, own, own)
 
 
 def _rt_count(inv: np.ndarray, ok: np.ndarray) -> int:

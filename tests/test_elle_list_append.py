@@ -2,7 +2,8 @@
 plain reference (`benchmark/references/elle_list_append.py`) on seeded
 simulated runs, clean and with each anomaly class planted, on the host,
 dense device and mesh tiers; the packed planes built from the
-inference's edge lists against the dense stack; a mesh-tier check that
+inference's edge lists against the dense stack, and the realtime
+plane's nested-row pack against the dense rows; a mesh-tier check that
 builds no dense plane, at a full run's size too; the padding rule; the
 stage seconds and counters of one call; and inference linear in the
 history."""
@@ -297,6 +298,84 @@ def test_realtime_plane_with_unknown_indices():
     infer_mod._rt_pack_into(plane, inv, ok)
     assert (plane == elle_mesh.pack_planes(dense[None], n_pad=128)[0]).all()
     assert infer_mod._rt_count(inv, ok) == int(dense.sum())
+
+
+def _order_vectors(kind: str, n: int, seed: int) -> tuple:
+    """(inv, ok) index vectors of one shape: `completion` in completion
+    order (`collect_txns`' case), `shuffled` the same out of order,
+    `unknown` with -1 on either side, `inverted` with invokes after
+    completions, `ties` drawn from a few values so completions equal
+    other txns' invokes."""
+    rng = np.random.default_rng(seed)
+    inv = np.sort(rng.integers(0, 3 * n + 1, n))
+    ok = inv + rng.integers(1, 8, n)
+    order = np.argsort(ok, kind="stable")
+    inv, ok = inv[order], ok[order]
+    if kind == "shuffled":
+        perm = rng.permutation(n)
+        inv, ok = inv[perm], ok[perm]
+    elif kind == "unknown":
+        inv = np.where(rng.random(n) < 0.2, -1, inv)
+        ok = np.where(rng.random(n) < 0.2, -1, ok)
+    elif kind == "inverted":
+        flip = rng.random(n) < 0.3
+        inv, ok = np.where(flip, ok, inv), np.where(flip, inv, ok)
+    elif kind == "ties":
+        inv = rng.integers(-1, 6, n)
+        ok = rng.integers(-1, 6, n)
+    return inv.astype(np.int64), ok.astype(np.int64)
+
+
+@pytest.mark.parametrize("n_dev", [1, 4])
+@pytest.mark.parametrize("n", [0, 1, 31, 32, 33, 1000])
+@pytest.mark.parametrize(
+    "kind", ["completion", "shuffled", "unknown", "inverted", "ties"])
+def test_realtime_pack_equals_the_dense_rows(kind, n, n_dev):
+    """The nested-row rt pack is the dense `_rt_rows` plane packed, bit
+    for bit, padding included."""
+    inv, ok = _order_vectors(kind, n, 2**31 + 17 * n + n_dev)
+    n_pad = elle_mesh.pad_for_mesh(n, n_dev)
+    plane = np.zeros((n_pad, n_pad // 32), np.uint32)
+    infer_mod._rt_pack_into(plane, inv, ok)
+    dense = infer_mod._rt_rows(inv, ok, 0, n)
+    assert (plane == elle_mesh.pack_planes(dense[None], n_pad=n_pad)[0]).all()
+    assert infer_mod._rt_count(inv, ok) == int(dense.sum())
+
+
+@pytest.mark.parametrize("plant", [False, True])
+def test_realtime_pack_of_a_simulated_run(plant):
+    """On a simulated run of the cell's traffic the rt pack equals the
+    dense rows, and is equal again with the order reversed in time (the
+    `realtime_reversed` fault's call, which takes the scatter branch)."""
+    h, _ = append_sim.generate(SMALL, 2**31 + 11, plant)
+    inv, ok = infer_mod.infer(h, workload="list-append").order_idx
+    n = len(inv)
+    assert n > 300 and (np.diff(ok) > 0).all()
+    top = int(max(inv.max(), ok.max())) + 1
+    for a, b in ((inv, ok), (top - ok, top - inv)):
+        n_pad = elle_mesh.pad_for_mesh(n, 1)
+        plane = np.zeros((n_pad, n_pad // 32), np.uint32)
+        infer_mod._rt_pack_into(plane, a, b)
+        dense = infer_mod._rt_rows(a, b, 0, n)
+        assert dense.any()
+        want = elle_mesh.pack_planes(dense[None], n_pad=n_pad)[0]
+        assert (plane == want).all()
+
+
+def test_packed_stack_builds_no_dense_realtime_rows(monkeypatch):
+    """`packed_stacked` never builds an n x n bool: with `_rt_rows`
+    refusing, the stack still equals the dense pack taken before."""
+    h, _ = planted(2**31 + 9, "G1c")
+    inf = infer_mod.infer(h, workload="list-append")
+    want = elle_mesh.pack_planes(inf.stacked())
+    inf = infer_mod.infer(h, workload="list-append")
+
+    def refuse(*a, **kw):
+        raise AssertionError("dense realtime rows built")
+    monkeypatch.setattr(infer_mod, "_rt_rows", refuse)
+    got = inf.packed_stacked()
+    assert got.shape[0] == len(infer_mod.PLANES)
+    assert (got == want).all()
 
 
 def test_mesh_check_builds_no_dense_plane(monkeypatch):
