@@ -37,7 +37,7 @@ from pathlib import Path
 
 ROOT = Path(__file__).resolve().parent
 
-# The sizes of the reference's own recipes (bench.py's BASELINE configs):
+# The sizes of the reference's own recipes (BASELINE.json's configs):
 # the etcd suite's 300 ops per key over thousands of keys, a 100k-op CAS
 # register, a crashed-op run, the deep-overlap envelope, and 10k-txn
 # list-append histories.
@@ -57,6 +57,166 @@ class PhaseFailed(AssertionError):
 def expect(cond, what: str) -> None:
     if not cond:
         raise PhaseFailed(what)
+
+
+def make_history(n_ops: int, concurrency: int, seed: int = 7,
+                 vmax: int = 4, crash_rate: float = 0.0,
+                 max_open: int = 0, crash_vmax: int = 0) -> "History":
+    """An etcd-shaped register workload (r/w/cas mix, etcd.clj:145-147)
+    executed against a sequentially-consistent in-memory register with
+    process interleaving.  With crash_rate, that fraction of calls
+    time out (:info, never taking effect) — the nemesis-run shape the
+    reference calls its worst cost driver (a crashed op stays
+    concurrent with the entire rest of the history,
+    doc/tutorial/06-refining.md:12-19).  max_open > 0 bounds the
+    simultaneously-open NORMAL calls (bursty interleaving: many worker
+    processes, bounded overlap depth — the live-process count still
+    spans `concurrency`)."""
+    from jepsen_tpu.history import (History, fail_op, info_op, invoke_op,
+                                    ok_op, pack_history)
+
+    rng = random.Random(seed)
+    ops, value = [], None
+    open_ops: dict = {}  # process -> (completion op) pending flush
+    procs = list(range(concurrency))
+    i = 0
+    while i < n_ops:
+        p = rng.choice(procs)
+        if p in open_ops:
+            ops.append(open_ops.pop(p))
+            continue
+        if max_open and len(open_ops) >= max_open:
+            if open_ops:
+                ops.append(open_ops.pop(rng.choice(list(open_ops))))
+            continue
+        i += 1
+        f = rng.choice(("read", "read", "write", "cas"))
+        if crash_rate and rng.random() < crash_rate:
+            # timed-out call: invoke journaled, :info completion, no
+            # effect on the register (the DB never applied it).
+            # crash_vmax > 0 restricts CRASHED ops' values to
+            # 0..crash_vmax so a subtle-violation planter can pick a
+            # legal value that is provably not crash-explainable
+            cm = crash_vmax or vmax
+            v = (None if f == "read" else rng.randint(0, cm)
+                 if f == "write" else
+                 [rng.randint(0, cm), rng.randint(0, cm)])
+            ops.append(invoke_op(p, f, v))
+            ops.append(info_op(p, f, v))
+            continue
+        if f == "read":
+            ops.append(invoke_op(p, "read", None))
+            open_ops[p] = ok_op(p, "read", value)
+        elif f == "write":
+            v = rng.randint(0, vmax)
+            ops.append(invoke_op(p, "write", v))
+            value = v
+            open_ops[p] = ok_op(p, "write", v)
+        else:
+            old, new = rng.randint(0, vmax), rng.randint(0, vmax)
+            ops.append(invoke_op(p, "cas", [old, new]))
+            if value == old:
+                value = new
+                open_ops[p] = ok_op(p, "cas", [old, new])
+            else:
+                open_ops[p] = fail_op(p, "cas", [old, new])
+    for comp in open_ops.values():
+        ops.append(comp)
+    h = History(ops).index()
+    # The framework's run loop journals ops into a ColumnJournal as
+    # they land (core.py), so a real history's columnar representation
+    # exists before analysis starts; building it here at construction
+    # time reproduces that (the scan engines then never walk Python
+    # objects).  The CPU oracle still receives the Op objects.
+    h.attach_packed(pack_history(h))
+    return h
+
+
+def plant_stale_read(h: "History", frac: float, vmax: int,
+                     forbidden=()) -> "tuple[int, int] | None":
+    """Plant a SUBTLE violation (VERDICT r3 #4): rewrite one ok-read to
+    a LEGAL value w that no linearization can produce — w is excluded
+    from the read's concurrency window (not the register value at the
+    window start, not written/cas-targeted by any call whose own
+    window intersects it) — instead of an out-of-domain constant.  The
+    violation is invisible to any local scan (w is written legitimately
+    elsewhere in the history) and refuting it requires the search to
+    carry the true state set to the read's depth.  `forbidden` removes
+    further candidates (e.g. every crashed call's value, so the
+    crash-relaxed tier's epsilon-jumps cannot explain w either).
+    Mutates h in place; returns (op_position, planted_value) or None.
+
+    Window analysis: walk the ops maintaining the sequential register
+    value and each process's open invoke; for the chosen read, V = the
+    value at its invoke + every write value / cas target of calls
+    whose [invoke, complete] intersects the read's window.  Only such
+    calls can linearize inside the window, so any legal w outside V
+    (and outside `forbidden`) makes the read impossible."""
+    import numpy as np
+
+    from jepsen_tpu.history import pack_history
+    ops = h.ops
+    n = len(ops)
+    value_at = np.zeros(n + 1, np.int64)     # seq value BEFORE op i
+    cur = -1                                 # None encoded as -1
+    for i, o in enumerate(ops):
+        value_at[i] = cur
+        if o.type == "ok" and o.f == "write":
+            cur = o.value
+        elif o.type == "ok" and o.f == "cas":
+            cur = o.value[1]
+    value_at[n] = cur
+    # per-call (invoke_pos, completion_pos|inf, candidate value):
+    # a call can linearize inside a window iff its own span intersects
+    # it; crashed calls (no completion) stay open to the end
+    pend: dict = {}
+    inv_of: dict = {}
+    inv_pos, comp_pos, wval = [], [], []
+    for i, o in enumerate(ops):
+        if o.type == "invoke":
+            pend[o.process] = len(inv_pos)
+            inv_pos.append(i)
+            comp_pos.append(n)
+            v = None
+            if o.f == "write":
+                v = o.value
+            elif o.f == "cas":
+                v = o.value[1]
+            wval.append(-1 if v is None else int(v))
+        elif o.process in pend:
+            c = pend.pop(o.process)
+            comp_pos[c] = i
+            inv_of[i] = inv_pos[c]
+    inv_pos = np.asarray(inv_pos, np.int64)
+    comp_pos = np.asarray(comp_pos, np.int64)
+    wval = np.asarray(wval, np.int64)
+    reads = [i for i, o in enumerate(ops)
+             if o.type == "ok" and o.f == "read"
+             and o.value is not None and i in inv_of]
+    start = int(len(reads) * frac)
+    for i in reads[start:] + reads[:start]:
+        lo = inv_of[i]
+        # A write X can be the read's last-write in SOME linearization
+        # iff X invokes before the read completes AND no write Y is
+        # FORCED between them (Y forced <=> inv_Y > comp_X and
+        # comp_Y < lo).  With M = max invoke position of writes
+        # completing before the window, X qualifies iff comp_X >= M —
+        # this keeps real-time-maximal writes that finish before the
+        # window opens (ordering them last is legal), which a naive
+        # comp >= lo overlap test wrongly excludes.
+        before = (comp_pos < lo) & (wval >= 0)
+        M = int(inv_pos[before].max()) if before.any() else 0
+        touch = (inv_pos <= i) & (comp_pos >= M) & (wval >= 0)
+        V = set(int(x) for x in np.unique(wval[touch]))
+        V.add(int(value_at[lo]))
+        w = next((x for x in range(vmax + 1)
+                  if x not in V and x not in forbidden), None)
+        if w is None:
+            continue
+        ops[i].value = w
+        h.attach_packed(pack_history(h))
+        return i, w
+    return None
 
 
 class Smoke:
@@ -151,12 +311,11 @@ class Smoke:
 
     def planted(self, n_ops, conc, seed, vmax=4, **kw):
         """(history, witness index) with a stale read planted at 90%
-        depth that no linearization can explain (bench.plant_stale_read);
+        depth that no linearization can explain (plant_stale_read);
         the witness is the planted read's invoke."""
-        import bench
         for s in range(seed, seed + 20):
-            h = bench.make_history(n_ops, conc, seed=s, vmax=vmax, **kw)
-            got = bench.plant_stale_read(
+            h = make_history(n_ops, conc, seed=s, vmax=vmax, **kw)
+            got = plant_stale_read(
                 h, 0.9, vmax, forbidden=set(range(kw["crash_vmax"] + 1))
                 if kw.get("crash_vmax") else ())
             if got is not None:
@@ -275,16 +434,14 @@ class Smoke:
     def multikey(self):
         """The etcd-shaped independent register through the suites'
         batched independent checker (-> wgl_seg.check_many)."""
-        import bench
-
         from jepsen_tpu import independent
         from jepsen_tpu.history import History
         S = self.S
-        hists = [bench.make_history(S["key_ops"], S["key_conc"],
-                                    seed=self.seed + 1000 + k)
+        hists = [make_history(S["key_ops"], S["key_conc"],
+                              seed=self.seed + 1000 + k)
                  for k in range(S["keys"])]
         bad = S["keys"] // 2
-        expect(bench.plant_stale_read(hists[bad], 0.9, 4) is not None,
+        expect(plant_stale_read(hists[bad], 0.9, 4) is not None,
                "no plantable stale read")
         ops = [o.assoc(value=independent.KV(k, o.value), index=None)
                for k, h in enumerate(hists) for o in h.ops]
@@ -312,12 +469,10 @@ class Smoke:
     def single(self):
         """One 100k-op CAS register through checker.linearizable, and
         its twin with a stale read at 90% depth."""
-        import bench
-
         from jepsen_tpu import checker as ck
         S = self.S
         lin = ck.linearizable(self.model)
-        h = bench.make_history(S["single_ops"], 5, seed=self.seed, vmax=9)
+        h = make_history(S["single_ops"], 5, seed=self.seed, vmax=9)
         bad, wit = self.planted(S["single_ops"], 5, self.seed + 1, vmax=9)
         t0 = time.monotonic()
         v, vb = lin.check(None, h), lin.check(None, bad)
@@ -328,19 +483,17 @@ class Smoke:
                           "planted": self.agree(vb, bad, False, wit)}}
 
     def crash(self):
-        """A 50k-op history with ~1% crashed calls (bench's hard
+        """A 50k-op history with ~1% crashed calls (the hard
         regime) and its planted twin, both through
         checker.linearizable.  The twin's witness is the planted read:
         the planter proves no linearization, crashed calls included,
         can explain it.  The oracle that rebuilds its final-paths is
         exponential in the crashed calls, so it runs under the artifact
         caps and may report final-paths-error instead."""
-        import bench
-
         from jepsen_tpu import checker as ck
         S = self.S
-        h = bench.make_history(S["crash_ops"], 16, seed=self.seed + 2,
-                               crash_rate=0.01, max_open=6)
+        h = make_history(S["crash_ops"], 16, seed=self.seed + 2,
+                         crash_rate=0.01, max_open=6)
         bad, wit = self.planted(S["crash_ops"], 16, self.seed + 3,
                                 vmax=9, crash_rate=0.01, max_open=6,
                                 crash_vmax=7)
@@ -364,14 +517,12 @@ class Smoke:
     def deep(self):
         """16 x 20k-op histories at max_open 12 through the compiled
         wgl_deep megakernel (wgl_deep.check_pipeline), one planted."""
-        import bench
-
         from jepsen_tpu.ops import wgl_deep
         S = self.S
         mo = S["deep_open"]
-        hs = [bench.make_history(S["deep_ops"], 16,
-                                 seed=self.seed + 41 + 101 * s, vmax=9,
-                                 max_open=mo)
+        hs = [make_history(S["deep_ops"], 16,
+                           seed=self.seed + 41 + 101 * s, vmax=9,
+                           max_open=mo)
               for s in range(S["deep_hists"] - 1)]
         bad, wit = self.planted(S["deep_ops"], 16, self.seed + 57,
                                 vmax=9, max_open=mo)
@@ -506,7 +657,6 @@ class Smoke:
         import numpy as np
         from jax.sharding import Mesh
 
-        import bench
         from jepsen_tpu.ops import elle_mesh, wgl_deep, wgl_seg
         S, m = self.S, self.model
         n = len(self.devices)
@@ -518,10 +668,10 @@ class Smoke:
             expect(key == [(x["valid?"], x.get("op_index")) for x in b],
                    f"{what}: mesh and one-device verdicts differ")
 
-        hists = [bench.make_history(S["key_ops"], S["key_conc"],
-                                    seed=self.seed + 1000 + k)
+        hists = [make_history(S["key_ops"], S["key_conc"],
+                              seed=self.seed + 1000 + k)
                  for k in range(S["keys"])]
-        bench.plant_stale_read(hists[S["keys"] // 2], 0.9, 4)
+        plant_stale_read(hists[S["keys"] // 2], 0.9, 4)
         one = wgl_seg.check_many(m, hists)
         seen.clear()
         many = wgl_seg.check_many(m, hists, mesh=mesh, mesh_axis="keys")
@@ -530,7 +680,7 @@ class Smoke:
                              "invalid": sum(not x["valid?"] for x in many),
                              **self.spans(seen, n)}
 
-        h = bench.make_history(S["single_ops"], 5, seed=self.seed, vmax=9)
+        h = make_history(S["single_ops"], 5, seed=self.seed, vmax=9)
         bad, wit = self.planted(S["single_ops"], 5, self.seed + 1, vmax=9)
         one = [wgl_seg.check(m, x) for x in (h, bad)]
         seen.clear()
@@ -544,9 +694,9 @@ class Smoke:
                                  **self.spans(seen, n)}
 
         mo = S["deep_open"]
-        dh = [bench.make_history(S["deep_ops"], 16,
-                                 seed=self.seed + 41 + 101 * s, vmax=9,
-                                 max_open=mo) for s in range(n - 1)]
+        dh = [make_history(S["deep_ops"], 16,
+                           seed=self.seed + 41 + 101 * s, vmax=9,
+                           max_open=mo) for s in range(n - 1)]
         dbad, dwit = self.planted(S["deep_ops"], 16, self.seed + 57,
                                   vmax=9, max_open=mo)
         dh.append(dbad)
@@ -598,12 +748,9 @@ class Smoke:
         """A history forced to max_open R by a burst of R concurrent
         writes, and its twin ending in a read of a never-written value
         (witness: that read)."""
-        import bench
-
         from jepsen_tpu.history import History, invoke_op, ok_op, \
             pack_history
-        base = bench.make_history(n_ops, 20, seed=985, vmax=9,
-                                  max_open=14)
+        base = make_history(n_ops, 20, seed=985, vmax=9, max_open=14)
         burst = ([invoke_op(100 + p, "write", p % 10) for p in range(R)]
                  + [ok_op(100 + p, "write", p % 10) for p in range(R)])
         out = []

@@ -28,7 +28,7 @@ Detection lag is measured from the WAL append wall stamp (`w` field,
 history.follow) to the flag emission — true op-append→flag latency
 when checker and run share a clock; `live_window_lag_seconds` tracks
 the same quantity for every checked window (clean ones included), and
-its p99 is the bench.py headline for the service.
+its p99 is the service's headline lag.
 
 **Fleet mode** (`worker_id` + `lease_ttl`, ISSUE 14): N schedulers
 over one root partition the tenants through per-run `lease.json`

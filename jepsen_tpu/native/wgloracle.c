@@ -3,8 +3,8 @@
  * The C twin of jepsen_tpu/ops/wgl_cpu.check's hot loop — Lowe-style
  * just-in-time linearization with memoization, the same algorithm
  * knossos :linear implements on the JVM (checker.clj:141-145).  It
- * exists to BOUND THE BASELINE CONSTANT: bench.py reports device
- * speedups against both the Python oracle (the knossos-equivalent
+ * exists to BOUND THE BASELINE CONSTANT: device speedups are stated
+ * against both the Python oracle (the knossos-equivalent
  * reference implementation) and this native one, so no ratio hides an
  * interpreter constant (VERDICT r2 #5).
  *

@@ -63,7 +63,6 @@ ResilientRunner chain (elle-mesh -> elle-device -> elle-host).
 from __future__ import annotations
 
 import math
-import os
 import time
 from typing import Optional, Sequence
 
@@ -243,10 +242,9 @@ def clear_plan_cache() -> None:
 
 def _block_for(n_pad: int) -> int:
     """Largest tile (bits) that divides n_pad — bounds the dense
-    in-flight unpacked tiles.  JEPSEN_TPU_ELLE_BLOCK caps it."""
-    cap = int(os.environ.get("JEPSEN_TPU_ELLE_BLOCK", 2048))
+    in-flight unpacked tiles."""
     for b in (2048, 1024, 512, 256, 128):
-        if b <= cap and n_pad % b == 0:
+        if n_pad % b == 0:
             return b
     return _TILE
 

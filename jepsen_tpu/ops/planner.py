@@ -1,36 +1,30 @@
-"""One engine planner: the (R, crashes, Sn, batch, mesh, env) ->
+"""One engine planner: the (R, crashes, Sn, batch, mesh, backend) ->
 engine-chain decision, made in ONE place (ROADMAP #1).
 
 Before this module the routing across the eight `ops/` engines was
 scattered through `wgl_seg.check/_check_fast/check_pipeline/check_many`
 private `if` ladders, `wgl_deep.supported`, `checker/elle.py`'s tier
-chain, `live/engine.py`'s bucket keys, and half a dozen `JEPSEN_TPU_*`
-env knobs read at the point of use.  Every entry point now asks
+chain and `live/engine.py`'s bucket keys.  Every entry point now asks
 `plan_engines(shape) -> Plan` and follows the plan; the Plan object is
 *inspectable* (`plan.engine`, `plan.fallbacks`, `plan.why`,
-`plan.bucket`, `plan.pruned`, `plan.rejected`) and is rendered verbatim
-into the dispatch record every verdict carries
-(`telemetry.attach_dispatch`), so `results.json` explains its own
-routing instead of requiring the reader to re-derive eight modules'
-worth of gating.
+`plan.bucket`, `plan.rejected`) and is rendered verbatim into the
+dispatch record every verdict carries (`telemetry.attach_dispatch`), so
+`results.json` explains its own routing instead of requiring the reader
+to re-derive eight modules' worth of gating.
 
-Three design rules, property-tested in tests/test_planner.py:
+Two design rules, property-tested in tests/test_planner.py:
 
   * **Purity** — `plan_engines` is a pure function of (shape, env,
-    backend).  The env is an explicit dict (default: a snapshot of the
-    `JEPSEN_TPU_*` process environment), so plans are reproducible and
-    testable without monkeypatching.
+    backend): routing follows the history's shape and the backend,
+    never a tuning variable.  The env is an explicit dict (default: a
+    snapshot of the `JEPSEN_TPU_*` process environment) with one
+    routing input, `JEPSEN_TPU_DEEP_INTERPRET`: a backend capability
+    that widens what the 'cpu' backend can run (the Pallas
+    interpreter) exactly as running on a TPU would.  The plan also
+    records `JEPSEN_TPU_PACK_THREADS`, the host ingest pool's size.
   * **One terminating chain** — every shape routes to exactly one
     ordered chain whose last engine is total (`wgl_cpu`, `elle-host`,
     `live-host`): no shape can fall off the end of the ladder.
-  * **Knobs only prune** — `JEPSEN_TPU_*` knobs remove engines from
-    the base chain (recorded in `plan.pruned`), they never insert
-    engines the shape wasn't already eligible for.  The one apparent
-    exception, `JEPSEN_TPU_DEEP_INTERPRET`, is a *backend capability*
-    input (it widens what the 'cpu' backend can run — the Pallas
-    interpreter — exactly as running on a TPU would), not a routing
-    knob; it is threaded through `deep_supported`'s backend argument,
-    never through the prune table.
 
 On top of the routing decision sit the two perf layers it unlocks
 (ISSUE 8):
@@ -96,32 +90,6 @@ class Unsupported(CheckError):
 TERMINAL_ENGINES = frozenset({"wgl_cpu", "elle-host", "live-host",
                               "lattice-host"})
 
-#: Env knobs that PRUNE engines from a plan (knob value "1" active).
-#: This is the one registry the knobs-only-prune property checks
-#: against: a knob may only remove the engines listed here, never add.
-#: JEPSEN_TPU_DEEP_INTERPRET is deliberately absent — it is a backend
-#: capability (see module docstring), consumed by deep_supported().
-PRUNE_KNOBS: dict = {
-    # the register-delta escape hatches keep their documented meaning
-    # (the candidate-table path), so they prune the deep diversion too
-    "JEPSEN_TPU_NO_REGS": ("wgl_seg_regs", "wgl_seg_batch_regs",
-                           "wgl_seg_pipeline", "wgl_deep"),
-    "JEPSEN_TPU_DYN_ROUNDS": ("wgl_seg_regs", "wgl_seg_batch_regs",
-                              "wgl_seg_pipeline", "wgl_deep"),
-    "JEPSEN_TPU_NO_DEEP": ("wgl_deep", "wgl_deep_split",
-                           "wgl_deep_hc", "wgl_deep_pipeline",
-                           "wgl_deep_mesh"),
-    # the sharded deep variants (word-split sub-plane stacks and the
-    # hypercube mask shard, ISSUE 10) can be pruned without touching
-    # the classic single-plane kernel: routing collapses back to the
-    # R <= DEEP_R_BASE boundary and the serial chain beyond it
-    "JEPSEN_TPU_NO_DEEP_SHARD": ("wgl_deep_split", "wgl_deep_hc"),
-    # opt-in segmented batch engine: the knob prunes the single-lane
-    # engines ABOVE it in the base chain so the segmented tier surfaces
-    "JEPSEN_TPU_SEGMENT": ("wgl_seg_batch_regs", "wgl_seg_batch"),
-}
-
-
 @dataclasses.dataclass(frozen=True)
 class Shape:
     """The routing-relevant shape of one check request — everything the
@@ -154,13 +122,12 @@ class Plan:
     """An inspectable engine plan: the chosen engine, the ordered
     fallbacks below it, why the head was chosen, the compiled-shape
     bucket its executable is cached under, and the full audit trail
-    (engines pruned by env knobs / rejected by shape gates)."""
+    (engines rejected by shape gates)."""
 
     engine: str
     fallbacks: tuple = ()
     why: str = ""
     bucket: tuple = ()
-    pruned: tuple = ()              # ((knob, engine), ...)
     rejected: tuple = ()            # ((engine, reason), ...)
     shape: Optional[Shape] = None
     # Host-ingest routing (ISSUE 9): which pack backend the plan's
@@ -198,8 +165,6 @@ class Plan:
             d["deep_variant"] = self.deep_variant
             d["shards"] = self.shards
             d["exchange_rounds"] = self.exchange_rounds
-        if self.pruned:
-            d["pruned"] = [list(p) for p in self.pruned]
         if self.rejected:
             d["rejected"] = [list(r) for r in self.rejected]
         return d
@@ -236,23 +201,18 @@ def _default_backend(backend: Optional[str]) -> str:
 # ---------------------------------------------------------------------------
 
 def _regs_eligible(R: int, U: int, Sn: int, decomposed: bool,
-                   r_cap: int = 6, sn_cap: int = 32,
-                   env: Optional[dict] = None) -> bool:
+                   r_cap: int = 6, sn_cap: int = 32) -> bool:
     """One gate for the register-delta kernel, shared by check(),
     check_many() and the relaxed tier so they cannot silently diverge:
     fixed rounds stay exact and compile small only for R <= r_cap, the
     uop index must fit int16, and the transition form must fit the
-    decomposed (Sn <= sn_cap) or nibble (Sn <= 8) tables.  The Pallas /
-    dynamic-rounds toggles imply the candidate-table path.  (The
+    decomposed (Sn <= sn_cap) or nibble (Sn <= 8) tables.  (The
     crashed-call path passes r_cap=8: its extra permanent slots are
     worth a bigger compile; the wide-state relaxed tier passes
     sn_cap=64 — its aux masks ride as sn_words=2 uint32 words.)"""
-    env = _snapshot_env(env)
     return (R <= r_cap and U <= 32767
             and ((decomposed and Sn <= sn_cap)
-                 or (not decomposed and Sn <= 8))
-            and env.get("JEPSEN_TPU_NO_REGS") != "1"
-            and env.get("JEPSEN_TPU_DYN_ROUNDS") != "1")
+                 or (not decomposed and Sn <= 8)))
 
 
 #: wgl_deep's scope constants, owned here so the planner and the kernel
@@ -277,8 +237,7 @@ def deep_split_planes(R: int) -> int:
 
 
 def deep_r_max(backend: Optional[str] = None,
-               n_devices: Optional[int] = None,
-               env: Optional[dict] = None) -> int:
+               n_devices: Optional[int] = None) -> int:
     """THE deep-overlap boundary, replacing the hard DEEP_R_MAX = 14:
 
       * one device covers DEEP_R_BASE with a single resident plane and
@@ -291,13 +250,8 @@ def deep_r_max(backend: Optional[str] = None,
     `backend` is part of the signature so per-backend envelopes can
     diverge without another call-site sweep; today the tpu kernel and
     its cpu interpreter share one boundary (whether the backend can run
-    the deep engine AT ALL stays `deep_supported`'s concern).
-    JEPSEN_TPU_NO_DEEP_SHARD=1 collapses both extensions back to the
-    single-plane base — a prune, never an invention (PRUNE_KNOBS)."""
+    the deep engine AT ALL stays `deep_supported`'s concern)."""
     del backend
-    env = _snapshot_env(env)
-    if env.get("JEPSEN_TPU_NO_DEEP_SHARD") == "1":
-        return DEEP_R_BASE
     r = DEEP_R_BASE + (DEEP_SPLIT_MAX.bit_length() - 1)
     if n_devices and int(n_devices) > 1:
         r = max(r, DEEP_R_BASE + (int(n_devices).bit_length() - 1))
@@ -321,53 +275,19 @@ def deep_supported(R: int, Sn: int, U: int, decomposed: bool,
     JEPSEN_TPU_DEEP_INTERPRET=1 (set by the test suite, which runs
     deliberately tiny histories on the virtual CPU mesh); production
     CPU deployments keep the existing compiled fallback chain.  That
-    knob is a backend-capability input, not a prune knob (see module
-    docstring)."""
+    variable is a backend-capability input (see module docstring)."""
     env = _snapshot_env(env)
-    return (decomposed and 0 < R <= deep_r_max(backend, n_devices, env)
+    return (decomposed and 0 < R <= deep_r_max(backend, n_devices)
             and Sn <= DEEP_SN_MAX
             and U <= 32767
             and (backend == "tpu"
                  or (backend == "cpu"
-                     and env.get("JEPSEN_TPU_DEEP_INTERPRET") == "1"))
-            and env.get("JEPSEN_TPU_NO_DEEP") != "1")
+                     and env.get("JEPSEN_TPU_DEEP_INTERPRET") == "1")))
 
 
 # ---------------------------------------------------------------------------
 # plan_engines — the one routing decision
 # ---------------------------------------------------------------------------
-
-def _apply_knobs(candidates: list, env: dict):
-    """Prune PRUNE_KNOBS-listed engines for active ("1") knobs.  The
-    last (terminating) engine is never in any prune list, so the chain
-    cannot empty.  Returns (chain, pruned pairs)."""
-    pruned = []
-    out = list(candidates)
-    for knob, engines in PRUNE_KNOBS.items():
-        if env.get(knob) != "1":
-            continue
-        if (knob == "JEPSEN_TPU_SEGMENT"
-                and "wgl_seg_batch_seg" not in out):
-            # the knob surfaces the segmented tier; where that tier is
-            # not eligible (mesh-sharded batches) it is a no-op rather
-            # than a prune of the only engines that cover the scope
-            continue
-        for e in engines:
-            if e in out:
-                out.remove(e)
-                pruned.append((knob, e))
-    return out, tuple(pruned)
-
-
-def _availability_env(env: dict) -> dict:
-    """The availability subset of the env: capability inputs consumed
-    by the shape gates (DEEP_INTERPRET widens the cpu backend).  The
-    PRUNE_KNOBS are deliberately stripped — base-chain eligibility is
-    a function of the SHAPE, and the knobs act only in _apply_knobs,
-    so `plan.pruned` is the complete account of what they did."""
-    return {k: v for k, v in env.items()
-            if k == "JEPSEN_TPU_DEEP_INTERPRET"}
-
 
 def _linear_candidates(s: Shape, env: dict, backend: str):
     """Eligible engine ladder for ONE linearizability history, in
@@ -375,7 +295,6 @@ def _linear_candidates(s: Shape, env: dict, backend: str):
     cands: list = []
     rejected: list = []
     why: dict = {}
-    env = _availability_env(env)
     nc = int(s.crashes)
     # unknowns are optimistic: the engine itself raises Unsupported
     # and the next tier takes over (the chain makes that safe)
@@ -390,8 +309,7 @@ def _linear_candidates(s: Shape, env: dict, backend: str):
                          f"{nc} crashed calls exceed the fast-scan cap "
                          f"({_MAX_CRASHED}); stripped/serial tiers own "
                          "this regime"))
-    elif not _regs_eligible(R_eff, U, Sn, decomposed, r_cap=r_cap,
-                            env=env):
+    elif not _regs_eligible(R_eff, U, Sn, decomposed, r_cap=r_cap):
         rejected.append((
             "wgl_seg_regs",
             f"R={R_eff} (incl. {nc} crashed) / Sn={Sn} / U={U} outside "
@@ -405,7 +323,7 @@ def _linear_candidates(s: Shape, env: dict, backend: str):
             f"R={R_eff} Sn={Sn}: register-delta segment kernel "
             "(quiescent cuts, device-maintained open set)")
 
-    dmax1 = deep_r_max(backend, 1, env=env)
+    dmax1 = deep_r_max(backend, 1)
     if deep_supported(R_eff, Sn, U, decomposed, backend, env=env):
         dname = "wgl_deep" if R_eff <= DEEP_R_BASE else "wgl_deep_split"
         cands.append(dname)
@@ -433,7 +351,7 @@ def _linear_candidates(s: Shape, env: dict, backend: str):
             n_devices=s.mesh):
         cands.append("wgl_deep_hc")
         why["wgl_deep_hc"] = (
-            f"R={R_eff} <= {deep_r_max(backend, s.mesh, env=env)} on "
+            f"R={R_eff} <= {deep_r_max(backend, s.mesh)} on "
             f"the {s.mesh}-device hypercube shard (top mask bits -> "
             "device index, one ppermute per high slot per event round)")
 
@@ -463,16 +381,15 @@ def _linear_candidates(s: Shape, env: dict, backend: str):
     return cands, rejected, why
 
 
-def _many_candidates(s: Shape, env: dict, backend: str):
+def _many_candidates(s: Shape):
     """Eligible ladder for check_many's independent-key batch."""
     cands: list = []
     rejected: list = []
     why: dict = {}
-    env = _availability_env(env)
     Sn = s.Sn if s.Sn is not None else 1
     U = s.U if s.U is not None else 1
     decomposed = s.decomposed if s.decomposed is not None else True
-    if _regs_eligible(s.R, U, Sn, decomposed, env=env):
+    if _regs_eligible(s.R, U, Sn, decomposed):
         cands.append("wgl_seg_batch_regs")
         why["wgl_seg_batch_regs"] = (
             f"R={s.R} Sn={Sn}: one register-delta lane per key, "
@@ -485,11 +402,6 @@ def _many_candidates(s: Shape, env: dict, backend: str):
     if s.R <= s.max_open_bits and Sn <= s.max_states:
         cands.append("wgl_seg_batch")
         why["wgl_seg_batch"] = "candidate-table batch lanes"
-        if s.mesh is None:
-            cands.append("wgl_seg_batch_seg")
-            why["wgl_seg_batch_seg"] = (
-                "segmented batch engine (returns-per-segment serial "
-                "depth; opt-in tier below the single-lane layouts)")
     else:
         rejected.append(("wgl_seg_batch",
                          f"R={s.R}/Sn={Sn} outside the batch engine's "
@@ -508,10 +420,12 @@ def plan_engines(shape: Shape, env: Optional[dict] = None,
     """THE routing decision: shape -> one terminating engine chain.
 
     Pure in (shape, env, backend): env defaults to a snapshot of the
-    process `JEPSEN_TPU_*` environment, backend to
-    `jax.default_backend()`.  Every entry point follows the returned
-    plan instead of a private `if` ladder; `plan.record()` renders it
-    verbatim into the dispatch record on every verdict."""
+    process `JEPSEN_TPU_*` environment (read for the backend
+    capability `JEPSEN_TPU_DEEP_INTERPRET` and the plan's pack
+    threads), backend to `jax.default_backend()`.  Every entry point
+    follows the returned plan instead of a private `if` ladder;
+    `plan.record()` renders it verbatim into the dispatch record on
+    every verdict."""
     env = _snapshot_env(env)
     backend = _default_backend(backend)
     s = shape
@@ -519,7 +433,7 @@ def plan_engines(shape: Shape, env: Optional[dict] = None,
     if s.kind == "linear":
         cands, rejected, why = _linear_candidates(s, env, backend)
     elif s.kind == "linear-many":
-        cands, rejected, why = _many_candidates(s, env, backend)
+        cands, rejected, why = _many_candidates(s)
     elif s.kind == "linear-pipeline":
         # the pipeline's stragglers go through the SINGLE-history
         # chain (check() per straggler), so that chain is what sits
@@ -535,8 +449,7 @@ def plan_engines(shape: Shape, env: Optional[dict] = None,
         Sn = s.Sn if s.Sn is not None else 1
         U = s.U if s.U is not None else 1
         dec = s.decomposed if s.decomposed is not None else True
-        avail = _availability_env(env)
-        if deep_supported(max(s.R, 1), Sn, U, dec, backend, env=avail):
+        if deep_supported(max(s.R, 1), Sn, U, dec, backend, env=env):
             cands.append("wgl_deep_pipeline")
             why["wgl_deep_pipeline"] = (
                 "pipelined deep megakernel (async dispatch, one fetch)"
@@ -547,7 +460,7 @@ def plan_engines(shape: Shape, env: Optional[dict] = None,
                              f"R={s.R}/Sn={Sn}/backend={backend} "
                              "outside the deep gate"))
         if (s.mesh or 0) > 1 and deep_supported(
-                max(s.R, 1), Sn, U, dec, backend, env=avail,
+                max(s.R, 1), Sn, U, dec, backend, env=env,
                 n_devices=s.mesh):
             # the pipeline's deep stragglers (R past one device's
             # stack) ride the hypercube shard before the serial chain
@@ -560,7 +473,7 @@ def plan_engines(shape: Shape, env: Optional[dict] = None,
         why.setdefault("wgl_cpu", "exact CPU oracle (total)")
     elif s.kind == "deep-mesh":
         rejected = []
-        if s.R > deep_r_max(backend, 1, env=_availability_env(env)):
+        if s.R > deep_r_max(backend, 1):
             cands = ["wgl_deep_hc", "wgl_seg", "wgl", "wgl_cpu"]
             why = {"wgl_deep_hc": (
                 f"R={s.R} beyond the single-device stack: mask-sharded "
@@ -593,13 +506,11 @@ def plan_engines(shape: Shape, env: Optional[dict] = None,
     else:
         raise ValueError(f"unknown plan kind {shape.kind!r}")
 
-    chain, pruned = _apply_knobs(cands, env)
-    assert chain, "engine chain emptied — prune table broke its invariant"
-    head = chain[0]
+    head = cands[0]
     bucket = _bucket_for(head, s)
-    return Plan(engine=head, fallbacks=tuple(chain[1:]),
+    return Plan(engine=head, fallbacks=tuple(cands[1:]),
                 why=why.get(head, "eligible"), bucket=bucket,
-                pruned=pruned, rejected=tuple(rejected), shape=s,
+                rejected=tuple(rejected), shape=s,
                 pack_backend=pack_backend_effective(env),
                 pack_threads=pack_threads_effective(env),
                 **_deep_extras(head, s))
@@ -657,7 +568,7 @@ def plan_elle(n_max: int, batch: int = 1, *, algorithm: str = "auto",
     elle-mesh -> elle-device -> elle-host, head picked by the strict
     `algorithm` or (auto) the txn count vs `mesh_threshold`.  The
     algorithm argument SELECTS within the base chain (caller intent,
-    like a mesh argument) — env knobs still only prune."""
+    like a mesh argument)."""
     env = _snapshot_env(env)
     rejected: list = []
     if algorithm == "host":
@@ -696,8 +607,8 @@ def plan_lattice(n_max: int, batch: int = 1, *,
                  devices: Optional[int] = None) -> Plan:
     """Tier chain for the full-lattice consistency engine (ISSUE 20):
     lattice-mesh -> lattice-device -> lattice-host.  Same selection
-    contract as `plan_elle` — `algorithm` is caller intent, knobs only
-    prune — but the lattice closes seven coupled relations per round
+    contract as `plan_elle` — `algorithm` is caller intent — but the
+    lattice closes seven coupled relations per round
     (Adya pair closure, session pair closure, predicate closure,
     long-fork automaton), so the mesh threshold sits lower: the dense
     8-plane stack outgrows one device sooner than the 5-plane one."""

@@ -6,7 +6,8 @@ delegates to knossos.linear / knossos.wgl at
 
   1. differential testing of the TPU kernel (same history => same verdict);
   2. the fallback path for rich host-side models with no DeviceSpec;
-  3. the "CPU knossos" baseline that bench.py measures speedups against.
+  3. the "CPU knossos" baseline device speedups are stated against
+     (BASELINE.md).
 
 Algorithm (Lowe-style JIT linearization, equivalent to knossos :linear):
 walk history events in order keeping a set of *configurations*

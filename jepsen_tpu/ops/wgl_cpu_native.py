@@ -2,8 +2,8 @@
 
 Same Lowe-style just-in-time linearization as ops.wgl_cpu (the
 knossos-equivalent reference implementation), with the hot loop in C
-over the integer uop tables the device kernels use.  bench.py reports
-device speedups against BOTH oracles so the ratios carry no hidden
+over the integer uop tables the device kernels use.  Device speedups
+are stated against BOTH oracles (BASELINE.md) so the ratios carry no hidden
 interpreter constant (the reference runs knossos on a 32 GB JVM,
 jepsen/project.clj:30; this native oracle bounds any
 "Python-was-just-slow" objection from below).

@@ -67,7 +67,6 @@ the prefix through the first dead segment.
 from __future__ import annotations
 
 import functools
-import os
 import threading
 from typing import Any, Optional
 
@@ -95,6 +94,17 @@ from jepsen_tpu.ops.planner import (  # noqa: F401 - re-exports
     _pack_uop_tables, _pad_len, _regs_eligible, _regs_fill,
     _regs_fill_compact, _scan_history, _segment_ends,
     _segments_from_fk, _split_crashed, plan)
+
+#: Events per scan-loop iteration in the fixed-round kernels (the
+#: scan's pipelining depth).
+SCAN_UNROLL = 4
+#: Histories per check_pipeline dispatch group.
+PIPE_GROUP = 4
+#: Closure rounds check_pipeline's speculative pass runs (clamped to
+#: the group's R); a death under fewer than R rounds re-runs exactly.
+SPEC_ROUNDS = 2
+#: Keys per double-buffered check_many dispatch chunk.
+OVERLAP_CHUNK = 1024
 
 
 # ---------------------------------------------------------------------------
@@ -949,23 +959,14 @@ def _dispatch_kernel(K, L, C, M, Sn, R, J, ret_t, cslot_t, cuop_t,
     decomposed = diag_w is not None
     use_bits = (decomposed and Sn <= 32) or (not decomposed and Sn <= 8)
     if use_bits:
-        # Fixed-round unrolled closure + scan pipelining by default (see
-        # _build_kernel_bits: rounds=R is exact); JEPSEN_TPU_DYN_ROUNDS=1
-        # restores the dynamic while_loop, JEPSEN_TPU_SCAN_UNROLL tunes
-        # the events-per-loop-iteration pipelining.  Deep-concurrency
+        # Fixed-round unrolled closure + scan pipelining (see
+        # _build_kernel_bits: rounds=R is exact).  Deep-concurrency
         # batches (R beyond typical workload concurrency) keep the
-        # dynamic loop: the static body is O(R * C * R) full-tensor ops
-        # per round x R rounds x unroll, which at R near max_open_bits
-        # compiles huge HLO and wastes rounds the early exit would skip.
-        # (JEPSEN_TPU_FORCE_STATIC=1 overrides the R guard explicitly;
-        # the unroll knob never does.)
-        if (os.environ.get("JEPSEN_TPU_DYN_ROUNDS") == "1"
-                or (R > 6
-                    and os.environ.get("JEPSEN_TPU_FORCE_STATIC") != "1")):
-            rounds, unroll = 0, 1
-        else:
-            rounds = int(R)
-            unroll = int(os.environ.get("JEPSEN_TPU_SCAN_UNROLL", "4"))
+        # dynamic while_loop: the static body is O(R * C * R)
+        # full-tensor ops per round x R rounds x unroll, which at R near
+        # max_open_bits compiles huge HLO and wastes rounds the early
+        # exit would skip.
+        rounds, unroll = (0, 1) if R > 6 else (int(R), SCAN_UNROLL)
         kern = _build_kernel_bits(K, int(L), int(C), max(1, M // 32),
                                   int(Sn), int(R), decomposed, J=J,
                                   rounds=rounds, unroll=unroll)
@@ -1063,7 +1064,6 @@ def _run_seg_regs(seg_fk: list, K: int, R: int, U: int, Sn: int, M: int,
         sharded = True
     I = min(2, R) if R else 1
     decomposed = diag_w is not None
-    unroll = int(os.environ.get("JEPSEN_TPU_SCAN_UNROLL", "4"))
     with st.span("tables"):
         a1t, a2t, t0t = _pack_uop_tables(
             legal, next_state, diag_w, const_w, const_t0)
@@ -1087,7 +1087,7 @@ def _run_seg_regs(seg_fk: list, K: int, R: int, U: int, Sn: int, M: int,
         if not sharded and compose:
             fn = _build_kernel_regs_packed(
                 int(K_run), int(Lp), I, max(1, M // 32), int(Sn), R,
-                decomposed, R, unroll, int(Sn) << nc, nc, rn,
+                decomposed, R, SCAN_UNROLL, int(Sn) << nc, nc, rn,
                 int(a1t.shape[0]), iuop_t.dtype == np.int16)
             # [6]: valid, dead, entry mask x4
             vd = _device_call(st, "wgl_seg", fn, args, lanes=K,
@@ -1095,7 +1095,7 @@ def _run_seg_regs(seg_fk: list, K: int, R: int, U: int, Sn: int, M: int,
             return None, K_run, False, vd
         kern = _build_kernel_regs(K_run, int(Lp), I, max(1, M // 32),
                                   int(Sn), R, decomposed,
-                                  rounds=R, unroll=unroll,
+                                  rounds=R, unroll=SCAN_UNROLL,
                                   J=int(Sn) << nc, nc=nc, rn=rn)
         put = None
         if sharded:
@@ -1669,9 +1669,7 @@ def _check_fast(model, spec, history, *, max_states, max_open_bits,
             # megakernel (crashed calls are permanent slots there; a
             # crash set too wide for the J = Sn * 2^nc entry axis
             # diverts the same way) vs the candidate-table plan() route
-            # (None).  The JEPSEN_TPU_NO_REGS / JEPSEN_TPU_DYN_ROUNDS
-            # escape hatches keep their documented meaning (the
-            # candidate-table path) via the planner's prune table.
+            # (None).
             route = planner.plan_engines(
                 planner.Shape(kind="linear",
                               R=rn if nc else int(fk.max_open),
@@ -2030,11 +2028,7 @@ def check_pipeline(model, histories, *, max_states: int = 64,
     seen: dict = {}
     rows: list = []
     strag: list = []
-    G = max(1, min(int(os.environ.get("JEPSEN_TPU_PIPE_GROUP", "4")),
-                   len(histories) or 1))
-    spec_rounds_env = max(1, int(os.environ.get(
-        "JEPSEN_TPU_SPEC_ROUNDS", "2")))
-    unroll = int(os.environ.get("JEPSEN_TPU_SCAN_UNROLL", "4"))
+    G = max(1, min(PIPE_GROUP, len(histories) or 1))
     init = np.asarray(spec.encode(model), np.int32)
 
     # streaming state: rebuilt only when the alphabet/shape grows
@@ -2168,15 +2162,15 @@ def check_pipeline(model, histories, *, max_states: int = 64,
                     Rp_c = ((Rp_c + 8191) // 8192) * 8192
                     fn = None
                 if fn is None:
-                    spec_rounds = min(R_cur, spec_rounds_env)
+                    spec_rounds = min(R_cur, SPEC_ROUNDS)
                     fn = planner.compiled(
                         "wgl_seg_pipeline",
                         (G, K_c, Lp_c, R_cur, int(Sn), U, Rp_c,
-                         spec_rounds, unroll, diag_w is not None),
+                         spec_rounds, SCAN_UNROLL, diag_w is not None),
                         _build_kernel_regs_group_c,
                         G, K_c, Lp_c, max(1, (1 << R_cur) // 32),
                         int(Sn), R_cur, diag_w is not None, spec_rounds,
-                        unroll, U, Rp_c)
+                        SCAN_UNROLL, U, Rp_c)
         if off:
             strag.extend(i for i, *_ in grp)
             continue
@@ -2208,8 +2202,7 @@ def check_pipeline(model, histories, *, max_states: int = 64,
         with st.span("dispatch"):
             payload = np.concatenate(blocks)
             # measured wire traffic: the compact event blocks + the uop
-            # tables shipped with every group (bench.py reports MB/s
-            # over the dispatch+fetch window from this)
+            # tables shipped with every group
             stats["wire_bytes"] = (stats.get("wire_bytes", 0)
                                    + payload.nbytes + buf32.nbytes)
             dispatched.append(
@@ -2291,134 +2284,10 @@ def check_pipeline(model, histories, *, max_states: int = 64,
     return results
 
 
-def _run_segmented(batch, legal, next_state, diag_w, const_w, const_t0,
-                   Sn: int, R: int, M: int, C: int, st: telemetry.Stages):
-    """The segmented batch engine: each key's event stream is cut at
-    its quiescent points (the single-history engine's trick, applied
-    across the whole batch), segments become kernel lanes bucketed by
-    length, each lane yields a [Sn, Sn] transfer matrix (J=Sn), and
-    per-key verdicts come from composing each key's chain on host.
-
-    Serial depth per kernel drops from max-returns-per-KEY (~hundreds)
-    to the bucket's returns-per-SEGMENT (~8-32), with lanes multiplying
-    accordingly — the same wall-clock trade the module docstring
-    describes for one history, at batch scale.
-
-    Returns ok_by_batch_index bool[Kk]; stage seconds go to `st`
-    (`kernel` for each bucket's device call)."""
-    Kk = len(batch)
-
-    # --- flatten all keys' returns with global segment ids ------------
-    rs_parts, cnt_parts, cs_parts, cu_parts = [], [], [], []
-    seg_of_ret_parts, rank_parts = [], []
-    seg_sizes_parts = []
-    seg_base = 0
-    key_nseg = np.zeros(Kk, np.int64)
-    for bi, (_, fk) in enumerate(batch):
-        rs, counts, cs, cu = _fk_arrays(fk)
-        nr = len(rs)
-        cuts = np.asarray(fk.cuts, np.int32)
-        # a crash-free complete history always ends quiescent, but be
-        # safe: treat a non-quiescent tail as a final segment
-        if nr and (len(cuts) != nr or cuts[-1] != 1):
-            cuts = np.copy(cuts) if len(cuts) == nr else \
-                np.zeros(nr, np.int32)
-            cuts[-1] = 1
-        seg_end = np.nonzero(cuts)[0]                    # inclusive
-        sizes = np.diff(np.concatenate([[-1], seg_end]))
-        nseg = len(seg_end)
-        starts = np.concatenate([[0], seg_end[:-1] + 1])
-        seg_of_ret = np.repeat(np.arange(nseg), sizes) + seg_base
-        rank = np.arange(nr) - np.repeat(starts, sizes)
-        rs_parts.append(rs)
-        cnt_parts.append(counts)
-        cs_parts.append(cs)
-        cu_parts.append(cu)
-        seg_of_ret_parts.append(seg_of_ret)
-        rank_parts.append(rank)
-        seg_sizes_parts.append(sizes)
-        key_nseg[bi] = nseg
-        seg_base += nseg
-
-    rs_all = np.concatenate(rs_parts)
-    cnt_all = np.concatenate(cnt_parts)
-    cs_all = np.concatenate(cs_parts)
-    cu_all = np.concatenate(cu_parts)
-    seg_of_ret = np.concatenate(seg_of_ret_parts)
-    rank_all = np.concatenate(rank_parts)
-    seg_sizes = np.concatenate(seg_sizes_parts)
-    n_seg = seg_base
-
-    # candidate rows -> their return's segment/rank
-    ends = np.cumsum(cnt_all)
-    ret_of_cand = np.repeat(np.arange(len(rs_all)), cnt_all)
-    j_of_cand = np.arange(ends[-1] if len(ends) else 0) - \
-        np.repeat(ends - cnt_all, cnt_all)
-
-    # --- bucket segments by size (pow2 floors at 8) --------------------
-    Lb_of_seg = np.maximum(
-        8, 1 << np.ceil(np.log2(np.maximum(seg_sizes, 1))).astype(int))
-    S_max = int(key_nseg.max()) if Kk else 0
-    # Ragged storage: one [Sn, Sn] matrix per segment — memory bounded
-    # by TOTAL segments, not Kk x the single deepest key.  Segments
-    # were appended key-by-key in order, so key bi's s-th segment lives
-    # at key_off[bi] + s.
-    T_all = np.empty((n_seg, Sn, Sn), bool)
-    key_off = np.concatenate([[0], np.cumsum(key_nseg)[:-1]])
-
-    for Lb in sorted(set(Lb_of_seg.tolist())):
-        in_b = Lb_of_seg == Lb
-        seg_ids = np.nonzero(in_b)[0]
-        lanes = len(seg_ids)
-        lane_of_seg = np.full(n_seg, -1, np.int64)
-        lane_of_seg[seg_ids] = np.arange(lanes)
-        # round lanes up through power-of-two tiers to bound the set of
-        # compiled kernel shapes
-        Kp = max(128, _next_pow2(lanes))
-
-        ret_in = in_b[seg_of_ret]
-        ret_slot = np.full((Kp, Lb), -1, np.int32)
-        ret_slot[lane_of_seg[seg_of_ret[ret_in]],
-                 rank_all[ret_in]] = rs_all[ret_in]
-        cand_slot = np.zeros((Kp, Lb, C), np.int32)
-        cand_uop = np.full((Kp, Lb, C), -1, np.int32)
-        if len(cu_all):
-            cand_in = ret_in[ret_of_cand]
-            seg_c = seg_of_ret[ret_of_cand[cand_in]]
-            cand_slot[lane_of_seg[seg_c],
-                      rank_all[ret_of_cand[cand_in]],
-                      j_of_cand[cand_in]] = cs_all[cand_in]
-            cand_uop[lane_of_seg[seg_c],
-                     rank_all[ret_of_cand[cand_in]],
-                     j_of_cand[cand_in]] = cu_all[cand_in]
-
-        ret_t = np.ascontiguousarray(ret_slot.T)
-        cslot_t = np.ascontiguousarray(cand_slot.transpose(1, 0, 2))
-        cuop_t = np.ascontiguousarray(cand_uop.transpose(1, 0, 2))
-        kern, args, _ = _dispatch_kernel(
-            Kp, int(Lb), int(C), int(M), int(Sn), int(R), int(Sn),
-            ret_t, cslot_t, cuop_t, legal, next_state,
-            diag_w, const_w, const_t0)
-        with st.span("kernel"):
-            T = _device_call(st, "wgl_seg_batch", kern, args,
-                             lanes=lanes, padded=Kp - lanes) > 0.5
-        T_all[seg_ids] = T[:lanes]                     # [Kp, Sn, Sn]
-
-    # --- compose each key's chain (entry state = enumeration index 0) -
-    v = np.zeros((Kk, Sn), bool)
-    v[:, 0] = True
-    for s in range(S_max):
-        act = np.nonzero(key_nseg > s)[0]
-        Ts = T_all[key_off[act] + s]                   # [A, Sn, Sn]
-        v[act] = (v[act][:, :, None] & Ts).any(axis=1)
-    return v.any(axis=1)
-
-
 def _emit_batch_result(results, i, fk, ok: bool, backend_name: str,
                        engine: str, witness: list, localize: bool) -> None:
-    """Per-key result dict — shared by the segmented and single-lane
-    batch paths.  An invalid key to localize joins `witness`, for
-    _localize_keys."""
+    """Per-key result dict — shared by the batch paths.  An invalid key
+    to localize joins `witness`, for _localize_keys."""
     results[i] = {
         "valid?": ok,
         "op_count": fk.n_calls,
@@ -2485,12 +2354,6 @@ def _localize_keys(results, keys: list, model, histories) -> None:
 # Multi-key batch mode (jepsen.independent on device)
 # ---------------------------------------------------------------------------
 
-def _overlap_chunk() -> int:
-    """Keys per double-buffered dispatch chunk (0 disables chunking:
-    one monolithic pack + dispatch, the pre-overlap behavior)."""
-    return int(os.environ.get("JEPSEN_TPU_OVERLAP_CHUNK", "1024"))
-
-
 def _run_many_overlapped(batch, R: int, U: int, Sn: int, M: int,
                          decomposed: bool, unroll: int,
                          buf32: np.ndarray, st: telemetry.Stages,
@@ -2519,16 +2382,10 @@ def _run_many_overlapped(batch, R: int, U: int, Sn: int, M: int,
     from jepsen_tpu.ops import runner as runner_mod
 
     stats = st.seconds
-    chunk = _overlap_chunk()
-    if chunk <= 0 or len(batch) <= chunk:
-        chunks = [batch]
-    else:
-        chunks = [batch[k:k + chunk]
-                  for k in range(0, len(batch), chunk)]
-    Kp = max(128, ((min(len(batch), chunk or len(batch)) + 127)
-                   // 128) * 128)
-    donate = backend_name not in ("cpu", "unknown") \
-        and os.environ.get("JEPSEN_TPU_NO_DONATE") != "1"
+    chunk = OVERLAP_CHUNK
+    chunks = [batch[k:k + chunk] for k in range(0, len(batch), chunk)]
+    Kp = max(128, ((min(len(batch), chunk) + 127) // 128) * 128)
+    donate = backend_name not in ("cpu", "unknown")
     n_native = [0]
     h2d = [buf32.nbytes * len(chunks)]
 
@@ -2722,15 +2579,7 @@ def check_many(model, histories, *, max_states: int = 64,
                 Sn = states.shape[0]
                 R = max(fk.max_open for _, fk in batch)
                 # THE routing decision (ops.planner), over the tables:
-                # register-delta compact lanes vs candidate-table lanes
-                # vs the opt-in segmented engine (JEPSEN_TPU_SEGMENT=1
-                # prunes the single-lane layouts so the segmented tier
-                # surfaces — measured on a v5e-1 it LOSES to them at
-                # both bench shapes, 2.0s vs 0.83s kernel at 300-op
-                # keys, because the J=Sn entry-state axis multiplies
-                # total work ~Sn x; kept verdict-identical as the
-                # scaling path for workloads whose per-key depth
-                # actually binds).
+                # register-delta compact lanes vs candidate-table lanes.
                 route = planner.plan_engines(
                     planner.Shape(kind="linear-many", R=int(R),
                                   Sn=int(Sn), U=len(rows),
@@ -2754,18 +2603,6 @@ def check_many(model, histories, *, max_states: int = 64,
         L = _pad_len(max(fk.n_rets for _, fk in batch))
         C = int(R)
 
-        if route.engine == "wgl_seg_batch_seg":
-            ok_b = _run_segmented(
-                batch, legal, next_state, diag_w, const_w, const_t0,
-                int(Sn), int(R), int(M), int(C), st)
-            for bi, (i, fk) in enumerate(batch):
-                _emit_batch_result(results, i, fk, bool(ok_b[bi]),
-                                   backend_name, "wgl_seg_batch",
-                                   witness,
-                                   localize and i not in stripped_note)
-            batch = []
-
-    if batch:
         # Pad the key axis for lane alignment (and even mesh sharding).
         Kk = len(batch)
         mult = 128
@@ -2782,7 +2619,6 @@ def check_many(model, histories, *, max_states: int = 64,
         # (planner._linear_candidates routes on exactly that gate).
         if route.engine == "wgl_seg_batch_regs":
             engine_name = "wgl_seg_batch_regs"
-            unroll = int(os.environ.get("JEPSEN_TPU_SCAN_UNROLL", "4"))
             with st.span("tables"):
                 a1t, a2t, t0t = _pack_uop_tables(
                     legal, next_state, diag_w, const_w, const_t0)
@@ -2797,7 +2633,7 @@ def check_many(model, histories, *, max_states: int = 64,
                     [a1t, a2t, t0t.view(np.uint32)])
                 ok_k = _run_many_overlapped(
                     batch, int(R), int(U), int(Sn), int(M),
-                    decomposed, unroll, buf32, st, backend_name)
+                    decomposed, SCAN_UNROLL, buf32, st, backend_name)
             else:
                 I = min(2, int(R))
                 with st.span("pack"):
@@ -2808,11 +2644,11 @@ def check_many(model, histories, *, max_states: int = 64,
                     kern = planner.compiled(
                         "wgl_seg_batch_regs",
                         (Kp, int(Lp), I, int(R), int(Sn), int(U),
-                         unroll, decomposed, "mesh"),
+                         SCAN_UNROLL, decomposed, "mesh"),
                         _build_kernel_regs,
                         Kp, int(Lp), I, max(1, M // 32),
                         int(Sn), int(R), decomposed,
-                        rounds=int(R), unroll=unroll)
+                        rounds=int(R), unroll=SCAN_UNROLL)
                     T = _device_call(
                         st, engine_name, kern, args, lanes=Kk,
                         padded=Kp - Kk,

@@ -1010,9 +1010,6 @@ def summarize(events: list[dict]) -> str:
                         + (f" ex{pl['exchange_rounds']}"
                            if pl.get("exchange_rounds") else "") + "]")
             lines.append(f"  {chain}: {why or '?'}{pack}{deep}")
-            if pl.get("pruned"):
-                lines.append("    pruned by env: " + ", ".join(
-                    f"{knob} -{e2}" for knob, e2 in pl["pruned"]))
         if len(shown) > 12:
             lines.append(f"  ... {len(shown) - 12} more plans")
 

@@ -882,9 +882,9 @@ def telemetry_run_html(name: str, ts: str) -> bytes:
 def _dispatch_plans_html(events) -> str:
     """The dispatch-plans panel (ISSUE 8): one row per distinct
     planner-emitted plan — engine, WHY it was chosen, the fallback
-    chain below it, the compiled-shape bucket, and any env-knob
-    prunes — rendered from the `plan` field attach_dispatch records on
-    every verdict."""
+    chain below it, the compiled-shape bucket, the pack backend and
+    the deep shard — rendered from the `plan` field attach_dispatch
+    records on every verdict."""
     seen: dict = {}
     for e in events:
         if e.get("type") != "dispatch":
@@ -903,8 +903,6 @@ def _dispatch_plans_html(events) -> str:
     for (eng, why, fb), info in seen.items():
         rec = info["rec"]
         pl = rec.get("plan") or {}
-        pruned = ", ".join(f"{k} &minus;{html.escape(str(e2))}"
-                           for k, e2 in (pl.get("pruned") or []))
         # record-level pack fields are what actually ran; the plan's
         # are the intent (they differ when a native error degraded)
         pb = rec.get("pack_backend") or pl.get("pack_backend")
@@ -924,13 +922,11 @@ def _dispatch_plans_html(events) -> str:
             f"<td>{html.escape(str(pl.get('bucket') or ''))}</td>"
             f"<td>{html.escape(pack)}</td>"
             f"<td>{html.escape(deep)}</td>"
-            f"<td>{pruned}</td>"
             f"<td>{info['verdicts']}</td></tr>")
     return ("<h2>Dispatch plans</h2>"
             "<table><tr><th>Engine</th><th>Why</th>"
             "<th>Fallback chain</th><th>Bucket</th><th>Pack</th>"
-            "<th>Deep shard</th>"
-            "<th>Pruned by env</th><th>Verdicts</th></tr>"
+            "<th>Deep shard</th><th>Verdicts</th></tr>"
             + "".join(rows) + "</table>")
 
 

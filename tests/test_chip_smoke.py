@@ -62,3 +62,29 @@ def test_no_accelerator_no_ok_line():
                             "PATH": "/usr/bin:/bin"})
     assert p.returncode != 0
     assert '"ok"' not in p.stdout
+
+
+def _ops(h):
+    return [(o.process, o.type, o.f, o.value) for o in h.ops]
+
+
+@pytest.mark.parametrize("kw", [{}, {"crash_rate": 0.01, "max_open": 6}])
+def test_make_history_is_deterministic_per_seed(kw):
+    a = chip_smoke.make_history(400, 5, seed=3, **kw)
+    b = chip_smoke.make_history(400, 5, seed=3, **kw)
+    c = chip_smoke.make_history(400, 5, seed=4, **kw)
+    assert _ops(a) == _ops(b)
+    assert _ops(a) != _ops(c)
+    assert a.packed_columns() is not None
+
+
+def test_planted_stale_read_is_refuted_at_its_read():
+    from jepsen_tpu import models
+    from jepsen_tpu.ops import wgl_cpu
+    h = chip_smoke.make_history(600, 5, seed=11, vmax=9)
+    assert wgl_cpu.check(models.CASRegister(), h)["valid?"] is True
+    pos, w = chip_smoke.plant_stale_read(h, 0.9, 9)
+    assert h.ops[pos].f == "read" and h.ops[pos].value == w
+    r = wgl_cpu.check(models.CASRegister(), h)
+    assert r["valid?"] is False
+    assert r["op_index"] == chip_smoke.Smoke.invoke_of(h, pos)

@@ -147,7 +147,7 @@ class TestFollowCursor:
 # ---------------------------------------------------------------------------
 
 def gen_history(n_ops, conc, seed, vmax=4, crash_rate=0.0):
-    """bench.make_history's shape, locally: an etcd-style r/w/cas mix
+    """chip_smoke.make_history's shape, locally: an etcd-style r/w/cas mix
     against a sequential register simulator (always linearizable
     unless planted otherwise)."""
     rng = random.Random(seed)

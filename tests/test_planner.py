@@ -1,10 +1,11 @@
 """The one engine planner (ops/planner.py, ISSUE 8): routing
-properties (every shape -> exactly one terminating chain; env knobs
-only prune), plan rendering into dispatch records, the compiled-plan
-cache, and the async double-buffered executor's correctness
+properties (every shape -> exactly one terminating chain, decided by
+the shape and the backend alone), plan rendering into dispatch records,
+the compiled-plan cache, and the async double-buffered executor's correctness
 (verdict-identical to serial dispatch; ResilientRunner bisection still
 fires mid-pipeline with donation enabled)."""
 
+import hashlib
 import os
 import random
 
@@ -34,21 +35,6 @@ def rand_shape(rng) -> planner.Shape:
         max_open_bits=rng.choice([10, 14]))
 
 
-def rand_env(rng) -> dict:
-    env = {}
-    for knob in ("JEPSEN_TPU_NO_REGS", "JEPSEN_TPU_DYN_ROUNDS",
-                 "JEPSEN_TPU_NO_DEEP", "JEPSEN_TPU_SEGMENT",
-                 "JEPSEN_TPU_NO_DEEP_SHARD"):
-        if rng.random() < 0.3:
-            env[knob] = "1"
-    return env
-
-
-def is_subsequence(sub, full) -> bool:
-    it = iter(full)
-    return all(x in it for x in sub)
-
-
 # ---------------------------------------------------------------------------
 # Routing properties — the ROADMAP #1 acceptance pins
 # ---------------------------------------------------------------------------
@@ -56,12 +42,12 @@ def is_subsequence(sub, full) -> bool:
 class TestPlanProperties:
     def test_every_shape_routes_to_one_terminating_chain(self):
         """Seeded-random sweep: every generated (R, crashes, Sn, batch,
-        mesh, env) shape yields exactly one chain, duplicate-free,
+        mesh, backend) shape yields exactly one chain, duplicate-free,
         ending in a total engine — nothing can fall off the ladder."""
         rng = random.Random(11)
         for _ in range(400):
             shape = rand_shape(rng)
-            env = rand_env(rng)
+            env = {}
             backend = rng.choice(["cpu", "tpu"])
             if backend == "cpu" and rng.random() < 0.5:
                 env["JEPSEN_TPU_DEEP_INTERPRET"] = "1"
@@ -73,38 +59,29 @@ class TestPlanProperties:
                 (shape, env, pl.chain)
             assert pl.why, (shape, env)
 
-    def test_env_knobs_only_prune_never_invent(self):
-        """For every shape, the knobbed chain is a subsequence of the
-        knob-free chain computed with the SAME availability inputs
-        (backend + DEEP_INTERPRET) — knobs remove engines, they never
-        insert ones the shape wasn't already eligible for, and they
-        never reorder the survivors."""
-        rng = random.Random(23)
+    def test_sweep_chains_pinned(self):
+        """The chain every swept shape gets on each backend, with no
+        environment and with the cpu interpreter available, pinned by
+        digest: routing is a function of the shape and the backend
+        alone, so a change to any shape's chain is a routing change and
+        must update the digest on purpose."""
+        rng = random.Random(11)
+        rows = []
         for _ in range(400):
             shape = rand_shape(rng)
-            backend = rng.choice(["cpu", "tpu"])
-            avail = {}
-            if backend == "cpu" and rng.random() < 0.5:
-                avail["JEPSEN_TPU_DEEP_INTERPRET"] = "1"
-            base = planner.plan_engines(shape, env=avail,
-                                        backend=backend)
-            env = {**avail, **rand_env(rng)}
-            knobbed = planner.plan_engines(shape, env=env,
-                                           backend=backend)
-            assert set(knobbed.chain) <= set(base.chain), \
-                (shape, env, base.chain, knobbed.chain)
-            assert is_subsequence(knobbed.chain, base.chain), \
-                (shape, env, base.chain, knobbed.chain)
-            # everything pruned is attributed to a registered knob,
-            # and only to engines that knob is allowed to remove
-            for knob, engine in knobbed.pruned:
-                assert env.get(knob) == "1"
-                assert engine in planner.PRUNE_KNOBS[knob]
+            for backend, env in (
+                    ("cpu", {}),
+                    ("cpu", {"JEPSEN_TPU_DEEP_INTERPRET": "1"}),
+                    ("tpu", {})):
+                rows.append(" ".join(planner.plan_engines(
+                    shape, env=env, backend=backend).chain))
+        assert len(set(rows)) == 23
+        assert hashlib.sha256("\n".join(rows).encode()).hexdigest() == (
+            "a414086730d668da2aaf7cc33a45ac4de186255bf6940fd9e7edaf6a798bff26")
 
     def test_deep_interpret_is_availability_not_a_prune_knob(self):
-        # the one knob that can ADD an engine is classified as a
-        # backend capability (like running on a TPU), not routing
-        assert "JEPSEN_TPU_DEEP_INTERPRET" not in planner.PRUNE_KNOBS
+        # the one variable that can ADD an engine is a backend
+        # capability (like running on a TPU), not routing
         shape = planner.Shape(kind="linear", R=9, Sn=4, U=6,
                               decomposed=True)
         off = planner.plan_engines(shape, env={}, backend="cpu")
@@ -126,12 +103,6 @@ class TestPlanProperties:
         assert planner.plan_engines(
             S(kind="linear", R=3, Sn=4, U=6, decomposed=True),
             env={}, backend="cpu").engine == "wgl_seg_regs"
-        # NO_REGS prunes regs AND the deep diversion: candidate-table
-        pl = planner.plan_engines(
-            S(kind="linear", R=3, Sn=4, U=6, decomposed=True),
-            env={"JEPSEN_TPU_NO_REGS": "1"}, backend="tpu")
-        assert pl.engine == "wgl_seg"
-        assert ("JEPSEN_TPU_NO_REGS", "wgl_seg_regs") in pl.pruned
         # deep regime on TPU
         assert planner.plan_engines(
             S(kind="linear", R=12, Sn=4, U=6, decomposed=True),
@@ -142,21 +113,12 @@ class TestPlanProperties:
             env={}, backend="tpu")
         assert pl.engine == "wgl"
         assert pl.chain[-1] == "wgl_cpu"
-        # batch: SEGMENT surfaces the segmented tier...
-        assert planner.plan_engines(
-            S(kind="linear-many", R=4, Sn=4, U=9, decomposed=True,
-              batch=100),
-            env={"JEPSEN_TPU_SEGMENT": "1"},
-            backend="cpu").engine == "wgl_seg_batch_seg"
-        # ...but is a no-op for mesh-sharded batches, where the
-        # segmented tier does not exist (pruning the only covering
-        # engines would break the scope)
-        pl = planner.plan_engines(
-            S(kind="linear-many", R=4, Sn=4, U=9, decomposed=True,
-              batch=100, mesh=8),
-            env={"JEPSEN_TPU_SEGMENT": "1"}, backend="cpu")
-        assert pl.engine == "wgl_seg_batch_regs"
-        assert not pl.pruned
+        # batch, mesh-sharded or not: register-delta lanes
+        for mesh in (None, 8):
+            pl = planner.plan_engines(
+                S(kind="linear-many", R=4, Sn=4, U=9, decomposed=True,
+                  batch=100, mesh=mesh), env={}, backend="cpu")
+            assert pl.engine == "wgl_seg_batch_regs"
 
     def test_elle_tiers(self):
         pl = planner.plan_elle(100_000)
@@ -194,13 +156,11 @@ class TestPlanProperties:
 
     def test_deep_r_max_envelope(self):
         # ISSUE 10: the hard DEEP_R_MAX constant is gone; the boundary
-        # is backend/mesh-aware and the shard knob only shrinks it
+        # is backend/mesh-aware
         assert not hasattr(planner, "DEEP_R_MAX")
         assert planner.deep_r_max("tpu", 1) == 16       # word-split
         assert planner.deep_r_max("tpu", 2) == 16
         assert planner.deep_r_max("tpu", 8) == 17       # hypercube
-        assert planner.deep_r_max(
-            "tpu", 8, env={"JEPSEN_TPU_NO_DEEP_SHARD": "1"}) == 14
         assert planner.deep_split_planes(14) == 1
         assert planner.deep_split_planes(15) == 2
         assert planner.deep_split_planes(16) == 4
@@ -225,14 +185,24 @@ class TestPlanProperties:
         assert pl.engine == "wgl_deep_hc"
         assert pl.deep_variant == "hypercube"
         assert pl.shards == 8 and pl.exchange_rounds == 3
-        # the new knob PRUNES the sharded variants (attributed), never
-        # invents — the chain falls back to the serial engines
-        pl = planner.plan_engines(
-            S(kind="linear", R=15, Sn=4, U=6, decomposed=True),
-            env={"JEPSEN_TPU_NO_DEEP_SHARD": "1"}, backend="tpu")
-        assert pl.engine == "wgl"
-        assert ("JEPSEN_TPU_NO_DEEP_SHARD", "wgl_deep_split") \
-            in pl.pruned
+
+    @pytest.mark.parametrize("mesh", [None, 2, 8])
+    def test_deep_boundary_by_shape(self, mesh):
+        """The deep envelope ends where the shape says: at
+        R == deep_r_max a deep variant heads the chain, one past it the
+        serial kernel does."""
+        S = planner.Shape
+        r_max = planner.deep_r_max("tpu", mesh)
+        at = planner.plan_engines(
+            S(kind="linear", R=r_max, Sn=4, U=6, decomposed=True,
+              mesh=mesh), env={}, backend="tpu")
+        assert at.engine.startswith("wgl_deep"), at.chain
+        assert at.deep_variant
+        past = planner.plan_engines(
+            S(kind="linear", R=r_max + 1, Sn=4, U=6, decomposed=True,
+              mesh=mesh), env={}, backend="tpu")
+        assert past.engine == "wgl", past.chain
+        assert not any(e.startswith("wgl_deep") for e in past.chain)
 
 
 # ---------------------------------------------------------------------------
@@ -260,39 +230,28 @@ class TestPlanRendering:
             assert pl["engine"] == "wgl_seg_batch_regs"
             assert pl["why"]
 
-    def test_pruned_knob_rendered(self, monkeypatch):
-        monkeypatch.setenv("JEPSEN_TPU_NO_REGS", "1")
-        r = wgl_seg.check(models.CASRegister(), rand_history(6))
-        pl = r["dispatch"]["plan"]
-        assert ["JEPSEN_TPU_NO_REGS", "wgl_seg_regs"] in pl["pruned"]
-
     def test_summarize_renders_plans(self):
         events = [{"type": "dispatch", "verdicts": 2, "record": {
             "engine": "wgl_seg",
             "why": "R=3 Sn=4: register-delta segment kernel",
             "fallback_chain": ["wgl_seg", "wgl", "wgl_cpu"],
-            "plan": {"engine": "wgl_seg_regs",
-                     "pruned": [["JEPSEN_TPU_NO_DEEP", "wgl_deep"]]},
+            "plan": {"engine": "wgl_seg_regs"},
         }}]
         out = telemetry.summarize(events)
         assert "dispatch plans:" in out
         assert "wgl_seg -> wgl_seg -> wgl -> wgl_cpu" in out
         assert "register-delta segment kernel" in out
-        assert "JEPSEN_TPU_NO_DEEP -wgl_deep" in out
 
     def test_web_dispatch_panel(self):
         from jepsen_tpu import web
         events = [{"type": "dispatch", "verdicts": 3, "record": {
             "engine": "wgl_seg", "why": "pipelined",
             "fallback_chain": ["wgl", "wgl_cpu"],
-            "plan": {"bucket": ["wgl_seg_pipeline", 4],
-                     "pruned": [["JEPSEN_TPU_NO_REGS",
-                                 "wgl_seg_regs"]]}}}]
+            "plan": {"bucket": ["wgl_seg_pipeline", 4]}}}]
         html_out = web._dispatch_plans_html(events)
         assert "Dispatch plans" in html_out
         assert "pipelined" in html_out
         assert "wgl_seg_pipeline" in html_out
-        assert "JEPSEN_TPU_NO_REGS" in html_out
 
 
 # ---------------------------------------------------------------------------
@@ -423,7 +382,7 @@ class TestOverlapExecutor:
             runner.overlap(range(5), pack=lambda i: i,
                            dispatch=dispatch)
 
-    @pytest.mark.parametrize("chunk", ["2", "5"])
+    @pytest.mark.parametrize("chunk", [2, 5])
     def test_chunked_check_many_bit_identical(self, monkeypatch, chunk):
         """Randomized differential sweep: double-buffered verdicts are
         identical to monolithic single-dispatch verdicts AND the CPU
@@ -435,9 +394,9 @@ class TestOverlapExecutor:
                               buggy=(s % 3 == 1),
                               crash_at=30 if s % 4 == 0 else None)
                  for s in range(11)]
-        monkeypatch.setenv("JEPSEN_TPU_OVERLAP_CHUNK", "0")
+        monkeypatch.setattr(wgl_seg, "OVERLAP_CHUNK", len(hists) + 1)
         mono = wgl_seg.check_many(model, hists)
-        monkeypatch.setenv("JEPSEN_TPU_OVERLAP_CHUNK", chunk)
+        monkeypatch.setattr(wgl_seg, "OVERLAP_CHUNK", chunk)
         buffered = wgl_seg.check_many(model, hists)
         for i, (a, b) in enumerate(zip(mono, buffered)):
             assert a["valid?"] == b["valid?"], i

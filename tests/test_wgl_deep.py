@@ -4,7 +4,7 @@ CPU oracle on the deep-concurrency regime the reference names as THE
 cost cliff (`doc/tutorial/06-refining.md:7-10`,
 `doc/tutorial/07-parameters.md:148-152`).  Runs on the CPU interpreter
 (tests force JAX_PLATFORMS=cpu); the TPU lowering is the same kernel
-body, exercised by bench.py's envelope lines on hardware.
+body, exercised by chip_smoke.py's deep phases on hardware.
 
 Histories here are deliberately SMALL: the interpreter executes the
 event loop op-by-op in Python, so sizes are chosen to cover the
@@ -25,7 +25,7 @@ from jepsen_tpu.ops import wgl_cpu, wgl_deep, wgl_seg
 def deep_history(n_calls, conc, seed, vmax=3, max_open=8,
                  crash_rate=0.0):
     """Bursty register workload bounded to `max_open` simultaneously
-    open calls (the bench.py make_history shape, trimmed for the
+    open calls (the chip_smoke.make_history shape, trimmed for the
     interpreter)."""
     rng = random.Random(seed)
     ops, value = [], None
@@ -92,8 +92,8 @@ class TestDeepDifferential:
             assert r["max_open"] >= 7
 
     def test_invalid_witness_equality(self):
-        # mo=10 at 0.9 depth mirrors bench.py's deep-regime refutation
-        # line (VERDICT r4 #3) at interpreter scale
+        # mo=10 at 0.9 depth: a deep-regime refutation (VERDICT r4 #3)
+        # at interpreter scale
         for mo, frac in ((7, 0.6), (9, 0.8), (10, 0.9)):
             h = corrupt(deep_history(140, 14, seed=70 + mo,
                                      max_open=mo), frac)
@@ -167,15 +167,6 @@ class TestDeepDifferential:
         r = wgl_seg.check(models.CASRegister(), h)
         assert r["engine"] == "wgl_seg"
 
-    def test_opt_out_env(self, monkeypatch):
-        monkeypatch.setenv("JEPSEN_TPU_NO_DEEP", "1")
-        h = deep_history(80, 12, seed=5, max_open=8)
-        # falls through to the candidate-table plan() path
-        r = wgl_seg.check(models.CASRegister(), h, max_open_bits=10)
-        o = wgl_cpu.check(models.CASRegister(), h)
-        assert r["valid?"] == o["valid?"]
-        assert r["engine"] == "wgl_seg"
-
     def test_supported_gate(self):
         assert wgl_deep.supported(14, 16, 100, True, "tpu")
         # ISSUE 10: word-split buys R=15/16 on one device; the
@@ -190,16 +181,6 @@ class TestDeepDifferential:
         assert not wgl_deep.supported(8, 33, 100, True, "tpu")
         assert not wgl_deep.supported(8, 16, 100, False, "tpu")
         assert not wgl_deep.supported(8, 16, 100, True, "gpu")
-
-    def test_no_deep_shard_collapses_to_base(self, monkeypatch):
-        # the knob prunes the sharded variants, never invents engines:
-        # the boundary collapses to the single-plane base and the
-        # serial chain owns everything past it
-        monkeypatch.setenv("JEPSEN_TPU_NO_DEEP_SHARD", "1")
-        assert wgl_deep.supported(14, 16, 100, True, "tpu")
-        assert not wgl_deep.supported(15, 16, 100, True, "tpu")
-        assert not wgl_deep.supported(17, 16, 100, True, "tpu",
-                                      n_devices=8)
 
     def test_cpu_interpreter_is_opt_in(self, monkeypatch):
         # ADVICE r4: on a production CPU backend the Pallas interpreter

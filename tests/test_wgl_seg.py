@@ -12,7 +12,7 @@ import pytest
 
 from jepsen_tpu import models
 from jepsen_tpu.history import (History, fail_op, info_op, invoke_op, ok_op)
-from jepsen_tpu.ops import wgl_cpu, wgl_seg
+from jepsen_tpu.ops import planner, wgl_cpu, wgl_seg
 
 
 def rand_history(seed, n_ops=80, conc=3, buggy=False, vmax=3,
@@ -364,7 +364,7 @@ class TestDecomposition:
 class TestRegsPath:
     """The register-delta batch kernel (default): per-return invoke
     deltas + device-maintained open-set registers, vs the candidate-table
-    kernel (JEPSEN_TPU_NO_REGS=1) and the CPU oracle."""
+    kernel and the CPU oracle."""
 
     def test_regs_is_default_engine(self):
         hists = [rand_history(700 + s, n_ops=40) for s in range(4)]
@@ -379,9 +379,11 @@ class TestRegsPath:
                               buggy=(s % 3 == 0)) for s in range(18)]
         m = models.CASRegister()
         res_regs = wgl_seg.check_many(m, hists)
-        monkeypatch.setenv("JEPSEN_TPU_NO_REGS", "1")
+        # force the candidate-table lanes: shut the register-delta gate
+        monkeypatch.setattr(planner, "_regs_eligible",
+                            lambda *a, **k: False)
         res_tab = wgl_seg.check_many(m, hists)
-        monkeypatch.delenv("JEPSEN_TPU_NO_REGS")
+        monkeypatch.undo()
         assert all(r["engine"] == "wgl_seg_batch_regs" for r in res_regs)
         assert all(r["engine"] == "wgl_seg_batch" for r in res_tab)
         for h, rr, rt in zip(hists, res_regs, res_tab):
@@ -643,28 +645,21 @@ class TestBatch:
         r = wgl_seg.check(models.CASRegister(), h)
         assert r["sharded"] is False
 
-    def test_segmented_engine_matches_oracle(self, monkeypatch):
-        # force the segmented (quiescent-cut) batch engine and check
-        # verdict parity on a mix of valid/buggy keys
-        monkeypatch.setenv("JEPSEN_TPU_SEGMENT", "1")
-        hists = [rand_history(900 + s, n_ops=60, conc=3,
-                              buggy=(s % 4 == 1)) for s in range(24)]
-        res = wgl_seg.check_many(models.CASRegister(), hists)
-        for h, r in zip(hists, res):
-            assert r["valid?"] == wgl_cpu.check(
-                models.CASRegister(), h)["valid?"]
-
-    def test_segmented_engine_long_keys(self, monkeypatch):
-        # long keys through the segmented engine; verdicts still match
-        monkeypatch.setenv("JEPSEN_TPU_SEGMENT", "1")
-        hists = [rand_history(40 + s, n_ops=1400, conc=3)
-                 for s in range(3)]
-        bad = History(list(hists[1])
-                      + [invoke_op(9, "read", None),
-                         ok_op(9, "read", 77)]).index()
-        hists[1] = bad
-        res = wgl_seg.check_many(models.CASRegister(), hists)
-        assert [r["valid?"] for r in res] == [True, False, True]
+    @pytest.mark.parametrize("conc", [8, 9])
+    def test_table_lanes_past_the_regs_gate(self, conc):
+        # at concurrency 8-9 the batch's R passes the register-delta
+        # gate (R <= 6): the shape alone routes every key to the
+        # candidate-table lanes, buggy keys included
+        m = models.CASRegister()
+        hists = [rand_history(950 + 10 * conc + s, n_ops=70, conc=conc,
+                              buggy=(s % 3 == 1)) for s in range(6)]
+        res = wgl_seg.check_many(m, hists)
+        assert res[0]["dispatch"]["R"] > 6
+        want = [wgl_cpu.check(m, h)["valid?"] for h in hists]
+        assert False in want
+        for r, w in zip(res, want):
+            assert r["engine"] == "wgl_seg_batch"
+            assert r["valid?"] == w
 
     def test_native_disabled_env(self, monkeypatch):
         monkeypatch.setenv("JEPSEN_TPU_NO_NATIVE", "1")
@@ -883,7 +878,7 @@ class TestColumnarScanAndPipeline:
         # `speculation: exact-rerun`) and carry the oracle's witness —
         # pins the operational trigger of the soundness argument.
         from jepsen_tpu.history import pack_history
-        monkeypatch.setenv("JEPSEN_TPU_SPEC_ROUNDS", "1")
+        monkeypatch.setattr(wgl_seg, "SPEC_ROUNDS", 1)
         model = models.CASRegister(0)
         hists = [rand_history(1200 + s, n_ops=140, conc=5,
                               buggy=(s % 2 == 1)) for s in range(4)]
@@ -904,7 +899,7 @@ class TestColumnarScanAndPipeline:
 
     def test_pipeline_spec_rounds_sweep_verdict_identical(
             self, monkeypatch):
-        # VERDICT r4 #5b: JEPSEN_TPU_SPEC_ROUNDS in {1, 2, R} must not
+        # VERDICT r4 #5b: SPEC_ROUNDS in {1, 2, R} must not
         # change any verdict or witness (fewer rounds only
         # under-approximate; survivors are exact VALID, deaths re-run).
         from jepsen_tpu.history import pack_history
@@ -914,8 +909,8 @@ class TestColumnarScanAndPipeline:
         for h in hists:
             h.attach_packed(pack_history(h))
         outs = []
-        for sr in ("1", "2", "8"):       # 8 clamps to R: exact rounds
-            monkeypatch.setenv("JEPSEN_TPU_SPEC_ROUNDS", sr)
+        for sr in (1, 2, 8):             # 8 clamps to R: exact rounds
+            monkeypatch.setattr(wgl_seg, "SPEC_ROUNDS", sr)
             outs.append(wgl_seg.check_pipeline(model, hists))
         for rs in zip(*outs):
             assert len({r["valid?"] for r in rs}) == 1
