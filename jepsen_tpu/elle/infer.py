@@ -35,8 +35,8 @@ device kernels' job (`jepsen_tpu.ops.elle_graph`).
 
 from __future__ import annotations
 
-import bisect
 import dataclasses
+import itertools
 from typing import Optional
 
 import numpy as np
@@ -199,12 +199,14 @@ def _edge_counts(lists: dict, order_idx: tuple, n: int) -> dict:
 
 
 class _Edges:
-    """Edge accumulator: per-plane (src, dst) lists of the LIST_PLANES,
-    handed on as int64 arrays (`arrays`)."""
+    """Edge accumulator: per-plane (src, dst) edges of the LIST_PLANES,
+    one at a time (`add`) or as whole arrays (`extend`), handed on in
+    the order they came as int64 arrays (`arrays`)."""
 
     def __init__(self):
         self._src = {p: [] for p in LIST_PLANES}
         self._dst = {p: [] for p in LIST_PLANES}
+        self._runs = {p: [] for p in LIST_PLANES}   # (src, dst) arrays
 
     def add(self, plane: str, a: int, b: int) -> None:
         if a == b or a is None or b is None:
@@ -212,10 +214,28 @@ class _Edges:
         self._src[plane].append(a)
         self._dst[plane].append(b)
 
+    def extend(self, plane: str, src: np.ndarray, dst: np.ndarray) -> None:
+        """Edges src[j] -> dst[j] in turn, after those so far, with
+        `add`'s a != b filter."""
+        self._close(plane)
+        keep = src != dst
+        self._runs[plane].append((src[keep], dst[keep]))
+
+    def _close(self, plane: str) -> None:
+        if self._src[plane]:
+            self._runs[plane].append(
+                (np.asarray(self._src[plane], np.int64),
+                 np.asarray(self._dst[plane], np.int64)))
+            self._src[plane], self._dst[plane] = [], []
+
     def arrays(self) -> dict:
-        return {p: (np.asarray(self._src[p], np.int64),
-                    np.asarray(self._dst[p], np.int64))
-                for p in LIST_PLANES}
+        out = {}
+        for p in LIST_PLANES:
+            self._close(p)
+            runs = self._runs[p] or [(np.zeros(0, np.int64),) * 2]
+            out[p] = tuple(np.concatenate(side).astype(np.int64, copy=False)
+                           for side in zip(*runs))
+        return out
 
 
 def txn_mops(okop) -> list:
@@ -290,21 +310,49 @@ def _order_planes(txns: list, edges: _Edges) -> tuple:
 # list-append
 # ---------------------------------------------------------------------------
 
+def _heads(x: np.ndarray) -> np.ndarray:
+    """Mask of the first of each run of equal values in `x`."""
+    h = np.ones(len(x), bool)
+    h[1:] = x[1:] != x[:-1]
+    return h
+
+
+def _tails(x: np.ndarray) -> np.ndarray:
+    """Mask of the last of each run of equal values in `x`."""
+    t = np.ones(len(x), bool)
+    t[:-1] = x[1:] != x[:-1]
+    return t
+
+
+def _segments(starts: np.ndarray, counts: np.ndarray) -> np.ndarray:
+    """starts[i], starts[i] + 1, ... counts[i] of them, for each i in
+    turn: the flat indices of a run of segments."""
+    ends = np.cumsum(counts)
+    return (np.repeat(starts - ends + counts, counts)
+            + np.arange(int(ends[-1]) if len(ends) else 0))
+
+
+# a read's classification, in the order of precedence
+_CLEAN, _ABORTED, _GARBAGE, _G1B, _INCOMPATIBLE = range(5)
+
+
 def _infer_list_append(txns, failed, indet, edges: _Edges):
     direct: dict = {}
-    meta: dict = {"keys": 0}
 
     def flag(name, i, m, **kw):
         direct.setdefault(name, []).append(
             dict({"op": txns[i][1].to_dict(), "mop": list(m)}, **kw))
 
-    # per-key append bookkeeping and the observed states over committed
-    # txns; duplicate-elements is a read that shows one element twice
-    # (Elle's definition; the read gives no edge) or a value two
-    # committed txns append, flagged in txn order
-    writer_of: dict = {}          # (k, v) -> txn index
-    appends_by_txn: dict = {}     # (k, txn) -> [v, ...] in mop order
-    reads: list = []              # (txn index, key, state tuple, mop)
+    # the walk: a code per key, and per key a code per version (a value
+    # some committed txn appends); each read's txn, key and state, each
+    # append's key, txn and version.  duplicate-elements is a read that
+    # shows one element twice (Elle's definition; the read gives no
+    # edge) or a value two committed txns append, flagged in txn order
+    kcode: dict = {}              # key -> code
+    vcode: list = []              # per key code: {value: version code}
+    writer: list = []             # per version: its committed appender
+    a_key, a_txn, a_ver = [], [], []
+    r_txn, r_key, r_state, r_mop = [], [], [], []
     for i, (_, okop) in enumerate(txns):
         for m in txn_mops(okop):
             if mop.is_read(m):
@@ -316,118 +364,200 @@ def _infer_list_append(txns, failed, indet, edges: _Edges):
                 if len(set(s)) != len(s):
                     flag("duplicate-elements", i, m)
                     continue
-                reads.append((i, mop.key(m), tuple(s), m))
+                kc = kcode.setdefault(mop.key(m), len(kcode))
+                if kc == len(vcode):
+                    vcode.append({})
+                r_txn.append(i)
+                r_key.append(kc)
+                r_state.append(s)
+                r_mop.append(m)
             elif mop.is_append(m):
-                k, v = mop.key(m), mop.value(m)
-                if (k, v) in writer_of and writer_of[(k, v)] != i:
+                kc = kcode.setdefault(mop.key(m), len(kcode))
+                if kc == len(vcode):
+                    vcode.append({})
+                c = vcode[kc].setdefault(mop.value(m), len(writer))
+                if c == len(writer):
+                    writer.append(i)
+                elif writer[c] != i:
                     flag("duplicate-elements", i, m,
-                         other=txns[writer_of[(k, v)]][1].to_dict())
+                         other=txns[writer[c]][1].to_dict())
                     continue
-                writer_of[(k, v)] = i
-                appends_by_txn.setdefault((k, i), []).append(v)
+                a_key.append(kc)
+                a_txn.append(i)
+                a_ver.append(c)
 
-    # version order = longest prefix chain
-    orders: dict = {}             # key -> tuple of values, longest observed
-    for i, k, s, m in reads:
-        if len(s) > len(orders.get(k, ())):
-            orders[k] = s
-    meta["keys"] = len({k for k, _ in writer_of} | set(orders))
+    # failed and indeterminate writes of the keys walked take codes too
+    # (with no writer); an element with none (code -1) is garbage.  Code
+    # -1 indexes the last slot of each per-version array, which no
+    # version holds
+    marks = []
+    for pairs in (failed, indet):
+        cs = []
+        for k, v in pairs:
+            kc = kcode.get(k)
+            if kc is not None:
+                c = vcode[kc].setdefault(v, len(writer))
+                if c == len(writer):
+                    writer.append(-1)
+                cs.append(c)
+        marks.append(cs)
+    nv, nk, nr = len(writer), len(vcode), len(r_txn)
+    v_writer = np.asarray(writer + [-1], np.int64)
+    v_failed = np.zeros(nv + 1, bool)
+    v_failed[marks[0]] = True
+    v_indet = np.zeros(nv + 1, bool)
+    v_indet[marks[1]] = True
 
-    # indexed once, so that a read costs its own length (plus the rw
-    # edges it draws): each key's appenders in first-append order
-    # (`rank`), and in the order of the last place their appends take
-    # in the key's version order (`unseen_from`; absent appends place
-    # last).  A clean read of the first L versions has seen exactly the
-    # appenders whose last place is below L.
-    by_key_appends: dict = {}     # key -> [(txn, [v, ...]), ...]
-    rank: dict = {}               # (key, txn) -> place in that list
-    for (k, t), vs in appends_by_txn.items():
-        lst = by_key_appends.setdefault(k, [])
-        rank[(k, t)] = len(lst)
-        lst.append((t, vs))
-    absent = float("inf")
-    unseen_from: dict = {}        # key -> ([last place, ...], [txn, ...])
-    for k, lst in by_key_appends.items():
-        place: dict = {}
-        for j, v in enumerate(orders.get(k, ())):
-            place.setdefault(v, j)
-        last = sorted((max(place.get(v, absent) for v in vs), t)
-                      for t, vs in lst)
-        unseen_from[k] = ([p for p, _ in last], [t for _, t in last])
+    # the reads' elements, flat and coded
+    r_txn = np.asarray(r_txn, np.int64)
+    r_key = np.asarray(r_key, np.int64)
+    r_len = np.fromiter(map(len, r_state), np.int64, nr)
+    r_start = np.cumsum(r_len) - r_len
+    n_el = int(r_len.sum())
+    gets = [d.get for d in vcode]
+    missing = itertools.repeat(-1)
+    e_ver = np.fromiter(itertools.chain.from_iterable(
+        map(gets[kc], s, missing) for kc, s in zip(r_key.tolist(), r_state)),
+        np.int64, n_el)
+    e_read = np.repeat(np.arange(nr), r_len)
+    e_pos = np.arange(n_el) - r_start[e_read]
+    e_txn = r_txn[e_read]
+    e_w = v_writer[e_ver]
 
-    # classify each read; only clean prefix reads contribute edges
-    for i, k, s, m in reads:
-        order = orders.get(k, ())
-        bad = False
-        for v in s:
-            if (k, v) in failed:
-                flag("G1a", i, m, kind="aborted")
-                bad = True
-                break
-            if (writer_of.get((k, v)) is None and (k, v) not in indet):
-                flag("G1a", i, m, kind="garbage")
-                bad = True
-                break
-        if bad:
-            continue
-        seen = set(s)
-        # G1b: an appender whose earlier append the read saw and whose
-        # last it did not (the first such in first-append order)
-        g1b = None
-        for v in s:
-            t = writer_of.get((k, v))
-            if t is not None and t != i:
-                tail = appends_by_txn[(k, t)][-1]
-                if tail not in seen and (
-                        g1b is None or rank[(k, t)] < rank[(k, g1b)]):
-                    g1b = t
-        if g1b is not None:
-            flag("G1b", i, m, writer=txns[g1b][1].to_dict())
-            continue
-        if tuple(order[:len(s)]) != tuple(s):
-            flag("incompatible-order", i, m, longest=list(order))
-            continue
-        # wr: the last element whose writer is a committed node other
-        # than the reader itself (read-your-own-write is not an
-        # external observation; the one before it is)
-        for v in reversed(s):
-            w = writer_of.get((k, v))
-            if w is not None and w != i:
-                edges.add("wr", w, i)
-                break
-        # rw: lists grow monotonically, so ANY committed append not in
-        # the observed state was installed after it — the next observed
-        # version plus every unobserved committed append (sound: the
-        # emitted edge stands for rw + a ww-path)
-        if k in unseen_from:
-            places, txs = unseen_from[k]
-            for t in txs[bisect.bisect_left(places, len(s)):]:
-                if t != i:
-                    edges.add("rw", i, t)
+    # version order = longest prefix chain: each key's first longest
+    # read (the order read), and each version's place in it
+    ne = np.flatnonzero(r_len > 0)
+    by_len = ne[np.lexsort((-r_len[ne], r_key[ne]))]
+    best = by_len[_heads(r_key[by_len])]     # ascending key code
+    order_read = np.full(nk, -1)
+    order_read[r_key[best]] = best
+    _, first = np.unique(r_key[ne], return_index=True)
+    kseq = r_key[best][np.argsort(ne[first])]  # by first nonempty read
+    big = int(r_len.max(initial=0)) + 1       # absent: after every read
+    v_place = np.full(nv + 1, big)
+    oe = _segments(r_start[best], r_len[best])
+    coded = e_ver[oe] >= 0
+    v_place[e_ver[oe][coded]] = e_pos[oe][coded]
+    # a prefix read matches its key's order read at each position
+    e_ord = e_ver[r_start[order_read[r_key[e_read]]] + e_pos]
+    r_prefix = np.ones(nr, bool)
+    r_prefix[e_read[e_ord != e_ver]] = False
 
-    # ww: consecutive committed writers along each key's version
-    # order, then order-tail -> unobserved appends (same monotonicity
-    # argument: absent from the longest observed state => later)
-    for k, order in orders.items():
-        prev = None
-        for v in order:
-            w = writer_of.get((k, v))
-            if w is None:
-                continue
-            if prev is not None and prev != w:
-                edges.add("ww", prev, w)
-            prev = w
-        if prev is not None and k in unseen_from:
-            places, txs = unseen_from[k]
-            for t in txs[bisect.bisect_left(places, len(order)):]:
-                if t != prev:
-                    edges.add("ww", prev, t)
+    # each (key, txn)'s appends in mop order, grouped in first-append
+    # order (within a key, txn order: the walk is in txn order); its
+    # tail version and the last place its appends take (absent last);
+    # each key's appenders by (last place, txn).  A clean read of the
+    # first L versions has seen exactly the appenders placed below L
+    a_key = np.asarray(a_key, np.int64)
+    by_key = np.argsort(a_key, kind="stable")
+    a_key = a_key[by_key]
+    a_txn = np.asarray(a_txn, np.int64)[by_key]
+    a_ver = np.asarray(a_ver, np.int64)[by_key]
+    g_head = _heads(a_key * len(txns) + a_txn)
+    g_key, g_txn = a_key[g_head], a_txn[g_head]
+    v_tail = np.full(nv + 1, -1)                # its appender's tail
+    v_tail[a_ver] = a_ver[_tails(a_key * len(txns) + a_txn)][
+        np.cumsum(g_head) - 1]
+    g_last = (np.maximum.reduceat(v_place[a_ver], np.flatnonzero(g_head))
+              if len(a_ver) else np.zeros(0, np.int64))
+    by_last = np.lexsort((g_txn, g_last, g_key))
+    s_txn = g_txn[by_last]
+    s_at = g_key[by_last] * (big + 1) + g_last[by_last]
+    k_end = np.searchsorted(g_key[by_last], np.arange(nk), "right")
+
+    def unseen(kc, length):
+        """(start, count) in s_txn of the appenders of keys `kc` that a
+        prefix of `length` versions has not seen."""
+        start = np.searchsorted(s_at, kc * (big + 1) + length)
+        return start, k_end[kc] - start
+
+    # classify each read; only clean prefix reads contribute edges.
+    # G1a: its first element that a failed txn appended (aborted) or no
+    # txn may have (garbage)
+    r_kind = np.zeros(nr, np.int8)
+    bad = np.flatnonzero(v_failed[e_ver] | ((e_w < 0) & ~v_indet[e_ver]))
+    bad = bad[_heads(e_read[bad])]
+    r_kind[e_read[bad]] = np.where(v_failed[e_ver[bad]], _ABORTED, _GARBAGE)
+    # G1b: an appender whose earlier append the read saw and whose last
+    # it did not (the first such in first-append order, so the least
+    # txn); membership by place in a prefix read, else by the read's
+    # own elements
+    cand = np.flatnonzero((e_w >= 0) & (e_w != e_txn)
+                          & (r_kind[e_read] == _CLEAN))
+    tail = v_tail[e_ver[cand]]
+    c_read = e_read[cand]
+    has_tail = v_place[tail] < r_len[c_read]
+    off = ~r_prefix[c_read]
+    if off.any():
+        el = np.flatnonzero(~r_prefix[e_read])
+        has_tail[off] = np.isin(c_read[off] * (nv + 1) + tail[off],
+                                e_read[el] * (nv + 1) + e_ver[el])
+    g1b = cand[~has_tail]
+    g1b = g1b[np.lexsort((e_w[g1b], e_read[g1b]))]
+    g1b = g1b[_heads(e_read[g1b])]
+    r_kind[e_read[g1b]] = _G1B
+    r_g1b = np.full(nr, -1)
+    r_g1b[e_read[g1b]] = e_w[g1b]
+    # incompatible-order: off its key's order
+    r_kind[(r_kind == _CLEAN) & ~r_prefix] = _INCOMPATIBLE
+    for r in np.flatnonzero(r_kind).tolist():
+        i, m, kind = int(r_txn[r]), r_mop[r], r_kind[r]
+        if kind == _ABORTED:
+            flag("G1a", i, m, kind="aborted")
+        elif kind == _GARBAGE:
+            flag("G1a", i, m, kind="garbage")
+        elif kind == _G1B:
+            flag("G1b", i, m, writer=txns[r_g1b[r]][1].to_dict())
+        else:
+            flag("incompatible-order", i, m,
+                 longest=list(r_state[order_read[r_key[r]]]))
+    telemetry.REGISTRY.counter(telemetry.ELLE_INFER_READS,
+                               path="array").inc(nr)
+
+    # wr: the last element whose writer is a committed node other than
+    # the reader itself (read-your-own-write is not an external
+    # observation; the one before it is)
+    clean = np.flatnonzero(r_kind == _CLEAN)
+    wr = np.flatnonzero((r_kind[e_read] == _CLEAN) & (e_w >= 0)
+                        & (e_w != e_txn))
+    wr = wr[_tails(e_read[wr])]
+    edges.extend("wr", e_w[wr], e_txn[wr])
+    # rw: lists grow monotonically, so ANY committed append not in the
+    # observed state was installed after it — the next observed version
+    # plus every unobserved committed append (sound: the emitted edge
+    # stands for rw + a ww-path)
+    start, count = unseen(r_key[clean], r_len[clean])
+    edges.extend("rw", np.repeat(r_txn[clean], count),
+                 s_txn[_segments(start, count)])
+
+    # ww: consecutive committed writers along each key's version order,
+    # then order-tail -> unobserved appends (same monotonicity argument:
+    # absent from the longest observed state => later); key by key, in
+    # the order of their first nonempty read
+    ob = order_read[kseq]
+    oe = _segments(r_start[ob], r_len[ob])
+    w, rank = e_w[oe], np.repeat(np.arange(len(kseq)), r_len[ob])
+    w, rank = w[w >= 0], rank[w >= 0]
+    step = np.flatnonzero(rank[1:] == rank[:-1])      # w[j] -> w[j + 1]
+    last = np.flatnonzero(_tails(rank))
+    start, count = unseen(kseq[rank[last]], r_len[ob[rank[last]]])
+    src = np.concatenate([w[step], np.repeat(w[last], count)])
+    dst = np.concatenate([w[step + 1], s_txn[_segments(start, count)]])
+    at = np.argsort(np.concatenate([2 * rank[step],       # chain, then tail
+                                    2 * np.repeat(rank[last], count) + 1]),
+                    kind="stable")
+    edges.extend("ww", src[at], dst[at])
 
     # bounded: results.json must not scale with history size
-    meta["version-orders"] = {
+    keys = list(kcode)
+    orders = {keys[k]: r_state[order_read[k]] for k in kseq.tolist()}
+    seen_key = np.zeros(nk, bool)
+    seen_key[a_key] = True
+    seen_key[kseq] = True
+    meta = {"keys": int(seen_key.sum()), "version-orders": {
         repr(k): (list(v[:32]) + ["..."] if len(v) > 32 else list(v))
         for k, v in sorted(orders.items(),
-                           key=lambda kv: repr(kv[0]))[:8]}
+                           key=lambda kv: repr(kv[0]))[:8]}}
     return direct, meta
 
 

@@ -791,6 +791,7 @@ DEVICE_CALLS = "jepsen_device_calls_total"        # {engine}
 LANES = "jepsen_lanes_total"                      # {engine, kind}
 WIRE_BYTES = "jepsen_wire_bytes_total"            # {engine, direction}
 ELLE_ROUNDS = "jepsen_elle_closure_rounds_total"  # {n_pad}
+ELLE_INFER_READS = "jepsen_elle_infer_reads_total"  # {path}
 
 # the innermost open counted span of this thread
 _open = threading.local()
